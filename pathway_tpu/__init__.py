@@ -25,6 +25,25 @@ from __future__ import annotations
 # the analysis package (six modules) loads only when the knob is ON.
 from . import config
 
+# persistent XLA compile cache, placed from outside: JAX reads
+# JAX_COMPILATION_CACHE_DIR itself and nothing here touches it then.
+# Without it the cache sits at a fixed path in the checkout — the path is
+# part of the cache key, so a temp name would never hit — and keeps every
+# executable, not only those that took JAX's default 1 s to compile: a
+# compile time near that threshold would make two runs of one program
+# cache different sets.  This is the only place the directory is set;
+# every entry point (cli, bench phase children, chip_smoke) passes
+# through this import.
+import os as _os
+
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    import jax as _jax
+    from pathlib import Path as _Path
+
+    _checkout = _Path(__file__).resolve().parents[1]
+    _jax.config.update("jax_compilation_cache_dir", str(_checkout / ".jax_cache"))
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
 if config.get("analysis.lock_sanitizer"):
     from .analysis.sanitizer import install as _sanitizer_install
 

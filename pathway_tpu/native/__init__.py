@@ -70,14 +70,15 @@ def _sources_newer_than_so() -> bool:
 
 def build(force: bool = False) -> bool:
     """Build libpathway_native.so (make, falling back to a direct g++ call).
-    Returns True if the library exists afterwards."""
+    Returns True if the library exists afterwards.  ``force`` rebuilds
+    whatever the mtimes say (a copied tree does not keep them)."""
     if not _NATIVE_DIR.exists():
         return False
     if not force and not _sources_newer_than_so():
         return True
     try:
         subprocess.run(
-            ["make", "-s"],
+            ["make", "-s", "-B"] if force else ["make", "-s"],
             cwd=_NATIVE_DIR,
             check=True,
             capture_output=True,

@@ -169,18 +169,22 @@ def track_resource(
 
 
 def device_bytes() -> Optional[int]:
-    """The backend's own resident-byte accounting: TPU/GPU platforms
-    report ``memory_stats()['bytes_in_use']``; the CPU backend doesn't,
-    so fall back to summing ``jax.live_arrays()`` — every live buffer
-    the backend still holds.  None when jax is unavailable."""
+    """The backend's own resident-byte accounting, summed over the
+    local devices (the ledger's owners may sit on any of them): TPU/GPU
+    platforms report ``memory_stats()['bytes_in_use']``; the CPU backend
+    doesn't, so fall back to summing ``jax.live_arrays()`` — every live
+    buffer the backend still holds.  None when jax is unavailable."""
     try:
         import jax
     except Exception:  # pragma: no cover - jax always present in-tree
         return None
     try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_in_use"):
-            return int(stats["bytes_in_use"])
+        total = sum(
+            int((dev.memory_stats() or {}).get("bytes_in_use", 0))
+            for dev in jax.local_devices()
+        )
+        if total:
+            return total
     except Exception:
         pass
     try:

@@ -15,7 +15,7 @@ final bucket is the +Inf overflow.  Power-of-two bounds make the bucket
 index one ``bit_length`` call (no search, no float math) and give uniform
 relative resolution (every bucket is 2x the last), which is what latency
 distributions need: the same histogram covers a 40 ns counter read and a
-70 ms tunnel round trip without configuration.
+multi-second first-call compile without configuration.
 """
 
 from __future__ import annotations

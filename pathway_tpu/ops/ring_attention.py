@@ -123,14 +123,13 @@ def ring_attention_sharded(
     causal: bool = False,
 ):
     """shard_map wrapper: q/k/v sharded on the sequence dim over ``axis``."""
-    from .topk import _shard_map
-
     spec_qkv = P(None, axis, None, None)
     spec_mask = P(None, axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis, causal=causal),
         mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask, spec_mask),
         out_specs=spec_qkv,
+        check_vma=False,
     )
     return fn(q, k, v, kv_mask, positions)

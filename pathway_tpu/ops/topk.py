@@ -24,28 +24,6 @@ __all__ = [
 ]
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: ``jax.shard_map`` (new) falls
-    back to ``jax.experimental.shard_map.shard_map`` (0.4.x), where the
-    replication check rejects the all-gather+merge pattern and is
-    disabled the same way ``check_vma=False`` disables it upstream."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def local_score_topk(
     queries: jnp.ndarray,  # [B, d]
     matrix: jnp.ndarray,  # [N, d] (local shard rows)
@@ -177,10 +155,12 @@ def sharded_topk(
         offsets = jnp.arange(n_shards) * rows_per_shard
         return merge_topk(gathered_scores, gathered_idx, offsets, k)
 
-    fn = _shard_map(
+    # check_vma off: the replication check rejects all-gather + merge
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(), P("data", None), P("data")),
         out_specs=(P(), P()),
+        check_vma=False,
     )
     return fn(queries, matrix, valid)

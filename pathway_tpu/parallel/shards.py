@@ -2,10 +2,10 @@
 
 The single-host serving tier (rounds 5–10) is capped by one chip's HBM
 and FLOPs no matter how many callers the scheduler coalesces; the
-scale-out design (ROADMAP item 1, proven by the MULTICHIP_r05 dryrun:
-fused serving over an 8-shard index with on-device global top-k merge at
-~0% merge share) partitions the index by DOCUMENT across a device group
-and fans the coalesced stage-1 batch out to every shard:
+scale-out design (ROADMAP item 1; chip_smoke.py's four-chip leg checks
+it on four real devices, the merge's cost is not measured) partitions
+the index by DOCUMENT across a device group and fans the coalesced
+stage-1 batch out to every shard:
 
 - ``ShardGroup`` resolves the serve device group (``PATHWAY_SERVE_SHARDS``
   or an explicit count, over the local devices) and owns the one routing

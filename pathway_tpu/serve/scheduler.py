@@ -1,10 +1,9 @@
 """Continuous cross-request batching: a coalescing serve scheduler with
 double-buffered stage pipelining.
 
-The serve path is RTT-bound and the fused pipeline already hits the
-2-dispatch + 2-fetch budget — but only *per request*: concurrent callers
-serialize on the pipeline, so at QPS above 1/RTT the device idles while
-requests queue.  Cross-request micro-batching is the standard fix in
+The fused pipeline already hits the 2-dispatch + 2-fetch budget (a
+count of host syncs) — but only *per request*: concurrent callers
+serialize on the pipeline, so the device idles while requests queue.  Cross-request micro-batching is the standard fix in
 neural-ranking serving ("Accelerating Retrieval-Augmented Generation",
 arxiv 2412.15246; Zamani et al., arxiv 1707.08275: retrieval+rerank
 throughput is dominated by batch occupancy, not per-query FLOPs).

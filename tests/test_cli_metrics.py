@@ -36,6 +36,30 @@ def test_spawn_propagates_failure(tmp_path):
     assert rc == 3
 
 
+def test_spawn_refuses_several_processes_on_an_accelerator_host(
+    tmp_path, monkeypatch, capsys
+):
+    """A chip belongs to one process: with an accelerator present, N > 1
+    is refused with a message before anything starts; N == 1 is not
+    probed at all."""
+    from pathway_tpu import cli
+
+    script = tmp_path / "prog.py"
+    script.write_text(
+        f"import pathlib; pathlib.Path(r'{tmp_path}', 'ran').write_text('x')\n"
+    )
+    monkeypatch.setattr(cli, "_accelerator_backend", lambda env: "tpu")
+    rc = cli_main(["spawn", "-n", "2", sys.executable, str(script)])
+    assert rc == 2
+    assert not (tmp_path / "ran").exists()
+    assert "one process" in capsys.readouterr().err
+    assert cli_main(["spawn", "-n", "1", sys.executable, str(script)]) == 0
+    assert (tmp_path / "ran").exists()
+    # the probe itself: a CPU-pinned environment needs no subprocess
+    monkeypatch.undo()
+    assert cli._accelerator_backend({"JAX_PLATFORMS": "cpu"}) is None
+
+
 def test_replay_sets_persistence_env(tmp_path):
     script = tmp_path / "prog.py"
     script.write_text(

@@ -6,14 +6,13 @@ from __future__ import annotations
 
 import io
 import json
-import subprocess
-import sys
-import time
-import urllib.request
+import os
 
 import pytest
 
-from .utils import REPO_ROOT, free_port
+import chip_smoke
+
+from .utils import REPO_ROOT
 
 
 def test_yaml_loader_variables_inside_constructors():
@@ -46,68 +45,27 @@ def test_yaml_loader_resolves_nested_modules():
     assert _resolve_callable("pw.stdlib.indexing.BruteForceKnnFactory")
 
 
+def _template_server(yaml_path, probe=None):
+    """The launcher as chip_smoke.py starts it, pinned to the CPU."""
+    return chip_smoke.TemplateServer(
+        yaml_path, {**os.environ, "JAX_PLATFORMS": "cpu"}, probe=probe
+    )
+
+
 @pytest.mark.slow
 def test_adaptive_rag_template_serves_end_to_end():
     """python -m pathway_tpu.cli run templates/adaptive_rag.yaml answers a
-    query end-to-end (the VERDICT r2 #9 acceptance)."""
-    port = free_port()
-    import os
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.Popen(
-        [
-            sys.executable,
-            "-m",
-            "pathway_tpu.cli",
-            "run",
-            "templates/adaptive_rag.yaml",
-            "--port",
-            str(port),
-        ],
-        cwd=REPO_ROOT,
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-    )
-
-    def post(route, payload, timeout):
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{port}{route}",
-            data=json.dumps(payload).encode(),
-            headers={"Content-Type": "application/json"},
-        )
-        with urllib.request.urlopen(req, timeout=timeout) as resp:
-            return json.loads(resp.read())
-
-    try:
-        deadline = time.time() + 120
-        up = False
-        while time.time() < deadline and not up:
-            if proc.poll() is not None:
-                out, _ = proc.communicate()
-                raise AssertionError(f"template app died:\n{out[-3000:]}")
-            try:
-                post("/v1/retrieve", {"query": "cats", "k": 1}, timeout=5)
-                up = True
-            except Exception:
-                time.sleep(1.0)
-        assert up, "template server did not come up"
-
-        docs = post("/v1/retrieve", {"query": "anything", "k": 3}, timeout=60)
+    query end-to-end, and says at start-up which platform it runs on."""
+    with _template_server("templates/adaptive_rag.yaml") as server:
+        docs = server.post("/v1/retrieve", {"query": "anything", "k": 3}, 60)
         assert len(docs) == 3
         assert all("text" in d and "metadata" in d for d in docs)
         paths = {d["metadata"]["path"] for d in docs}
         assert any("sample_documents" in p for p in paths)
 
-        answer = post(
-            "/v1/pw_ai_answer", {"prompt": "What do cats do?"}, timeout=180
-        )
+        answer = server.post("/v1/pw_ai_answer", {"prompt": "What do cats do?"})
         assert isinstance(answer, str) and answer.strip(), answer
-    finally:
-        proc.kill()
-        proc.wait()
+    assert "platform=cpu " in server.output()
 
 
 def test_yaml_loader_circular_variables_raise():
@@ -127,86 +85,36 @@ def test_yaml_loader_circular_variables_raise():
 # ---------------------------------------------------------------------------
 
 
-def _launch_template(yaml_path, port):
-    import os
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    return subprocess.Popen(
-        [sys.executable, "-m", "pathway_tpu.cli", "run", yaml_path,
-         "--port", str(port)],
-        cwd=REPO_ROOT,
-        env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-    )
-
-
-def _post(port, route, payload, timeout):
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{port}{route}",
-        data=json.dumps(payload).encode(),
-        headers={"Content-Type": "application/json"},
-    )
-    with urllib.request.urlopen(req, timeout=timeout) as resp:
-        return json.loads(resp.read())
-
-
-def _wait_up(proc, port, probe_payload, deadline_s=120):
-    deadline = time.time() + deadline_s
-    while time.time() < deadline:
-        if proc.poll() is not None:
-            out, _ = proc.communicate()
-            raise AssertionError(f"template app died:\n{out[-3000:]}")
-        try:
-            _post(port, "/v1/retrieve", probe_payload, timeout=5)
-            return
-        except Exception:
-            time.sleep(1.0)
-    raise AssertionError("template server did not come up")
-
-
 @pytest.mark.slow
 def test_demo_question_answering_template_serves_end_to_end():
     """Reference demo-question-answering app shape
     (docs/2.developers/7.templates/1000.demo-question-answering.md):
     retrieve + statistics + list_documents + answer over one YAML app."""
-    port = free_port()
-    proc = _launch_template("templates/demo_question_answering.yaml", port)
-    try:
-        _wait_up(proc, port, {"query": "cats", "k": 1})
-        docs = _post(port, "/v1/retrieve", {"query": "anything", "k": 3}, 60)
+    with _template_server("templates/demo_question_answering.yaml") as server:
+        docs = server.post("/v1/retrieve", {"query": "anything", "k": 3}, 60)
         assert len(docs) == 3 and all("text" in d for d in docs)
-        stats = _post(port, "/v1/statistics", {}, 60)
+        stats = server.post("/v1/statistics", {}, 60)
         assert stats["file_count"] >= 3, stats
-        listed = _post(port, "/v1/pw_list_documents", {}, 60)
+        listed = server.post("/v1/pw_list_documents", {}, 60)
         assert {d["path"].rsplit("/", 1)[-1] for d in listed} >= {
             "animals.txt", "dataflow.txt", "tpu.txt"
         }
-        answer = _post(
-            port, "/v1/pw_ai_answer", {"prompt": "What do cats do?"}, 180
-        )
+        answer = server.post("/v1/pw_ai_answer", {"prompt": "What do cats do?"})
         assert isinstance(answer, str) and answer.strip()
-        summary = _post(
-            port, "/v1/pw_ai_summary",
-            {"text_list": ["cats purr", "dogs bark"]}, 180,
+        summary = server.post(
+            "/v1/pw_ai_summary", {"text_list": ["cats purr", "dogs bark"]}
         )
         assert isinstance(summary, str) and summary.strip()
-    finally:
-        proc.kill()
-        proc.wait()
 
 
 @pytest.mark.slow
 def test_multimodal_rag_template_serves_images():
     """Reference multimodal-rag shape (1003.template-multimodal-rag.md):
     images become searchable documents via local CLIP labels."""
-    port = free_port()
-    proc = _launch_template("templates/multimodal_rag.yaml", port)
-    try:
-        _wait_up(proc, port, {"query": "red", "k": 1})
-        docs = _post(port, "/v1/retrieve", {"query": "red square", "k": 3}, 60)
+    with _template_server(
+        "templates/multimodal_rag.yaml", probe={"query": "red", "k": 1}
+    ) as server:
+        docs = server.post("/v1/retrieve", {"query": "red square", "k": 3}, 60)
         assert len(docs) == 3
         # every indexed image chunk carries CLIP labels as searchable text
         assert all(d["text"] for d in docs), docs
@@ -214,36 +122,26 @@ def test_multimodal_rag_template_serves_images():
         assert paths == {
             "red_square.png", "blue_circle.png", "green_stripes.png"
         }, paths
-        answer = _post(
-            port, "/v1/pw_ai_answer",
-            {"prompt": "Which image shows a red square?"}, 180,
+        answer = server.post(
+            "/v1/pw_ai_answer", {"prompt": "Which image shows a red square?"}
         )
         assert isinstance(answer, str) and answer.strip()
-    finally:
-        proc.kill()
-        proc.wait()
 
 
 @pytest.mark.slow
 def test_slides_search_template_returns_slides():
     """Reference slides-search shape (1010.template-slides-search.md):
     the deck is parsed per slide and /v1/pw_ai_answer returns SLIDES."""
-    port = free_port()
-    proc = _launch_template("templates/slides_search.yaml", port)
-    try:
-        _wait_up(proc, port, {"query": "revenue", "k": 1})
-        slides = _post(
-            port, "/v1/pw_ai_answer", {"prompt": "revenue growth"}, 120
-        )
+    with _template_server(
+        "templates/slides_search.yaml", probe={"query": "revenue", "k": 1}
+    ) as server:
+        slides = server.post("/v1/pw_ai_answer", {"prompt": "revenue growth"}, 120)
         assert isinstance(slides, list) and slides, slides
         assert all("text" in s and "metadata" in s for s in slides)
         assert all("slide" in s["metadata"] for s in slides), slides
         # three slides indexed from one deck
-        stats = _post(port, "/v1/statistics", {}, 60)
+        stats = server.post("/v1/statistics", {}, 60)
         assert stats["file_count"] == 3, stats
-    finally:
-        proc.kill()
-        proc.wait()
 
 
 def test_kafka_etl_template_unifies_time_zones(monkeypatch):
