@@ -1,0 +1,29 @@
+"""Published peaks of the chips the benchmark may run on, by ``device_kind``.
+
+A device that is not in the table is an error, not a default.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8,
+    # 16 GB HBM2e at 819 GB/s, per chip
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+    },
+}
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise SystemExit(
+            f"no published peaks for device_kind {device_kind!r}: add it to "
+            "benchmarks/peaks.py with its source"
+        ) from None
