@@ -42,15 +42,16 @@ they execute later, not under the lock):
   ``fabric.send``/``fabric.recv`` chaos sites.  The sanctioned shape is
   serve/fabric.py's swap-under-lock / I/O-off-lock discipline.
 
-And the INVERSE scope check on serve-path modules: a trace span opened
-as a context manager (``with trace.span(...):`` / ``start_span`` /
-``span_timer``) whose body ACQUIRES a lock.  Spans time *work*, not
-lock waits — a span held across ``with <lock>:`` silently folds queue
-contention into the stage it claims to measure, which is exactly the
-mis-attribution per-request tracing exists to kill.  The serve paths
-therefore record spans with EXPLICIT timestamps
-(``trace.current().add_span(name, t0, t1)``), reusing the clock reads
-the stage histograms already take.
+And the INVERSE scope check on serve-path modules: a span opened as a
+context manager (``with observe.span(...):`` — observe/spans.py, the
+program's one bracket — or any ``.span`` / ``start_span`` /
+``span_timer`` of an OTLP-style tracer) whose body ACQUIRES a lock.
+Spans time *work*, not lock waits — a span held across ``with <lock>:``
+silently folds queue contention into the stage it claims to measure,
+which is exactly the mis-attribution per-request tracing exists to kill.
+The order on the serve paths is therefore lock-then-span: take the lock,
+then open ``observe.span``; the wait for the lock is measured as an
+``observe.interval`` of its own, from clock reads taken around it.
 
 Deliberate cases (e.g. a dispatch-only launch under the lock that
 snapshots device state consistently and never blocks on the result) are
@@ -92,8 +93,9 @@ _PICKLE_CALLS = {
 }
 _COERCIONS = {"np.asarray", "np.array", "numpy.asarray", "numpy.array",
               "float", "int"}
-# span-opening context managers (observe/trace.py and OTLP-style APIs):
-# `with trace.span(...)`, `with tracer.start_span(...)`, span timers
+# span-opening context managers, by the callee's last name: the program's
+# own `with observe.span(...)` (observe/spans.py) and OTLP-style APIs
+# (`with tracer.span(...)`, `with tracer.start_span(...)`, span timers)
 _SPAN_CM_LEAVES = {"span", "start_span", "span_timer"}
 
 
@@ -177,9 +179,8 @@ class LockDisciplineRule(Rule):
         message = (
             "trace span opened across a `with <lock>:` boundary on "
             "a serve-path module — spans time WORK, not lock waits; "
-            "record the span with explicit timestamps "
-            "(trace.current().add_span(name, t0, t1)) around the "
-            "work itself, outside the lock acquisition"
+            "take the lock first and open observe.span inside it, and "
+            "record the wait as its own observe.interval(name, t0, t1)"
         )
         # combined single-statement form: `with tracer.span(...),
         # self._lock:` acquires the lock INSIDE the span timing when the

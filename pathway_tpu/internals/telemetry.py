@@ -113,6 +113,16 @@ class Telemetry:
                 s.set_attribute(k, v)
             yield s
 
+    def export_span(
+        self, name: str, start_unix_ns: int, end_unix_ns: int, **attributes: Any
+    ) -> None:
+        """A finished span with its measured start and end
+        (``observe.span`` exports through this)."""
+        s = self.tracer.start_span(name, start_time=start_unix_ns)
+        for k, v in attributes.items():
+            s.set_attribute(k, v)
+        s.end(end_time=end_unix_ns)
+
     def shutdown(self) -> None:
         for p in (self._tracer_provider, self._meter_provider):
             if p is not None:

@@ -18,9 +18,14 @@ Design constraints, in order:
 1. **Nearly free.**  Fixed-slot power-of-two-bucket histograms (one
    ``bit_length`` + three increments per event), pre-resolved series
    objects on the hot sites, a bounded pre-allocated event ring, and
-   scrape-time *providers* for anything derivable from live state.  The
-   ``observe_overhead`` bench phase prices the recorder on-vs-off; the
-   budget is < 3% added serve latency.
+   scrape-time *providers* for anything derivable from live state.  What
+   it costs on the chip (PERF.md, ISSUE 24; ``vs1m-query-open`` at 1,200
+   requests/s, ``latency_p50_ms``): 6.15 ms with everything on against
+   5.36-5.40 with ``PATHWAY_OBSERVE=0``, about 14% — the host is one GIL
+   with the scheduler thread at 77% load, so every microsecond there
+   costs several in the median.  One bracket costs 1.5 us (2.4 with a
+   histogram) on that host; a thread-CPU clock read 5.9 us, which is why
+   the CPU twin is sampled.
 2. **Analyzer-clean.**  The recorder itself passes the PR 2
    lock-discipline / hidden-sync / recompile-hazard rules: locks are
    held only for integer updates, instrumentation points sit outside
@@ -31,6 +36,12 @@ Design constraints, in order:
    ``pathway_exchange_*`` plane counters — plus a ``/serve_stats`` JSON
    view and OTLP spans via ``internals/telemetry.py`` when an endpoint
    is configured.
+4. **One primitive per stage boundary** (``spans.py``): ``observe.span``
+   brackets host work on the calling thread and feeds one pair of clock
+   reads to the wall histogram, its thread-CPU twin, the active trace
+   tree and a ``jax.profiler.TraceAnnotation("pw.<name>")`` on the device
+   trace's clock; ``observe.interval`` records what crossed threads or
+   was a wait (histogram + tree, no profiler event).
 
 ``PATHWAY_OBSERVE=0`` (or ``set_enabled(False)``) reduces every record
 call to a bool check.
@@ -59,7 +70,6 @@ from .recorder import (
     Gauge,
     count,
     counter,
-    emit_span,
     enabled,
     gauge,
     histogram,
@@ -72,6 +82,7 @@ from .recorder import (
     set_enabled,
     snapshot,
 )
+from .spans import interval, serve_stage, span
 
 __all__ = [
     "Counter",
@@ -82,11 +93,11 @@ __all__ = [
     "bucket_bounds_s",
     "count",
     "counter",
-    "emit_span",
     "enabled",
     "gauge",
     "hbm",
     "histogram",
+    "interval",
     "next_id",
     "profile",
     "record_event",
@@ -94,8 +105,10 @@ __all__ = [
     "register_provider",
     "render_prometheus",
     "reset",
+    "serve_stage",
     "set_enabled",
     "slo",
     "snapshot",
+    "span",
     "trace",
 ]

@@ -45,10 +45,13 @@ def _bucket_index(ns: int) -> int:
     return i
 
 
+_BOUNDS_S = tuple((1 << (_SHIFT + i)) * 1e-9 for i in range(N_BUCKETS - 1))
+
+
 def bucket_bounds_s() -> List[float]:
     """Upper bounds of the finite buckets, in seconds (the Prometheus
     ``le`` values; the +Inf bucket is implicit)."""
-    return [(1 << (_SHIFT + i)) * 1e-9 for i in range(N_BUCKETS - 1)]
+    return list(_BOUNDS_S)
 
 
 class LatencyHistogram:
@@ -77,10 +80,16 @@ class LatencyHistogram:
     def observe_ns(self, ns: int) -> None:
         if not _state.enabled:
             return
-        i = _bucket_index(ns)
+        ns = int(ns)
+        # _bucket_index, inlined: this is the hottest line of the recorder
+        i = (ns - 1).bit_length() - _SHIFT if ns > 0 else 0
+        if i < 0:
+            i = 0
+        elif i > N_BUCKETS - 1:
+            i = N_BUCKETS - 1
         with self._lock:
             self._counts[i] += 1
-            self._sum_ns += int(ns)
+            self._sum_ns += ns
             self._n += 1
 
     def observe_s(self, seconds: float) -> None:
@@ -144,14 +153,13 @@ class LatencyHistogram:
         counts, _sum_ns, n = self.snapshot()
         if n == 0:
             return None
-        bounds = bucket_bounds_s()
         target = q * n
         cum = 0
         for i, c in enumerate(counts):
             cum += c
             if cum >= target:
-                return bounds[min(i, N_BUCKETS - 2)]
-        return bounds[-1]
+                return _BOUNDS_S[min(i, N_BUCKETS - 2)]
+        return _BOUNDS_S[-1]
 
 
 class EventRing:

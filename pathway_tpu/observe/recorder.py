@@ -17,8 +17,8 @@ Three ways data gets here, by cost profile:
   ``/serve_stats`` JSON view (capacity slots, overwrite-oldest).
 
 ``set_enabled(False)`` (or ``PATHWAY_OBSERVE=0``) turns every record
-call into an early-return bool check — the knob the ``observe_overhead``
-bench phase flips to price the recorder itself.  Rendering snapshots
+call into an early-return bool check (what it costs when on, measured on
+the chip, is in the package docstring and PERF.md, ISSUE 24).  Rendering snapshots
 each series before formatting, so scraped histogram buckets are
 cumulative and monotone even under concurrent writes.
 """
@@ -39,7 +39,6 @@ __all__ = [
     "Gauge",
     "count",
     "counter",
-    "emit_span",
     "enabled",
     "gauge",
     "histogram",
@@ -67,7 +66,8 @@ def set_enabled(flag: bool) -> None:
 
 
 class Counter:
-    """Monotone counter; ``inc`` is the hot-path entry."""
+    """Monotone counter; ``inc`` is the hot-path entry (whole events, or
+    seconds for the ``*_seconds_total`` families)."""
 
     __slots__ = ("_value", "_lock")
 
@@ -75,14 +75,14 @@ class Counter:
         self._value = 0
         self._lock = threading.Lock()
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         if not _state.enabled:
             return
         with self._lock:
             self._value += n
 
     @property
-    def value(self) -> int:
+    def value(self) -> float:
         return self._value
 
     def reset(self) -> None:
@@ -233,47 +233,6 @@ def _provider_samples() -> List[Tuple[str, str, _LabelKey, float]]:
             continue
     samples.sort(key=lambda s: (s[1], s[2]))
     return samples
-
-
-# -- OTLP spans ----------------------------------------------------------
-_telemetry = None
-_spans_on: Optional[bool] = None
-
-
-def emit_span(name: str, **attributes: Any) -> None:
-    """Emit one span for the current instant: onto the ACTIVE per-request
-    trace (observe/trace.py — the round-13 rework of what used to be an
-    OTLP-only stub) and, when an endpoint is configured
-    (PATHWAY_MONITORING_SERVER), as an OTLP span through
-    ``internals/telemetry.py``.  The span carries the measured stage
-    durations as attributes — serve timing is measured by the recorder,
-    the span is its export.  Gated on the same global switch as every
-    other record call — PATHWAY_OBSERVE=0 silences span export too."""
-    global _telemetry, _spans_on
-    if not _state.enabled:
-        return
-    from . import trace as _trace  # lazy: trace.py imports this module
-
-    t = _trace.current()
-    if t is not None:
-        t.add_event(name, **attributes)
-    if _spans_on is False:
-        return
-    if _spans_on is None:
-        try:
-            from ..internals.telemetry import NoopTelemetry, maybe_telemetry
-
-            _telemetry = maybe_telemetry()
-            _spans_on = not isinstance(_telemetry, NoopTelemetry)
-        except Exception:
-            _spans_on = False
-        if not _spans_on:
-            return
-    try:
-        with _telemetry.span(name, **attributes):
-            pass
-    except Exception:
-        pass
 
 
 # -- rendering -----------------------------------------------------------

@@ -23,10 +23,16 @@ Model
   rider's tree holds a ``batch`` span with the queue wait and the batch
   trace id — ``/traces`` inlines the linked batch tree so a rider's view
   shows who it rode with and where the shared time went.
-- Spans carry EXPLICIT timestamps (``add_span(name, t0_ns, t1_ns)``) —
-  the serve path already measures its stages for the histograms, so
-  tracing adds no second clock read, and no span context manager is
-  ever held across a lock (the analyzer's span-across-lock rule).
+- Spans carry EXPLICIT timestamps (``add_span(name, t0_ns, t1_ns)``),
+  written by the two calls of ``observe/spans.py``: ``observe.span``
+  brackets host work on the calling thread and feeds its ONE pair of
+  clock reads to the histogram, this tree and the profiler;
+  ``observe.interval`` records what crossed threads or was a wait.  The
+  order at every site is lock-then-span: ``observe.span`` is never held
+  across a lock acquisition (the analyzer's span-across-lock rule), a
+  lock wait is an ``interval`` of its own.  A span opened inside another
+  of the same trace is its child (``parent``), so self time is a span
+  minus its children.
 
 Tail-based sampling
 -------------------
@@ -57,8 +63,11 @@ Cost discipline
 ``PATHWAY_TRACE_SAMPLE``) makes ``start_trace`` return ``None`` after a
 single flag check with zero allocations; every instrumentation site is
 ``t = trace.current()`` / ``if t is None: return`` — one context-var
-read.  The ``tracing_overhead`` bench phase prices the enabled path
-(< 3% p50 at concurrency 16, 2+2 budget intact).
+read.  What the enabled path costs on the chip (PERF.md, ISSUE 24;
+``vs1m-query-open``, ``latency_p50_ms``): 6.15 ms at sample 1.0 against
+5.90-5.96 with ``PATHWAY_TRACE_SAMPLE=0``, about 4%.  Every tree node
+costs every request (a first version with eleven nodes a request-and-batch
+read 7.09 ms), so waits that are gaps between siblings stay gaps.
 
 Chaos: the ``trace.record`` / ``trace.export`` sites (robust/inject.py)
 prove that a faulted tracing path degrades to DROPPED spans (counted on
@@ -143,7 +152,7 @@ _CURRENT: "ContextVar[Optional[TraceContext]]" = ContextVar(
 
 _store_lock = threading.Lock()
 _kept: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
-_pending: "OrderedDict[str, Dict[str, Any]]" = OrderedDict()
+_pending: "OrderedDict[str, tuple]" = OrderedDict()  # finish()'s entries
 _kept_evicted = 0
 _pending_evicted = 0
 _started = 0
@@ -201,7 +210,7 @@ class TraceContext:
         "trace_id", "name", "kind", "t0_ns", "deadline", "spans",
         "statuses", "links", "attrs", "dispatches", "fetches",
         "physical_dispatches", "dropped", "finished", "force_keep",
-        "_lock", "_next_sid",
+        "_lock", "_sids",
     )
 
     def __init__(self, name: str, kind: str, deadline=None):
@@ -221,7 +230,7 @@ class TraceContext:
         self.finished = False
         self.force_keep = False
         self._lock = threading.Lock()
-        self._next_sid = 2
+        self._sids = itertools.count(2)  # next() is atomic: no lock
 
     # -- span recording -----------------------------------------------------
     def add_span(
@@ -232,32 +241,57 @@ class TraceContext:
         status: str = "ok",
         parent: int = 1,
         exemplar=None,
+        sid: Optional[int] = None,
         **attrs: Any,
     ) -> int:
         """Record one finished span with explicit timestamps (the serve
         path measures its stages anyway — tracing reuses those clock
         reads).  ``exemplar`` is the LatencyHistogram this duration was
         also observed into: if the trace is KEPT, the trace id is
-        stamped onto that histogram's matching bucket.  Returns the span
-        id (0 = dropped: trace full, finished, or chaos-faulted)."""
-        if not _record_allowed("trace.record"):
+        stamped onto that histogram's matching bucket.  ``sid`` is an id
+        taken earlier with ``reserve_span_id`` (a span that had children
+        before it ended).  Returns the span id (0 = dropped: trace full,
+        finished, or chaos-faulted)."""
+        return self.record(
+            sid, int(parent), str(name), int(t0_ns),
+            max(0, int(t1_ns) - int(t0_ns)), str(status), attrs or None,
+            exemplar,
+        )
+
+    def record(
+        self, sid, parent, name, t0_ns, dur_ns, status, attrs, exemplar
+    ) -> int:
+        """``add_span`` without the keyword packing and coercions: the
+        entry ``observe.span`` / ``observe.interval`` use, with values
+        already of the right types."""
+        inj = _inject_mod or _inject()
+        if inj is not None and inj.any_armed() and not _record_allowed(
+            "trace.record"
+        ):
             with self._lock:
                 self.dropped += 1
             _C_SPANS_DROPPED.inc()
             return 0
+        if sid is None:
+            sid = next(self._sids)
         with self._lock:
             if self.finished or len(self.spans) >= _MAX_SPANS:
                 self.dropped += 1
-                _C_SPANS_DROPPED.inc()
-                return 0
-            sid = self._next_sid
-            self._next_sid += 1
-            self.spans.append((
-                sid, int(parent), str(name), int(t0_ns),
-                max(0, int(t1_ns) - int(t0_ns)), str(status),
-                attrs or None, exemplar,
-            ))
+                dropped = True
+            else:
+                dropped = False
+                self.spans.append(
+                    (sid, parent, name, t0_ns, dur_ns, status, attrs, exemplar)
+                )
+        if dropped:
+            _C_SPANS_DROPPED.inc()
+            return 0
         return sid
+
+    def reserve_span_id(self) -> int:
+        """An id for a span that is still open, so that spans ending
+        inside it can name it as their ``parent``."""
+        return next(self._sids)
 
     def add_event(self, name: str, status: str = "ok", **attrs: Any) -> int:
         """A zero-duration annotation span (cache hit/miss, shard skip,
@@ -373,10 +407,24 @@ def _keep_reason(ctx: TraceContext, dur_ns: int) -> Optional[str]:
             pass
     h = _SLOW_HISTS.get(ctx.kind)
     if h is not None and h.count >= _SLOW_MIN_COUNT:
-        q = h.quantile_s(_SLOW_PCT)
+        q = _slow_threshold_s(ctx.kind, h)
         if q is not None and dur_ns * 1e-9 >= q:
             return "slow"
     return None
+
+
+# kind -> (histogram count when scanned, quantile): the scan runs on every
+# finish of every request and batch, and a power-of-two bucket bound moves
+# rarely, so it is redone once per _SLOW_MIN_COUNT new observations
+_slow_cache: Dict[str, Tuple[int, Optional[float]]] = {}
+
+
+def _slow_threshold_s(kind: str, h) -> Optional[float]:
+    n = h.count
+    cached = _slow_cache.get(kind)
+    if cached is None or not 0 <= n - cached[0] < _SLOW_MIN_COUNT:
+        cached = _slow_cache[kind] = (n, h.quantile_s(_SLOW_PCT))
+    return cached[1]
 
 
 def _keep(record: Dict[str, Any], reason: str) -> None:
@@ -428,11 +476,35 @@ def finish(
     dur_ns = time.perf_counter_ns() - ctx.t0_ns
     if ctx.kind == "request":
         _H_REQUEST.observe_ns(dur_ns)
-    record: Dict[str, Any] = {
+    entry = (ctx, spans, links, dur_ns, time.time())
+    reason = _keep_reason(ctx, dur_ns)
+    if reason is None:
+        # parked as it is: the record is built only if a kept rider's link
+        # promotes it (99 of 100 traces are dropped from here)
+        with _store_lock:
+            _pending[ctx.trace_id] = entry
+            while len(_pending) > _PENDING_CAPACITY:
+                _pending.popitem(last=False)
+                _pending_evicted += 1
+        _C_SAMPLED_OUT.inc()
+        return None
+    _keep(_record(*entry), reason)
+    # link promotion: a kept rider must be able to resolve its batch —
+    # pull the linked traces out of the pending ring into the kept store
+    for lid in links:
+        with _store_lock:
+            linked = _pending.pop(lid, None)
+        if linked is not None:
+            _keep(_record(*linked), "linked")
+    return reason
+
+
+def _record(ctx: TraceContext, spans, links, dur_ns: int, ts: float) -> Dict[str, Any]:
+    return {
         "trace_id": ctx.trace_id,
         "name": ctx.name,
         "kind": ctx.kind,
-        "ts": time.time(),
+        "ts": ts,
         "duration_ms": dur_ns * 1e-6,
         "statuses": list(ctx.statuses),
         "dispatches": ctx.dispatches,
@@ -446,24 +518,6 @@ def finish(
         "_dur_ns": dur_ns,
         "_spans": spans,
     }
-    reason = _keep_reason(ctx, dur_ns)
-    if reason is None:
-        with _store_lock:
-            _pending[ctx.trace_id] = record
-            while len(_pending) > _PENDING_CAPACITY:
-                _pending.popitem(last=False)
-                _pending_evicted += 1
-        _C_SAMPLED_OUT.inc()
-        return None
-    _keep(record, reason)
-    # link promotion: a kept rider must be able to resolve its batch —
-    # pull the linked traces out of the pending ring into the kept store
-    for lid in links:
-        with _store_lock:
-            linked = _pending.pop(lid, None)
-        if linked is not None:
-            _keep(linked, "linked")
-    return reason
 
 
 # -- export ------------------------------------------------------------------
@@ -501,7 +555,8 @@ def _tree(
         "children": [],
     }
     nodes: Dict[int, Dict[str, Any]] = {1: root}
-    for span in sorted(record["_spans"], key=lambda s: (s[3], s[0])):
+    spans = sorted(record["_spans"], key=lambda s: (s[3], s[0]))
+    for span in spans:
         d = _span_dict(record, span)
         d["children"] = []
         attrs = span[6] or {}
@@ -511,7 +566,10 @@ def _tree(
             if target is not None:
                 d["linked"] = _tree(target, index, inline=False)
         nodes[span[0]] = d
-        nodes.get(span[1], root)["children"].append(d)
+    # a parent is recorded when it ENDS, after its children: attach only
+    # once every node exists (a parent that was dropped falls to the root)
+    for span in spans:
+        nodes.get(span[1], root)["children"].append(nodes[span[0]])
     out = {k: v for k, v in record.items() if not k.startswith("_")}
     out["root"] = root
     return out
@@ -589,6 +647,7 @@ def reset() -> None:
     with _store_lock:
         _kept.clear()
         _pending.clear()
+        _slow_cache.clear()
         _kept_evicted = 0
         _pending_evicted = 0
     _started = 0

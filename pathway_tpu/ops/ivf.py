@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import observe
-from ..observe import hbm, profile, trace
+from ..observe import hbm, profile
 from ..robust import (
     RetryPolicy,
     TAIL_SKIPPED,
@@ -66,6 +66,10 @@ _TAIL_RETRY = RetryPolicy(attempts=3, base_delay_s=0.002, max_delay_s=0.02)
 # maintenance-duration histograms (flight recorder): absorb/retrain wall
 # time, observed from the maintenance threads AFTER their lock sections
 _H_ABSORB = observe.histogram("pathway_ivf_absorb_seconds")
+# an absorb's halves: the plan runs off the index lock, the commit under it
+# (the part that holds stage-1 dispatch off)
+_H_ABSORB_PLAN = observe.histogram("pathway_ivf_absorb_stage_seconds", stage="plan")
+_H_ABSORB_COMMIT = observe.histogram("pathway_ivf_absorb_stage_seconds", stage="commit")
 _H_RETRAIN = observe.histogram("pathway_ivf_retrain_seconds")
 
 
@@ -705,10 +709,15 @@ class IvfKnnIndex:
                         snap = self._absorb_snapshot()
                     if snap is None:
                         return
-                    plan = self._plan_absorb(snap)
-                    with self._lock:
+                    with observe.span("ivf.absorb.plan", hist=_H_ABSORB_PLAN):
+                        plan = self._plan_absorb(snap)
+                    # lock, then span: the bracket is the time serving is
+                    # held off, not this thread's wait for the lock
+                    with self._lock, observe.span(
+                        "ivf.absorb.commit", hist=_H_ABSORB_COMMIT
+                    ) as commit:
                         self._commit_absorb(snap, plan)
-                    _H_ABSORB.observe_ns(time.perf_counter_ns() - t0)
+                    observe.interval("ivf.absorb", t0, commit.t1_ns, hist=_H_ABSORB)
                     return
                 except Exception as exc:
                     with self._lock:
@@ -924,66 +933,58 @@ class IvfKnnIndex:
         cache = self._tail_cache
         if cache is None:
             self.stats["tail_cache_misses"] += 1
-            t_up0 = time.perf_counter_ns()
-            tail, tail_mat, tail_valid, t_pad = self._tail_snapshot()
+            # a serve that paid the (cache-miss) tail re-upload shows it as
+            # its own span — the classic "why was THIS one slow" answer
+            # after an absorb invalidated the cache
+            with observe.span("ivf.tail_upload") as upload:
+                tail, tail_mat, tail_valid, t_pad = self._tail_snapshot()
+                upload.set(rows=t_pad)
 
-            def _upload():
-                if t_pad:
+                def _upload():
+                    if t_pad:
+                        return (
+                            jnp.asarray(tail_mat[:t_pad], self.dtype),
+                            jnp.asarray(tail_valid[:t_pad]),
+                        )
+                    # placeholder shapes for the tail-less kernel signature
                     return (
-                        jnp.asarray(tail_mat[:t_pad], self.dtype),
-                        jnp.asarray(tail_valid[:t_pad]),
+                        jnp.asarray(
+                            np.zeros((1, self.dimension), np.float32),
+                            self.dtype,
+                        ),
+                        jnp.asarray(np.zeros(1, bool)),
                     )
-                # placeholder shapes for the tail-less kernel signature
-                return (
-                    jnp.asarray(
-                        np.zeros((1, self.dimension), np.float32), self.dtype
-                    ),
-                    jnp.asarray(np.zeros(1, bool)),
-                )
 
-            try:
-                # transient upload failures retry briefly (the caller
-                # holds the index lock, so the budget is milliseconds);
-                # "ivf.tail_upload" is the chaos-suite fault site
-                dev_mat, dev_valid = retry_call(
-                    "ivf.tail_upload", _upload, policy=_TAIL_RETRY
-                )
-            except Exception as exc:
-                # degradation ladder: tail unavailable ⇒ serve resident-
-                # only results, flagged + counted.  NOT cached, so the
-                # next serve retries the upload and recovery is automatic.
-                log_once(
-                    f"ivf.tail_upload:{type(exc).__name__}",
-                    "IVF exact-tail device upload failed (%r); serving "
-                    "resident-only (tail_skipped) until it recovers",
-                    exc,
-                )
-                record_degraded(TAIL_SKIPPED)
-                self.tail_degraded = True
-                _t = trace.current()
-                if _t is not None:
-                    _t.add_span(
-                        "ivf.tail_upload", t_up0, time.perf_counter_ns(),
-                        status=TAIL_SKIPPED, error=type(exc).__name__,
+                try:
+                    # transient upload failures retry briefly (the caller
+                    # holds the index lock, so the budget is milliseconds);
+                    # "ivf.tail_upload" is the chaos-suite fault site
+                    dev_mat, dev_valid = retry_call(
+                        "ivf.tail_upload", _upload, policy=_TAIL_RETRY
                     )
-                return (
-                    [],
-                    jnp.asarray(
-                        np.zeros((1, self.dimension), np.float32), self.dtype
-                    ),
-                    jnp.asarray(np.zeros(1, bool)),
-                    0,
-                )
+                except Exception as exc:
+                    # degradation ladder: tail unavailable ⇒ serve resident-
+                    # only results, flagged + counted.  NOT cached, so the
+                    # next serve retries the upload and recovery is automatic.
+                    log_once(
+                        f"ivf.tail_upload:{type(exc).__name__}",
+                        "IVF exact-tail device upload failed (%r); serving "
+                        "resident-only (tail_skipped) until it recovers",
+                        exc,
+                    )
+                    record_degraded(TAIL_SKIPPED)
+                    self.tail_degraded = True
+                    upload.set(status=TAIL_SKIPPED, error=type(exc).__name__)
+                    return (
+                        [],
+                        jnp.asarray(
+                            np.zeros((1, self.dimension), np.float32),
+                            self.dtype,
+                        ),
+                        jnp.asarray(np.zeros(1, bool)),
+                        0,
+                    )
             self.tail_degraded = False
-            _t = trace.current()
-            if _t is not None:
-                # a serve that paid the (cache-miss) tail re-upload shows
-                # it as its own span — the classic "why was THIS one
-                # slow" answer after an absorb invalidated the cache
-                _t.add_span(
-                    "ivf.tail_upload", t_up0, time.perf_counter_ns(),
-                    rows=t_pad,
-                )
             cache = (tail, dev_mat, dev_valid, t_pad)
             self._tail_cache = cache
         else:

@@ -102,13 +102,21 @@ _STAGE2_RETRY = RetryPolicy(attempts=3, base_delay_s=0.002, max_delay_s=0.02)
 # failures otherwise)
 _OUTER_RETRY = RetryPolicy(attempts=1)
 
-# flight-recorder stage histograms: stage2_pack is host-side pair
-# assembly + packing up to the rescore dispatch; stage2_rtt is the
-# rescore dispatch→fetch; postprocess (shared series with stage 1's
-# completion in ops/serving.py) is host result assembly.
+# flight-recorder stage series, each fed by one ``observe.span`` /
+# ``observe.interval`` (see ops/serving.py).  Per batch, by the same clock
+# reads: stage2_gather + stage2_packrows + stage2_dispatch == stage2_pack.
+# The older series keep their boundaries: stage2_pack = pair assembly +
+# packing up to the rescore dispatch returning, stage2_rtt = rescore
+# dispatch → fetched, postprocess = host result assembly (shared with
+# stage 1's completion).
 _H_S2PACK = observe.histogram("pathway_serve_stage_seconds", stage="stage2_pack")
 _H_S2RTT = observe.histogram("pathway_serve_stage_seconds", stage="stage2_rtt")
 _H_POST = observe.histogram("pathway_serve_stage_seconds", stage="postprocess")
+_S2_GATHER = observe.serve_stage("stage2_gather")
+_S2_PACKROWS = observe.serve_stage("stage2_packrows")
+_S2_DISPATCH = observe.serve_stage("stage2_dispatch")
+_S2_FETCH = observe.serve_stage("stage2_fetch", cpu=False)
+_S2_POST = observe.serve_stage("stage2_postprocess")
 
 
 # -- pluggable rerank stages -------------------------------------------------
@@ -591,13 +599,10 @@ class RetrieveRerankPipeline:
         stage_budget_ms: List[Optional[float]] = [None] * len(stages)
 
         def stage_span(i: int, status: str, t_end: int, **attrs) -> None:
-            _t = trace.current()
-            if _t is None:
-                return
-            t0 = t_stage[i] or t_end
-            _t.add_span(
-                "stage." + stages[i].name, t0, t_end, status=status,
-                budget_ms=stage_budget_ms[i], keep=keeps[i], **attrs,
+            observe.interval(
+                "stage." + stages[i].name, t_stage[i] or t_end, t_end,
+                status=status, budget_ms=stage_budget_ms[i], keep=keeps[i],
+                **attrs,
             )
 
         def skip(stage: RerankStage, exc: BaseException) -> None:
@@ -780,7 +785,6 @@ class RetrieveRerankPipeline:
         over-fetch — the classic single-stage configuration)."""
         from ..models.encoder import _bucket
 
-        t_pack = time.perf_counter_ns()
         ce = self.cross_encoder
         Kc = pool or self.candidates
         k_out = min(k, Kc)
@@ -788,10 +792,12 @@ class RetrieveRerankPipeline:
         pairs: List[Tuple[str, str]] = []
         slot_ids: List[int] = []
         missing: List[int] = []
-        for qi, row in enumerate(cand_keys):
-            for j, key in enumerate(row[:Kc]):
-                pairs.append((queries[qi], self._text_of(key, missing)))
-                slot_ids.append(qi * Kc + j)
+        with observe.span("stage2.gather", **_S2_GATHER) as gather:
+            for qi, row in enumerate(cand_keys):
+                for j, key in enumerate(row[:Kc]):
+                    pairs.append((queries[qi], self._text_of(key, missing)))
+                    slot_ids.append(qi * Kc + j)
+            gather.set(pairs=len(pairs))
         meta = {"missing_docs": tuple(missing)} if missing else None
         if not pairs:
             return lambda: ServeResult(
@@ -799,7 +805,7 @@ class RetrieveRerankPipeline:
             )
         if getattr(ce, "_hf", False):
             return self._submit_stage2_host(
-                queries, cand_keys, pairs, k_out,
+                queries, cand_keys, pairs, k_out, gather,
                 deadline=deadline, stage1_flags=stage1_flags, meta=meta,
                 pool=Kc,
             )
@@ -809,50 +815,51 @@ class RetrieveRerankPipeline:
         # pack OFF every lock: tokenization + row packing are pure host
         # work on stateless helpers, and under the coalescing scheduler
         # batch N+1's pack must overlap batch N's device time
-        ids, segments, positions, doc_slots, n_seg = ce._pack_pairs(pairs)
-        rows_real = ids.shape[0]
-        Rb = _bucket(rows_real)
-        L = ids.shape[1]
-        ids, segments, positions = pad_packed_rows(ids, segments, positions, Rb)
-        Sb = seg_bucket(n_seg)
-        pair_slot = np.full(Rb * Sb, Qb * Kc, np.int32)  # default: dropped
-        for i, (r, s) in enumerate(doc_slots):
-            pair_slot[r * Sb + s] = slot_ids[i]
-        fn = self._compiled_stage2(Rb, L, Sb, Qb, k_out, Kc=Kc)
-        # retry transient dispatch failures; the per-model breaker both
-        # gates the attempts (CircuitOpen fast-fails to the ladder) and
-        # learns from their outcomes ("rerank.dispatch" is the chaos site)
-        out = retry_call(
-            "rerank.dispatch",
-            fn,
-            ce.params,
-            jnp.asarray(ids),
-            jnp.asarray(segments),
-            jnp.asarray(positions),
-            jnp.asarray(pair_slot),
-            deadline=deadline,
-            policy=_STAGE2_RETRY,
-            breaker=self._breaker,
-        )
-        record_dispatch("rerank_stage2")
-        if hasattr(out, "copy_to_host_async"):
-            out.copy_to_host_async()
+        with observe.span("stage2.pack", after=gather, **_S2_PACKROWS) as pack:
+            ids, segments, positions, doc_slots, n_seg = ce._pack_pairs(pairs)
+            rows_real = ids.shape[0]
+            Rb = _bucket(rows_real)
+            L = ids.shape[1]
+            ids, segments, positions = pad_packed_rows(
+                ids, segments, positions, Rb
+            )
+            Sb = seg_bucket(n_seg)
+            pair_slot = np.full(Rb * Sb, Qb * Kc, np.int32)  # default: dropped
+            for i, (r, s) in enumerate(doc_slots):
+                pair_slot[r * Sb + s] = slot_ids[i]
+            pack.set(rows=Rb)
+        with observe.span(
+            "stage2.dispatch", after=pack, **_S2_DISPATCH
+        ) as dispatch:
+            fn = self._compiled_stage2(Rb, L, Sb, Qb, k_out, Kc=Kc)
+            # retry transient dispatch failures; the per-model breaker both
+            # gates the attempts (CircuitOpen fast-fails to the ladder) and
+            # learns from their outcomes ("rerank.dispatch" is the chaos site)
+            out = retry_call(
+                "rerank.dispatch",
+                fn,
+                ce.params,
+                jnp.asarray(ids),
+                jnp.asarray(segments),
+                jnp.asarray(positions),
+                jnp.asarray(pair_slot),
+                deadline=deadline,
+                policy=_STAGE2_RETRY,
+                breaker=self._breaker,
+            )
+            record_dispatch("rerank_stage2")
+            if hasattr(out, "copy_to_host_async"):
+                out.copy_to_host_async()
         with self._lock:
             self.stats["stage2_pairs"] += len(pairs)
             self.stats["stage2_rows"] += Rb
-        t_dispatch = time.perf_counter_ns()
+        t_pack, t_dispatch = gather.t0_ns, dispatch.t1_ns
         _H_S2PACK.observe_ns(t_dispatch - t_pack)
         # packing occupancy, both granularities: packed ROWS actually
         # carrying tokens vs the bucketed row count, and real PAIR
         # segments vs the padded [Rb, Sb] segment grid
         observe.record_occupancy("stage2", rows_real, Rb)
         observe.record_occupancy("stage2_pairs", len(pairs), Rb * Sb)
-        _t = trace.current()
-        if _t is not None:
-            _t.add_span(
-                "stage2.pack_dispatch", t_pack, t_dispatch,
-                exemplar=_H_S2PACK, pairs=len(pairs), rows=Rb,
-            )
 
         def complete() -> List[List[Tuple[int, float]]]:
             inject.fire("cross_encoder.fetch", deadline=deadline)
@@ -862,40 +869,31 @@ class RetrieveRerankPipeline:
                 # caller (_PendingServe) converts this into the
                 # rerank_skipped rung instead of waiting longer
                 deadline.check("cross_encoder.fetch")
-            arr = np.asarray(out)[:nq]
+            with observe.span("stage2.fetch", **_S2_FETCH) as fetch:
+                arr = np.asarray(out)[:nq]
             record_fetch("rerank_stage2")
-            t_fetch = time.perf_counter_ns()
-            _H_S2RTT.observe_ns(t_fetch - t_dispatch)
-            _ct = trace.current()
-            if _ct is not None:
-                _ct.add_span(
-                    "stage2.rtt", t_dispatch, t_fetch, exemplar=_H_S2RTT
-                )
-            scores = np.ascontiguousarray(arr[:, :k_out]).view(np.float32)
-            perm = arr[:, k_out:]
+            _H_S2RTT.observe_ns(fetch.t1_ns - t_dispatch)
             results: List[List[Tuple[int, float]]] = []
-            for qi in range(nq):
-                row: List[Tuple[int, float]] = []
-                cands = cand_keys[qi]
-                for j in range(k_out):
-                    s = float(scores[qi, j])
-                    ci = int(perm[qi, j])
-                    if not np.isfinite(s) or ci >= len(cands):
-                        continue
-                    row.append((cands[ci], s))
-                results.append(row[:k])
-            t_done = time.perf_counter_ns()
-            _H_POST.observe_ns(t_done - t_fetch)
-            observe.record_event(
-                "serve", "rerank_stage2", t_done - t_pack,
+            with observe.span("stage2.postprocess", **_S2_POST) as post:
+                scores = np.ascontiguousarray(arr[:, :k_out]).view(np.float32)
+                perm = arr[:, k_out:]
+                for qi in range(nq):
+                    row: List[Tuple[int, float]] = []
+                    cands = cand_keys[qi]
+                    for j in range(k_out):
+                        s = float(scores[qi, j])
+                        ci = int(perm[qi, j])
+                        if not np.isfinite(s) or ci >= len(cands):
+                            continue
+                        row.append((cands[ci], s))
+                    results.append(row[:k])
+            _H_POST.observe_ns(post.t1_ns - post.t0_ns)
+            # the whole of stage 2, pack → done, across the threads it ran
+            # on: the /serve_stats ring's serve event (the tree has it as
+            # the cascade's ``stage.cross_encoder`` span)
+            observe.interval(
+                "rerank_stage2", t_pack, post.t1_ns, ring=True, tree=None,
                 queries=nq, pairs=len(pairs), rows=Rb,
-            )
-            observe.emit_span(
-                "pathway.serve.rerank_stage2",
-                queries=nq, pairs=len(pairs),
-                pack_ms=(t_dispatch - t_pack) * 1e-6,
-                rtt_ms=(t_fetch - t_dispatch) * 1e-6,
-                postprocess_ms=(t_done - t_fetch) * 1e-6,
             )
             return ServeResult(results, degraded=stage1_flags, meta=meta)
 
@@ -907,6 +905,7 @@ class RetrieveRerankPipeline:
         cand_keys,
         pairs,
         k_out,
+        gather,
         deadline: Optional[Deadline] = None,
         stage1_flags: Sequence[str] = (),
         meta=None,
@@ -914,29 +913,32 @@ class RetrieveRerankPipeline:
     ):
         """HF fallback: unpacked async scoring + host-side per-query sort
         (HF modules take no segment inputs; still one dispatch + one fetch,
-        just a max-length-padded batch)."""
+        just a max-length-padded batch).  ``gather`` is the caller's
+        pair-gathering span (``stage2_pack`` runs from its start)."""
         from ..models.encoder import _bucket
 
-        t_pack = time.perf_counter_ns()
         # the lambda forwards the deadline to the MODEL's submit (so its
         # inner "cross_encoder.dispatch" retries and its completion-time
         # check are budget-bounded) — retry_call's own deadline= kwarg is
         # consumed by the wrapper and would otherwise never reach it
-        score_done = retry_call(
-            "rerank.dispatch",
-            lambda: self.cross_encoder.submit(
-                pairs, packed=False, deadline=deadline
-            ),
-            deadline=deadline,
-            policy=_OUTER_RETRY,
-            breaker=self._breaker,
-        )
-        record_dispatch("rerank_stage2_host")
+        with observe.span(
+            "stage2.dispatch", after=gather, host=True, **_S2_DISPATCH
+        ) as dispatch:
+            score_done = retry_call(
+                "rerank.dispatch",
+                lambda: self.cross_encoder.submit(
+                    pairs, packed=False, deadline=deadline
+                ),
+                deadline=deadline,
+                policy=_OUTER_RETRY,
+                breaker=self._breaker,
+            )
+            record_dispatch("rerank_stage2_host")
         rows = _bucket(len(pairs))  # one row per pair
         with self._lock:
             self.stats["stage2_pairs"] += len(pairs)
             self.stats["stage2_rows"] += rows
-        t_dispatch = time.perf_counter_ns()
+        t_pack, t_dispatch = gather.t0_ns, dispatch.t1_ns
         _H_S2PACK.observe_ns(t_dispatch - t_pack)
         observe.record_occupancy("stage2", len(pairs), rows)
 
@@ -944,31 +946,25 @@ class RetrieveRerankPipeline:
             inject.fire("cross_encoder.fetch", deadline=deadline)
             if deadline is not None:
                 deadline.check("cross_encoder.fetch")
-            flat = score_done()
+            with observe.span("stage2.fetch", host=True, **_S2_FETCH) as fetch:
+                flat = score_done()
             record_fetch("rerank_stage2_host")
-            t_fetch = time.perf_counter_ns()
-            _H_S2RTT.observe_ns(t_fetch - t_dispatch)
-            _ct = trace.current()
-            if _ct is not None:
-                _ct.add_span(
-                    "stage2.rtt", t_dispatch, t_fetch,
-                    exemplar=_H_S2RTT, host=True,
-                )
+            _H_S2RTT.observe_ns(fetch.t1_ns - t_dispatch)
             results: List[List[Tuple[int, float]]] = []
-            pos = 0
             width = pool or self.candidates
-            for qi in range(len(queries)):
-                n_c = min(len(cand_keys[qi]), width)
-                scored = list(
-                    zip(cand_keys[qi][:n_c], flat[pos : pos + n_c].tolist())
-                )
-                pos += n_c
-                scored.sort(key=lambda kv: -kv[1])
-                results.append(scored[:k_out])
-            t_done = time.perf_counter_ns()
-            _H_POST.observe_ns(t_done - t_fetch)
-            observe.record_event(
-                "serve", "rerank_stage2_host", t_done - t_pack,
+            with observe.span("stage2.postprocess", **_S2_POST) as post:
+                pos = 0
+                for qi in range(len(queries)):
+                    n_c = min(len(cand_keys[qi]), width)
+                    scored = list(
+                        zip(cand_keys[qi][:n_c], flat[pos : pos + n_c].tolist())
+                    )
+                    pos += n_c
+                    scored.sort(key=lambda kv: -kv[1])
+                    results.append(scored[:k_out])
+            _H_POST.observe_ns(post.t1_ns - post.t0_ns)
+            observe.interval(
+                "rerank_stage2_host", t_pack, post.t1_ns, ring=True, tree=None,
                 queries=len(queries), pairs=len(pairs),
             )
             return ServeResult(results, degraded=stage1_flags, meta=meta)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import observe
-from ..observe import profile, trace
+from ..observe import profile
 from ..models.transformer import TransformerEncoder
 from ..robust import (
     CircuitOpen,
@@ -50,21 +50,46 @@ _LOCKED_DISPATCH_RETRY = RetryPolicy(
     attempts=3, base_delay_s=0.002, max_delay_s=0.02
 )
 
-# flight-recorder stage histograms (pathway_tpu/observe): resolved once
-# at import so the per-serve cost is one observe_ns per stage boundary.
-# tokenize_pack covers host prep (lock wait + tokenize + pad + compiled-fn
-# lookup) up to the dispatch; stage1_rtt is dispatch→fetch-complete of the
-# fused kernel; postprocess is the host-side result assembly.
-#
-# Tracing (observe/trace.py) reuses the SAME clock reads: every span on
-# this path is recorded with the timestamps already taken for these
-# histograms (explicit t0/t1 — no span context manager is ever held
-# across the serve locks), and the histogram objects ride along as
-# exemplar targets so a kept trace stamps its id onto the exact bucket
-# its stage durations landed in.
+# flight-recorder stage series (pathway_tpu/observe), resolved once at
+# import.  Every boundary on this path is ONE ``observe.span`` (host work on
+# the calling thread) or ``observe.interval`` (a wait, or what crossed
+# threads); a span opens only AFTER the serve locks are held, the wait for
+# them is an interval of its own.  Per batch, by the same clock reads:
+# stage1_tokenize + stage1_lock_wait + stage1_dispatch == tokenize_pack.
+# The three older series keep their boundaries (the benchmark reads them):
+# tokenize_pack = host prep up to the dispatch returning, lock wait
+# included; stage1_rtt = dispatch → fetched, on whichever waiter fetched;
+# postprocess = host result assembly (stage 2's completion shares it).
 _H_TOKENIZE = observe.histogram("pathway_serve_stage_seconds", stage="tokenize_pack")
 _H_STAGE1 = observe.histogram("pathway_serve_stage_seconds", stage="stage1_rtt")
 _H_POST = observe.histogram("pathway_serve_stage_seconds", stage="postprocess")
+_S1_TOKENIZE = observe.serve_stage("stage1_tokenize")
+_H_S1_LOCK_WAIT = observe.histogram("pathway_serve_stage_seconds", stage="stage1_lock_wait")
+_S1_DISPATCH = observe.serve_stage("stage1_dispatch")
+_S1_FETCH = observe.serve_stage("stage1_fetch", cpu=False)
+_S1_POST = observe.serve_stage("stage1_postprocess")
+
+
+def _stage1_launched(t_start: int, t_ready: int, dispatch) -> int:
+    """Close stage 1's host side once its dispatch bracket ended: the wait
+    between host prep ready and the bracket opening (the serve locks; on
+    the exact path also its snapshot under them) and the older
+    ``tokenize_pack`` series.  Histograms only: in the batch's tree the
+    lock wait is the gap between ``stage1.tokenize`` and
+    ``stage1.dispatch`` (a tree node costs every batch).  Returns the
+    dispatch instant."""
+    _H_S1_LOCK_WAIT.observe_ns(dispatch.t0_ns - t_ready)
+    _H_TOKENIZE.observe_ns(dispatch.t1_ns - t_start)
+    return dispatch.t1_ns
+
+
+def _stage1_fetch(outs, n_real: int, t_dispatch: int, kind: str):
+    """The blocking fetch of stage 1's packed output(s), on whichever
+    waiter got here first, and the dispatch → fetched round trip it ends."""
+    with observe.span("stage1.fetch", kind=kind, **_S1_FETCH) as fetch:
+        arrs = [np.asarray(out)[:n_real] for out in outs]
+    _H_STAGE1.observe_ns(fetch.t1_ns - t_dispatch)  # the round trip it ends
+    return arrs
 
 
 class FusedEncodeSearch:
@@ -589,6 +614,7 @@ class FusedEncodeSearch:
         n_real: int,
         k: int,
         t_start: int,
+        t_ready: int,
         deadline: Optional[Deadline] = None,
     ):
         """Scatter-dispatch serve over a ``ShardedIvfIndex``: encode the
@@ -626,23 +652,12 @@ class FusedEncodeSearch:
         # the encode launch opens the stage-1 logical dispatch group;
         # its failure (past retries) is a stage-1 outage — the caller's
         # ladder turns it into retrieval_failed
-        if self._exporting():
-            z, qtok = retry_call(
+        with observe.span("stage1.encode", queries=n_real, batch=B):
+            out = retry_call(
                 "serve.dispatch", enc, self.encoder.params, ids, mask,
                 deadline=deadline,
             )
-        else:
-            z = retry_call(
-                "serve.dispatch", enc, self.encoder.params, ids, mask,
-                deadline=deadline,
-            )
-            qtok = None
-        _t = trace.current()
-        if _t is not None:
-            _t.add_span(
-                "stage1.encode", t_start, time.perf_counter_ns(),
-                queries=n_real, batch=B,
-            )
+        z, qtok = out if self._exporting() else (out, None)
         physical = 1  # the encode launch
         outs: List[Any] = []
         snaps: List[Any] = []
@@ -663,38 +678,44 @@ class FusedEncodeSearch:
                 # fires inside retry_call and models transient faults)
                 inject.fire(f"shard.dispatch.{s}", deadline=deadline)
                 with jax.default_device(group.device(s)), child._lock:  # pathway: allow(lock-order): rank exception index(3)<scheduler(5) — the fused-serve pair order is index-before-pipeline at EVERY site (absorb DONATES slab buffers, forcing launch-before-unlock under the shard's index lock; the compiled-getter guard self._lock nests briefly inside), so the pair is globally ordered and deadlock-free
-                    if child._slabs is None:
-                        child.build()  # first build only
-                    else:
-                        child.maybe_retrain_async()
-                    tail, tail_dev, tail_valid_dev, t_pad = (
-                        child._tail_snapshot_device()
-                    )
-                    fn, n_slotspace = self._shard_search_fn(
-                        child, B, k_eff, t_pad
-                    )
-                    # scatter leg: the shared embedding hops to the
-                    # shard's device (async d2d), then the shard kernel
-                    # launches — under the child lock, because a
-                    # concurrent absorb commit DONATES the slab buffers
-                    # (same launch-before-unlock rule as _submit_ivf)
-                    z_s = jax.device_put(z, group.device(s))  # pathway: allow(lock-discipline, value-flow): device→device scatter of an UNFETCHED [B, d] embedding — an async ICI hop enqueued like a dispatch, not a host link round trip; the value is loop-invariant but the TARGET device varies per shard (mirrored in residency.DECLARED_TRANSFERS), and it must precede the launch that consumes it under this lock
-                    out = retry_call(  # pathway: allow(lock-discipline): dispatch-only — donated absorb buffers force launch-before-unlock; the merged fetch happens off-lock in the completion
+                    # lock, then span: the bracket times this shard's
+                    # launch, not the wait for its lock
+                    with observe.span(
                         "shard.dispatch",
-                        fn,
-                        z_s,
-                        child._slabs,
-                        child._bias,
-                        child._centroids
-                        if isinstance(child._centroids, jax.Array)
-                        else jnp.asarray(child._centroids),
-                        tail_dev,
-                        tail_valid_dev,
-                        deadline=deadline,
-                        policy=_LOCKED_DISPATCH_RETRY,
-                        breaker=breaker,
-                    )
-                    keys_by_slot = child._keys_by_slot  # dispatch-time snap
+                        hist=self._shard_hist("dispatch", s), shard=s,
+                    ):
+                        if child._slabs is None:
+                            child.build()  # first build only
+                        else:
+                            child.maybe_retrain_async()
+                        tail, tail_dev, tail_valid_dev, t_pad = (
+                            child._tail_snapshot_device()
+                        )
+                        fn, n_slotspace = self._shard_search_fn(
+                            child, B, k_eff, t_pad
+                        )
+                        # scatter leg: the shared embedding hops to the
+                        # shard's device (async d2d), then the shard kernel
+                        # launches — under the child lock, because a
+                        # concurrent absorb commit DONATES the slab buffers
+                        # (same launch-before-unlock rule as _submit_ivf)
+                        z_s = jax.device_put(z, group.device(s))  # pathway: allow(lock-discipline, value-flow): device→device scatter of an UNFETCHED [B, d] embedding — an async ICI hop enqueued like a dispatch, not a host link round trip; the value is loop-invariant but the TARGET device varies per shard (mirrored in residency.DECLARED_TRANSFERS), and it must precede the launch that consumes it under this lock
+                        out = retry_call(  # pathway: allow(lock-discipline): dispatch-only — donated absorb buffers force launch-before-unlock; the merged fetch happens off-lock in the completion
+                            "shard.dispatch",
+                            fn,
+                            z_s,
+                            child._slabs,
+                            child._bias,
+                            child._centroids
+                            if isinstance(child._centroids, jax.Array)
+                            else jnp.asarray(child._centroids),
+                            tail_dev,
+                            tail_valid_dev,
+                            deadline=deadline,
+                            policy=_LOCKED_DISPATCH_RETRY,
+                            breaker=breaker,
+                        )
+                        keys_by_slot = child._keys_by_slot  # dispatch-time snap
             except Exception as exc:
                 # a dead shard costs recall on its partition, never the
                 # request: skip it, flag the serve, keep the rest going
@@ -709,26 +730,16 @@ class FusedEncodeSearch:
                     s,
                     exc,
                 )
-                _t = trace.current()
-                if _t is not None:
-                    _t.add_span(
-                        "shard.dispatch", t_shard, time.perf_counter_ns(),
-                        status="skipped", shard=s,
-                        error=type(exc).__name__,
-                    )
+                observe.interval(
+                    "shard.dispatch", t_shard, time.perf_counter_ns(),
+                    status="skipped", shard=s, error=type(exc).__name__,
+                )
                 outs.append(None)
                 snaps.append(None)
                 continue
             physical += 1
             outs.append(out)
             snaps.append((keys_by_slot, tail, n_slotspace, child))
-            t_shard_done = time.perf_counter_ns()
-            self._shard_hist("dispatch", s).observe_ns(t_shard_done - t_shard)
-            _t = trace.current()
-            if _t is not None:
-                _t.add_span(
-                    "shard.dispatch", t_shard, t_shard_done, shard=s
-                )
         live = [s for s in range(len(shards)) if outs[s] is not None]
         if not live:
             if skipped:
@@ -748,35 +759,35 @@ class FusedEncodeSearch:
         host_merge = bool(self.shard_host_merge)
         merge_dev = getattr(z, "device", None) or group.device(0)
         out_m = None
-        t_merge = time.perf_counter_ns()
-        if not host_merge:
-            # gather leg: per-shard packed candidate lists hop back to
-            # the merge device (async d2d), then ONE tree-reduce merge
-            # kernel produces the packed global top-K — the only output
-            # the host ever fetches
-            moved = [jax.device_put(outs[s], merge_dev) for s in live]
-            mfn = self._merge_fn(len(live), B, k_eff)
-            out_m = retry_call(
-                "shard.merge", mfn, *moved,
-                deadline=deadline, policy=_LOCKED_DISPATCH_RETRY,
-            )
-            physical += 1
-            if hasattr(out_m, "copy_to_host_async"):
-                out_m.copy_to_host_async()
-        record_dispatch("serve_sharded", shards=physical)
-        t_dispatch = time.perf_counter_ns()
-        self._shard_hist("merge_dispatch", -1).observe_ns(
-            t_dispatch - t_merge
+        with observe.span(
+            "shard.merge", hist=self._shard_hist("merge_dispatch", -1),
+            shards=len(live), host_merge=host_merge, skipped=len(skipped),
+        ) as merge:
+            if not host_merge:
+                # gather leg: per-shard packed candidate lists hop back to
+                # the merge device (async d2d), then ONE tree-reduce merge
+                # kernel produces the packed global top-K — the only output
+                # the host ever fetches
+                moved = [jax.device_put(outs[s], merge_dev) for s in live]
+                mfn = self._merge_fn(len(live), B, k_eff)
+                out_m = retry_call(
+                    "shard.merge", mfn, *moved,
+                    deadline=deadline, policy=_LOCKED_DISPATCH_RETRY,
+                )
+                physical += 1
+                if hasattr(out_m, "copy_to_host_async"):
+                    out_m.copy_to_host_async()
+            record_dispatch("serve_sharded", shards=physical)
+        # the fan-out has no one lock to wait for: its dispatch is the
+        # whole of encode + per-shard launches + merge, an interval (the
+        # shard.* spans above are its profiler events)
+        t_dispatch = merge.t1_ns
+        observe.interval(
+            "stage1.dispatch", t_ready, t_dispatch,
+            hist=_S1_DISPATCH["hist"], kind="sharded", queries=n_real, batch=B,
         )
         _H_TOKENIZE.observe_ns(t_dispatch - t_start)
         observe.record_occupancy("stage1", n_real, B)
-        _t = trace.current()
-        if _t is not None:
-            _t.add_span(
-                "shard.merge", t_merge, t_dispatch,
-                shards=len(live), host_merge=bool(host_merge),
-                skipped=len(skipped),
-            )
 
         def complete() -> List[List[Tuple[int, float]]]:
             inject.fire("serve.fetch", deadline=deadline)
@@ -785,7 +796,9 @@ class FusedEncodeSearch:
                 # every shard's list and tree-merge on host
                 from .topk import tree_merge_topk_host
 
-                per_shard = [np.asarray(outs[s])[:n_real] for s in live]
+                per_shard = _stage1_fetch(
+                    [outs[s] for s in live], n_real, t_dispatch, "sharded_host"
+                )
                 record_fetch("serve_sharded_host", shards=len(live))
                 scores = np.stack(
                     [
@@ -801,50 +814,40 @@ class FusedEncodeSearch:
                     scores, ords, cids, k_eff
                 )
             else:
-                arr = np.asarray(out_m)[:n_real]
+                (arr,) = _stage1_fetch((out_m,), n_real, t_dispatch, "sharded")
                 record_fetch("serve_sharded")
                 m_s = np.ascontiguousarray(arr[:, :k_eff]).view(np.float32)
                 m_h = arr[:, k_eff : 2 * k_eff]
                 m_i = arr[:, 2 * k_eff :]
-            t_fetch = time.perf_counter_ns()
-            _H_STAGE1.observe_ns(t_fetch - t_dispatch)
-            _ct = trace.current()
-            if _ct is not None:
-                _ct.add_span(
-                    "stage1.fetch", t_dispatch, t_fetch,
-                    exemplar=_H_STAGE1, kind="sharded",
-                )
             results: List[List[Tuple[int, float]]] = []
-            for qi in range(len(texts)):
-                row: List[Tuple[int, float]] = []
-                for j in range(m_s.shape[1]):
-                    sc = float(m_s[qi, j])
-                    if not np.isfinite(sc):
-                        continue
-                    ordinal = int(m_h[qi, j])
-                    cid = int(m_i[qi, j])
-                    if ordinal < 0 or cid < 0:
-                        continue
-                    keys_by_slot, tail_keys, n_slotspace, _child = snaps[
-                        live[ordinal]
-                    ]
-                    if cid < n_slotspace:
-                        row.append((int(keys_by_slot[cid]), sc))
-                    elif cid - n_slotspace < len(tail_keys):
-                        row.append((tail_keys[cid - n_slotspace], sc))
-                # merged list arrives score-sorted; dedupe upsert twins
-                # (a key resident in both the slab and the tail)
-                seen = set()
-                dedup = []
-                for key, sc in row:
-                    if key not in seen:
-                        seen.add(key)
-                        dedup.append((key, sc))
-                results.append(dedup[:k])
-            t_post = time.perf_counter_ns()
-            _H_POST.observe_ns(t_post - t_fetch)
-            if _ct is not None:
-                _ct.add_span("stage1.postprocess", t_fetch, t_post)
+            with observe.span("stage1.postprocess", **_S1_POST) as post:
+                for qi in range(len(texts)):
+                    row: List[Tuple[int, float]] = []
+                    for j in range(m_s.shape[1]):
+                        sc = float(m_s[qi, j])
+                        if not np.isfinite(sc):
+                            continue
+                        ordinal = int(m_h[qi, j])
+                        cid = int(m_i[qi, j])
+                        if ordinal < 0 or cid < 0:
+                            continue
+                        keys_by_slot, tail_keys, n_slotspace, _child = snaps[
+                            live[ordinal]
+                        ]
+                        if cid < n_slotspace:
+                            row.append((int(keys_by_slot[cid]), sc))
+                        elif cid - n_slotspace < len(tail_keys):
+                            row.append((tail_keys[cid - n_slotspace], sc))
+                    # merged list arrives score-sorted; dedupe upsert twins
+                    # (a key resident in both the slab and the tail)
+                    seen = set()
+                    dedup = []
+                    for key, sc in row:
+                        if key not in seen:
+                            seen.add(key)
+                            dedup.append((key, sc))
+                    results.append(dedup[:k])
+            _H_POST.observe_ns(post.t1_ns - post.t0_ns)
             flags: List[str] = []
             if tail_skipped:
                 flags.append(TAIL_SKIPPED)
@@ -868,6 +871,7 @@ class FusedEncodeSearch:
         n_real: int,
         k: int,
         t_start: int,
+        t_ready: int,
         deadline: Optional[Deadline] = None,
         z=None,
         stage1_launches: int = 1,
@@ -896,121 +900,103 @@ class FusedEncodeSearch:
                 meta={"index_generation": self.index_generation()},
             )
             return lambda: empty
-        if index._slabs is None:
-            index.build()  # first build only: nothing to serve from yet
-        else:
-            index.maybe_retrain_async()
-        k_eff = min(k, len(index))
-        # exact tail: rows not yet absorbed into the slabs.  The device
-        # upload is CACHED on the index and invalidated only when the tail
-        # mutates (add/absorb/remove/install) — re-uploading the padded
-        # ~3 MB tail matrix on every dispatch was a per-call host->device
-        # transfer on the one-RTT latency path (ADVICE r5 #1)
-        tail, tail_dev, tail_valid_dev, t_pad = index._tail_snapshot_device()
-        # degradation ladder: a failed tail upload (after its retry
-        # budget) serves resident-only results, flagged on the response;
-        # the degraded counter was bumped by the snapshot itself
-        tail_skipped = bool(getattr(index, "tail_degraded", False))
-        fn, k_main, k_tail = self._compiled_ivf(
-            ids.shape[0], ids.shape[1], k_eff, t_pad, from_z=z is not None
-        )
-        if z is not None:
-            args = [z]
-        else:
-            args = [self.encoder.params, ids, mask]
-        args += [
-            index._slabs,
-            index._bias,
-            index._centroids
-            if isinstance(index._centroids, jax.Array)
-            else jnp.asarray(index._centroids),
-            tail_dev,
-            tail_valid_dev,
-        ]
-        # dispatch-time generation snapshot, stamped into the result so
-        # the tier-0 capture can refuse a row whose dispatch observed a
-        # newer index state than its admission key
-        gen0 = self.index_generation()
-        # transient dispatch failures retry with backoff under the site's
-        # budget ("ivf.dispatch" is also the chaos-suite fault site); the
-        # deadline bounds both the attempts and the backoff sleeps
-        if self._exporting() and z is None:
-            out, qtok = retry_call(
-                "ivf.dispatch", fn, *args,
-                deadline=deadline, policy=_LOCKED_DISPATCH_RETRY,
+        # the caller holds both locks: the bracket times the dispatch call
+        # itself (tail snapshot, compiled lookup, jit call, async copy)
+        with observe.span(
+            "stage1.dispatch", kind="ivf", queries=n_real, batch=ids.shape[0],
+            **_S1_DISPATCH,
+        ) as dispatch:
+            if index._slabs is None:
+                index.build()  # first build only: nothing to serve from yet
+            else:
+                index.maybe_retrain_async()
+            k_eff = min(k, len(index))
+            # exact tail: rows not yet absorbed into the slabs.  The device
+            # upload is CACHED on the index and invalidated only when the
+            # tail mutates (add/absorb/remove/install) — re-uploading the
+            # padded ~3 MB tail matrix on every dispatch was a per-call
+            # host->device transfer on the one-RTT latency path (ADVICE r5 #1)
+            tail, tail_dev, tail_valid_dev, t_pad = index._tail_snapshot_device()
+            dispatch.set(tail=t_pad)
+            # degradation ladder: a failed tail upload (after its retry
+            # budget) serves resident-only results, flagged on the response;
+            # the degraded counter was bumped by the snapshot itself
+            tail_skipped = bool(getattr(index, "tail_degraded", False))
+            fn, k_main, k_tail = self._compiled_ivf(
+                ids.shape[0], ids.shape[1], k_eff, t_pad, from_z=z is not None
             )
-        else:
+            if z is not None:
+                args = [z]
+            else:
+                args = [self.encoder.params, ids, mask]
+            args += [
+                index._slabs,
+                index._bias,
+                index._centroids
+                if isinstance(index._centroids, jax.Array)
+                else jnp.asarray(index._centroids),
+                tail_dev,
+                tail_valid_dev,
+            ]
+            # dispatch-time generation snapshot, stamped into the result so
+            # the tier-0 capture can refuse a row whose dispatch observed a
+            # newer index state than its admission key
+            gen0 = self.index_generation()
+            # transient dispatch failures retry with backoff under the
+            # site's budget ("ivf.dispatch" is also the chaos-suite fault
+            # site); the deadline bounds both the attempts and the sleeps
             out = retry_call(
                 "ivf.dispatch", fn, *args,
                 deadline=deadline, policy=_LOCKED_DISPATCH_RETRY,
             )
-            qtok = None
-        record_dispatch("serve_ivf", shards=stage1_launches)
-        if hasattr(out, "copy_to_host_async"):
-            out.copy_to_host_async()
-        # instrumentation: timestamps only between dispatch and fetch —
-        # the observe calls are integer updates, never a host sync
-        t_dispatch = time.perf_counter_ns()
-        _H_TOKENIZE.observe_ns(t_dispatch - t_start)
+            out, qtok = out if self._exporting() and z is None else (out, None)
+            record_dispatch("serve_ivf", shards=stage1_launches)
+            if hasattr(out, "copy_to_host_async"):
+                out.copy_to_host_async()
+        t_dispatch = _stage1_launched(t_start, t_ready, dispatch)
         observe.record_occupancy("stage1", n_real, ids.shape[0])
-        _t = trace.current()
-        if _t is not None:
-            _t.add_span(
-                "stage1.dispatch", t_start, t_dispatch,
-                exemplar=_H_TOKENIZE, kind="ivf",
-                queries=n_real, batch=ids.shape[0], tail=t_pad,
-            )
         keys_by_slot = index._keys_by_slot  # rebuilds REPLACE the array
 
         def complete() -> List[List[Tuple[int, float]]]:
             inject.fire("serve.fetch", deadline=deadline)
-            arr = np.asarray(out)[:n_real]
+            (arr,) = _stage1_fetch((out,), n_real, t_dispatch, "ivf")
             record_fetch("serve_ivf")
-            t_fetch = time.perf_counter_ns()
-            _H_STAGE1.observe_ns(t_fetch - t_dispatch)
-            _ct = trace.current()
-            if _ct is not None:
-                _ct.add_span(
-                    "stage1.fetch", t_dispatch, t_fetch,
-                    exemplar=_H_STAGE1, kind="ivf",
-                )
-            scores = np.ascontiguousarray(arr[:, :k_main]).view(np.float32)
-            slots = arr[:, k_main : 2 * k_main]
-            if k_tail:
-                t_scores = np.ascontiguousarray(
-                    arr[:, 2 * k_main : 2 * k_main + k_tail]
-                ).view(np.float32)
-                t_idx = arr[:, 2 * k_main + k_tail :]
             results: List[List[Tuple[int, float]]] = []
-            for qi in range(len(texts)):
-                row: List[Tuple[int, float]] = []
-                for j in range(slots.shape[1]):
-                    s = float(scores[qi, j])
-                    slot = int(slots[qi, j])
-                    if not np.isfinite(s) or slot < 0:
-                        continue
-                    # no live-dict filter: removed rows were already biased
-                    # to -inf in the DISPATCHED arrays (dispatch-time
-                    # semantics); keys_by_slot is the dispatch-time snapshot
-                    row.append((int(keys_by_slot[slot]), s))
+            with observe.span("stage1.postprocess", **_S1_POST) as post:
+                scores = np.ascontiguousarray(arr[:, :k_main]).view(np.float32)
+                slots = arr[:, k_main : 2 * k_main]
                 if k_tail:
-                    for j in range(t_idx.shape[1]):
-                        s = float(t_scores[qi, j])
-                        ti = int(t_idx[qi, j])
-                        if np.isfinite(s) and ti < len(tail):
-                            row.append((tail[ti], s))
-                row.sort(key=lambda kv: -kv[1])
-                seen = set()
-                dedup = []
-                for key, s in row:
-                    if key not in seen:
-                        seen.add(key)
-                        dedup.append((key, s))
-                results.append(dedup[:k])
-            t_post = time.perf_counter_ns()
-            _H_POST.observe_ns(t_post - t_fetch)
-            if _ct is not None:
-                _ct.add_span("stage1.postprocess", t_fetch, t_post)
+                    t_scores = np.ascontiguousarray(
+                        arr[:, 2 * k_main : 2 * k_main + k_tail]
+                    ).view(np.float32)
+                    t_idx = arr[:, 2 * k_main + k_tail :]
+                for qi in range(len(texts)):
+                    row: List[Tuple[int, float]] = []
+                    for j in range(slots.shape[1]):
+                        s = float(scores[qi, j])
+                        slot = int(slots[qi, j])
+                        if not np.isfinite(s) or slot < 0:
+                            continue
+                        # no live-dict filter: removed rows were already
+                        # biased to -inf in the DISPATCHED arrays (dispatch-
+                        # time semantics); keys_by_slot is the dispatch-time
+                        # snapshot
+                        row.append((int(keys_by_slot[slot]), s))
+                    if k_tail:
+                        for j in range(t_idx.shape[1]):
+                            s = float(t_scores[qi, j])
+                            ti = int(t_idx[qi, j])
+                            if np.isfinite(s) and ti < len(tail):
+                                row.append((tail[ti], s))
+                    row.sort(key=lambda kv: -kv[1])
+                    seen = set()
+                    dedup = []
+                    for key, s in row:
+                        if key not in seen:
+                            seen.add(key)
+                            dedup.append((key, s))
+                    results.append(dedup[:k])
+            _H_POST.observe_ns(post.t1_ns - post.t0_ns)
             return ServeResult(
                 results,
                 degraded=(TAIL_SKIPPED,) if tail_skipped else (),
@@ -1041,7 +1027,6 @@ class FusedEncodeSearch:
         degraded response instead of surfacing it to the user."""
         k = k or self.k
         index = self.index
-        t_start = time.perf_counter_ns()
         if not texts:
             return lambda: ServeResult()
         # host prep FULLY OFF the serve lock: tokenize + bucket-pad here,
@@ -1050,23 +1035,27 @@ class FusedEncodeSearch:
         # one thread's lock hold (tokenizers are stateless; the bucket
         # padding matches encoder.encode's, so B in the compile key still
         # takes a handful of values — round-1 advice)
-        ids, mask = self.encoder.tokenizer.encode_batch(texts)
-        ids = np.asarray(ids)
-        mask = np.asarray(mask)
-        n_real = ids.shape[0]
-        b = _bucket(n_real)
-        if b > n_real:
-            ids = np.concatenate(
-                [ids, np.zeros((b - n_real, ids.shape[1]), ids.dtype)]
-            )
-            mask = np.concatenate(
-                [mask, np.zeros((b - n_real, mask.shape[1]), mask.dtype)]
-            )
+        with observe.span("stage1.tokenize", **_S1_TOKENIZE) as tokenize:
+            ids, mask = self.encoder.tokenizer.encode_batch(texts)
+            ids = np.asarray(ids)
+            mask = np.asarray(mask)
+            n_real = ids.shape[0]
+            b = _bucket(n_real)
+            if b > n_real:
+                ids = np.concatenate(
+                    [ids, np.zeros((b - n_real, ids.shape[1]), ids.dtype)]
+                )
+                mask = np.concatenate(
+                    [mask, np.zeros((b - n_real, mask.shape[1]), mask.dtype)]
+                )
+        # tokenize_pack runs from t_start to the dispatch returning; the
+        # wait for the serve locks from t_ready to the dispatch bracket
+        t_start, t_ready = tokenize.t0_ns, tokenize.t1_ns
         if self._sharded:
             # no global lock: per-shard child locks cover the donated
             # buffers, and the compile caches lock internally
             return self._submit_sharded(
-                texts, ids, mask, n_real, k, t_start, deadline
+                texts, ids, mask, n_real, k, t_start, t_ready, deadline
             )
         # tier-1 embedding cache (pathway_tpu/cache): resolve the batch's
         # embeddings BEFORE any serve lock — cached device rows compose
@@ -1077,16 +1066,20 @@ class FusedEncodeSearch:
         z = None
         stage1_launches = 1
         if self.embed_cache is not None and not self._exporting():
-            z, encoded = self._cached_embeddings(ids, mask, n_real, deadline)
+            with observe.span("stage1.embed_cache") as lookup:
+                z, encoded = self._cached_embeddings(
+                    ids, mask, n_real, deadline
+                )
+            t_ready = lookup.t1_ns or t_ready
             stage1_launches = 2 if encoded else 1
         if self._ivf:
             with index._lock, self._lock:  # pathway: allow(lock-order): rank exception index(3)<scheduler(5) — index-before-pipeline is the fused-serve pair order at EVERY site (IVF absorb DONATES slab buffers, so the stage-1 launch must precede unlocking the index; self._lock nests inside to guard the compiled-fn cache), globally ordered with the shard fan-out's child._lock→self._lock
                 return self._submit_ivf(
-                    texts, ids, mask, n_real, k, t_start, deadline,
+                    texts, ids, mask, n_real, k, t_start, t_ready, deadline,
                     z=z, stage1_launches=stage1_launches,
                 )
         return self._submit_exact(
-            texts, ids, mask, n_real, k, t_start, deadline,
+            texts, ids, mask, n_real, k, t_start, t_ready, deadline,
             z=z, stage1_launches=stage1_launches,
         )
 
@@ -1098,6 +1091,7 @@ class FusedEncodeSearch:
         n_real: int,
         k: int,
         t_start: int,
+        t_ready: int,
         deadline: Optional[Deadline] = None,
         z=None,
         stage1_launches: int = 1,
@@ -1141,57 +1135,38 @@ class FusedEncodeSearch:
             gen0 = self.index_generation()  # dispatch-time snapshot
         # transient dispatch failures retry with backoff ("serve.dispatch"
         # doubles as the chaos-suite fault site); deadline bounds attempts
-        if self._exporting() and z is None:
-            out, qtok = retry_call(
-                "serve.dispatch", fn, *args, deadline=deadline
-            )
-        else:
+        with observe.span(
+            "stage1.dispatch", kind="exact", queries=n_real, batch=B,
+            **_S1_DISPATCH,
+        ) as dispatch:
             out = retry_call("serve.dispatch", fn, *args, deadline=deadline)
-            qtok = None
-        record_dispatch("serve_exact", shards=stage1_launches)
-        if hasattr(out, "copy_to_host_async"):
-            out.copy_to_host_async()
-        t_dispatch = time.perf_counter_ns()
-        _H_TOKENIZE.observe_ns(t_dispatch - t_start)
+            out, qtok = out if self._exporting() and z is None else (out, None)
+            record_dispatch("serve_exact", shards=stage1_launches)
+            if hasattr(out, "copy_to_host_async"):
+                out.copy_to_host_async()
+        t_dispatch = _stage1_launched(t_start, t_ready, dispatch)
         observe.record_occupancy("stage1", n_real, B)
-        _t = trace.current()
-        if _t is not None:
-            _t.add_span(
-                "stage1.dispatch", t_start, t_dispatch,
-                exemplar=_H_TOKENIZE, kind="exact",
-                queries=n_real, batch=B,
-            )
 
         def complete() -> List[List[Tuple[int, float]]]:
             inject.fire("serve.fetch", deadline=deadline)
-            arr = np.asarray(out)[:n_real]
+            (arr,) = _stage1_fetch((out,), n_real, t_dispatch, "exact")
             record_fetch("serve_exact")
-            t_fetch = time.perf_counter_ns()
-            _H_STAGE1.observe_ns(t_fetch - t_dispatch)
-            _ct = trace.current()
-            if _ct is not None:
-                _ct.add_span(
-                    "stage1.fetch", t_dispatch, t_fetch,
-                    exemplar=_H_STAGE1, kind="exact",
-                )
-            scores = np.ascontiguousarray(arr[:, :k_eff]).view(np.float32)
-            ints = np.ascontiguousarray(arr[:, k_eff:]).view(np.uint32)
-            hi = ints[:, :k_eff].astype(np.uint64)
-            lo = ints[:, k_eff:].astype(np.uint64)
-            keys = (hi << np.uint64(32)) | lo
             results: List[List[Tuple[int, float]]] = []
-            for qi in range(len(texts)):
-                row: List[Tuple[int, float]] = []
-                for j in range(k_eff):
-                    s = float(scores[qi, j])
-                    if not np.isfinite(s):
-                        continue
-                    row.append((int(keys[qi, j]), s))
-                results.append(row[:k])
-            t_post = time.perf_counter_ns()
-            _H_POST.observe_ns(t_post - t_fetch)
-            if _ct is not None:
-                _ct.add_span("stage1.postprocess", t_fetch, t_post)
+            with observe.span("stage1.postprocess", **_S1_POST) as post:
+                scores = np.ascontiguousarray(arr[:, :k_eff]).view(np.float32)
+                ints = np.ascontiguousarray(arr[:, k_eff:]).view(np.uint32)
+                hi = ints[:, :k_eff].astype(np.uint64)
+                lo = ints[:, k_eff:].astype(np.uint64)
+                keys = (hi << np.uint64(32)) | lo
+                for qi in range(len(texts)):
+                    row: List[Tuple[int, float]] = []
+                    for j in range(k_eff):
+                        s = float(scores[qi, j])
+                        if not np.isfinite(s):
+                            continue
+                        row.append((int(keys[qi, j]), s))
+                    results.append(row[:k])
+            _H_POST.observe_ns(post.t1_ns - post.t0_ns)
             return ServeResult(results, meta={"index_generation": gen0})
 
         # device-resident query token states for a late-interaction stage

@@ -82,6 +82,14 @@ _H_STAGE = {
     s: observe.histogram("pathway_freshness_stage_seconds", stage=s)
     for s in _STAGES
 }
+# the embed bracket's series.  Its thread-CPU twin: wall − CPU is how long
+# the ingest thread was blocked (the GIL it shares with serving, the device)
+_EMBED_SERIES = {
+    "hist": _H_STAGE["embed"],
+    "cpu_hist": observe.histogram(
+        "pathway_freshness_stage_cpu_seconds", stage="embed"
+    ),
+}
 _C_FAIL = {
     s: observe.counter("pathway_ingest_failures_total", stage=s)
     for s in ("poll", "embed", "commit")
@@ -403,10 +411,10 @@ class LiveIngestRunner:
             trace.finish(ctx, statuses=(f"ingest_{stage}_failed",))
 
     def _absorb(self, batch: List[_Doc]) -> None:
-        t_dequeue = time.perf_counter_ns()
         t_oldest = min(d.t_arrival_ns for d in batch)
+        plane = self.freshness_plane
         ctx = None
-        if self.freshness_plane:
+        if plane:
             ctx = trace.start_trace("ingest.batch", kind="ingest")
             if ctx is not None:
                 # root the trace at the oldest rider's arrival: the root
@@ -416,26 +424,30 @@ class LiveIngestRunner:
                     docs=len(batch),
                     connectors=sorted({d.connector for d in batch}),
                 )
-        if not _stage_allowed("ingest.embed"):
-            self._drop("embed", batch, ctx)
-            return
         texts = [d.text for d in batch]
         keys = [d.key for d in batch]
-        try:
-            # sequence packing when the encoder offers it (the
-            # variable-length ingest hot path; same [B, d] contract)
-            enc = getattr(
-                self.encoder, "encode_packed_to_device", None
-            ) or self.encoder.encode_to_device
-            vecs = enc(texts)
-        except Exception as exc:
-            log_once(
-                f"ingest.embed:{type(exc).__name__}",
-                "ingest embed failed (%r); dropping batch", exc,
-            )
+        # dequeue → embedded: tokenize + pack + encode, on this thread
+        with trace.use(ctx), observe.span(
+            "ingest.embed", **(_EMBED_SERIES if plane else {})
+        ) as embed:
+            vecs = None
+            if _stage_allowed("ingest.embed"):
+                try:
+                    # sequence packing when the encoder offers it (the
+                    # variable-length ingest hot path; same [B, d] contract)
+                    enc = getattr(
+                        self.encoder, "encode_packed_to_device", None
+                    ) or self.encoder.encode_to_device
+                    vecs = enc(texts)
+                except Exception as exc:
+                    log_once(
+                        f"ingest.embed:{type(exc).__name__}",
+                        "ingest embed failed (%r); dropping batch", exc,
+                    )
+        if vecs is None:
             self._drop("embed", batch, ctx)
             return
-        t_embed = time.perf_counter_ns()
+        t_dequeue, t_embed = embed.t0_ns, embed.t1_ns
         # absorb plan, off every lock: the device→host sync the IVF's
         # own off-lock normalize will consume (value-flow: the sync must
         # not happen under the index lock)
@@ -471,22 +483,22 @@ class LiveIngestRunner:
         # every rider's freshness and the per-stage attribution
         self._docs_total += len(batch)
         self._batches_total += 1
-        if self.freshness_plane:
-            for d in batch:
-                _H_FRESH.observe_ns(t_commit - d.t_arrival_ns)
-                _H_STAGE["queue_wait"].observe_ns(t_dequeue - d.t_arrival_ns)
-            _H_STAGE["embed"].observe_ns(t_embed - t_dequeue)
-            _H_STAGE["absorb_plan"].observe_ns(t_plan - t_embed)
-            _H_STAGE["commit"].observe_ns(t_commit - t_plan)
+        if not plane:
+            return
+        for d in batch:
+            _H_FRESH.observe_ns(t_commit - d.t_arrival_ns)
+            _H_STAGE["queue_wait"].observe_ns(t_dequeue - d.t_arrival_ns)
+        # the other stages wait or take locks: intervals, contiguous
+        observe.interval("ingest.queue_wait", t_oldest, t_dequeue, tree=ctx)
+        observe.interval(
+            "ingest.absorb_plan", t_embed, t_plan,
+            hist=_H_STAGE["absorb_plan"], tree=ctx,
+        )
+        observe.interval(
+            "ingest.commit", t_plan, t_commit,
+            hist=_H_STAGE["commit"], tree=ctx,
+        )
         if ctx is not None:
-            ctx.add_span("ingest.queue_wait", t_oldest, t_dequeue,
-                         exemplar=_H_STAGE["queue_wait"])
-            ctx.add_span("ingest.embed", t_dequeue, t_embed,
-                         exemplar=_H_STAGE["embed"])
-            ctx.add_span("ingest.absorb_plan", t_embed, t_plan,
-                         exemplar=_H_STAGE["absorb_plan"])
-            ctx.add_span("ingest.commit", t_plan, t_commit,
-                         exemplar=_H_STAGE["commit"])
             ctx.annotate(
                 generation=getattr(self.index, "generation", None),
                 generation_before=gen_before,

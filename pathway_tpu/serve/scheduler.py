@@ -61,7 +61,6 @@ from __future__ import annotations
 
 # pathway: serve-path  (hidden-sync lint applies: no implicit host round trips)
 
-import contextlib
 import inspect
 import threading
 import time
@@ -110,8 +109,22 @@ def max_batch_queries() -> int:
 
 # time-in-queue: enqueue → handoff of the shared batch to the waiters
 # (shared series across scheduler instances, like the serve stage
-# histograms; per-instance split rides the provider counters below)
+# histograms; per-instance split rides the provider counters below).  It
+# CONTAINS the batch's launch, which runs on the scheduler thread: per
+# request, queue_wait == admission_wait + launch, by the same clock reads.
 _H_QUEUE_WAIT = observe.histogram("pathway_serve_queue_wait_seconds")
+# enqueue → the launch that took the request: backlog + coalescing window
+_H_ADMISSION_WAIT = observe.histogram("pathway_serve_admission_wait_seconds")
+# handoff (event set) → the rider returns from its wait: thread wake-up
+_H_TICKET_WAKE = observe.histogram("pathway_serve_ticket_wake_seconds")
+_LAUNCH = observe.serve_stage("launch")
+# the scheduler thread's wall time in seconds, by phase: nothing queued,
+# the deliberate coalescing wait, packing + launching a batch, advancing
+# the previous batch's pipeline
+_C_PHASE = {
+    p: observe.counter("pathway_serve_dispatcher_seconds_total", phase=p)
+    for p in ("idle", "window", "launch", "advance")
+}
 
 # requests shed at admission, by priority class — pre-created for the
 # known classes so the family renders at 0 before the first shed
@@ -127,9 +140,6 @@ def _shed_classes() -> frozenset:
     raw = str(config.get("serve.shed_priorities"))
     return frozenset(p.strip().lower() for p in raw.split(",") if p.strip())
 
-# stateless shared no-op context manager for the untraced fast path
-_NOOP_CM = contextlib.nullcontext()
-
 
 class _Request:
     """One admitted serve/score call: resolved by the scheduler with the
@@ -137,7 +147,7 @@ class _Request:
 
     __slots__ = (
         "items", "k", "deadline", "t_enqueue_ns", "event", "batch", "slots",
-        "cache_store", "trace",
+        "cache_store", "trace", "t_handoff_ns",
     )
 
     def __init__(self, items: Sequence[Any], k: Optional[int], deadline):
@@ -145,6 +155,9 @@ class _Request:
         self.k = k
         self.deadline = deadline
         self.t_enqueue_ns = time.perf_counter_ns()
+        # when the scheduler handed this request its batch (0: resolved
+        # without a handoff, or its wake-up is already recorded)
+        self.t_handoff_ns = 0
         self.event = threading.Event()
         self.batch: Optional["_Batch"] = None
         self.slots: List[int] = []
@@ -164,7 +177,8 @@ class _Batch:
     ever guards the once-only completion, never a queue."""
 
     __slots__ = ("_handle", "_n_items", "_n_requests", "_degrade_empty",
-                 "_lock", "_done", "_result", "_error", "_trace")
+                 "_lock", "_done", "_result", "_error", "_trace",
+                 "t_launch_ns", "link")
 
     def __init__(self, handle, n_items: int, n_requests: int,
                  degrade_empty: bool, trace_ctx=None):
@@ -181,6 +195,10 @@ class _Batch:
         # advance()/result() re-activate it because they run on other
         # threads (scheduler thread / whichever waiter fetches first)
         self._trace = trace_ctx
+        # the launch bracket's start (0: none) and the riders' link-span
+        # attrs: each rider records its own waits from them after it wakes
+        self.t_launch_ns = 0
+        self.link: Dict[str, Any] = {}
 
     def advance(self) -> None:
         """Pipelining hook: complete stage 1 and dispatch stage 2 of this
@@ -191,10 +209,7 @@ class _Batch:
         if adv is None:
             return
         try:
-            if self._trace is not None:
-                with trace.use(self._trace):
-                    adv()
-            else:
+            with trace.use(self._trace):
                 adv()
         except Exception:
             pass  # surfaces (once) at result() via the same handle
@@ -203,10 +218,7 @@ class _Batch:
         with self._lock:
             if not self._done:
                 try:
-                    if self._trace is not None:
-                        with trace.use(self._trace):
-                            self._result = self._handle()
-                    else:
+                    with trace.use(self._trace):
                         self._result = self._handle()
                 except Exception as exc:
                     if self._degrade_empty:
@@ -241,6 +253,28 @@ class _Batch:
         return self._result
 
 
+def _record_waits(req: "_Request", t_handoff: int, t_woke: int) -> None:
+    """A rider's waits, recorded by the rider after its fetch (the scheduler
+    thread, the bottleneck, only stamps the handoff).  All from the launch
+    bracket's clock reads, so queue_wait == admission_wait + launch.  One
+    tree node, the LINK span: its duration is the queue wait, its attrs say
+    which batch the rider rode, how long it waited for a launch and to wake
+    (every node costs each request: measured, PERF.md ISSUE 24); /traces
+    inlines the linked batch tree under it."""
+    batch, rt, t_enqueue = req.batch, req.trace, req.t_enqueue_ns
+    admission_ns, wake_ns = batch.t_launch_ns - t_enqueue, t_woke - t_handoff
+    _H_ADMISSION_WAIT.observe_ns(admission_ns)
+    _H_TICKET_WAKE.observe_ns(wake_ns)
+    linked = batch.link.get("linked_trace")
+    if rt is not None and linked is not None:
+        rt.add_link(linked)
+    observe.interval(
+        "batch", t_enqueue, t_handoff, hist=_H_QUEUE_WAIT, tree=rt,
+        admission_wait_ms=admission_ns * 1e-6, wake_ms=wake_ns * 1e-6,
+        **batch.link,
+    )
+
+
 class _Ticket:
     """Per-request future.  Calling it (or ``result(timeout)``) blocks
     until the scheduler hands this request its shared batch, then the
@@ -258,7 +292,12 @@ class _Ticket:
         req = self._request
         if not req.event.wait(timeout):
             raise TimeoutError("serve ticket not dispatched within timeout")
-        return self._owner._demux(req, req.batch.result())
+        t_woke = time.perf_counter_ns()
+        result = req.batch.result()  # the fetch first: it is what riders wait for
+        t_handoff, req.t_handoff_ns = req.t_handoff_ns, 0
+        if t_handoff:
+            _record_waits(req, t_handoff, t_woke)
+        return self._owner._demux(req, result)
 
     def __call__(self):
         return self.result()
@@ -401,8 +440,15 @@ class _CoalescerBase:
                         # double buffering: stage-1 of the batch just
                         # dispatched is on the device queue; completing the
                         # PREVIOUS batch's stage 1 and dispatching its
-                        # stage 2 now overlaps the two on device
+                        # stage 2 now overlaps the two on device (a no-op
+                        # where a waiter got there first, a wait where one
+                        # is at it: an interval, not a profiler event)
+                        t_advance = time.perf_counter_ns()
                         prev.advance()
+                        observe.interval(
+                            "sched.advance", t_advance, time.perf_counter_ns(),
+                            counter=_C_PHASE["advance"], tree=None,
+                        )
                     prev = batch
             except Exception as exc:
                 # the scheduler thread must OUTLIVE any one bad batch:
@@ -438,28 +484,34 @@ class _CoalescerBase:
         deadline slack and the batch query cap), then pop one batch.
         Returns None when stopped and drained."""
         with self._cond:
-            while self._running and not self._queue:
-                self._cond.wait(0.1)
+            if self._running and not self._queue:
+                with observe.span("sched.idle", counter=_C_PHASE["idle"]):
+                    while self._running and not self._queue:
+                        self._cond.wait(0.1)
             if not self._queue:
                 return None  # stopped and drained
-            anchor_ns = self._queue[0].t_enqueue_ns
-            # the cap bounds UNIQUE items (the device batch shape), so the
-            # window stays open for hot duplicate-heavy traffic even when
-            # the raw queued count is past it — those riders dedup in
-            while self._running and self._queued_unique_locked() < self._max_batch:
-                now = time.perf_counter_ns()
-                if not self._window_pinned:
-                    self._window_s = coalesce_window_s()
-                end_s = (anchor_ns - now) * 1e-9 + self._window_s
-                for r in self._queue:
-                    if r.deadline is not None:
-                        # the window never eats more than half of any
-                        # queued request's remaining budget
-                        end_s = min(end_s, 0.5 * r.deadline.remaining_s())
-                if end_s <= 0:
-                    break
-                self._cond.wait(end_s)
-            return self._pop_batch_locked()
+            with observe.span("sched.window", counter=_C_PHASE["window"]):
+                anchor_ns = self._queue[0].t_enqueue_ns
+                # the cap bounds UNIQUE items (the device batch shape), so
+                # the window stays open for hot duplicate-heavy traffic even
+                # when the raw queued count is past it — those riders dedup in
+                while (
+                    self._running
+                    and self._queued_unique_locked() < self._max_batch
+                ):
+                    now = time.perf_counter_ns()
+                    if not self._window_pinned:
+                        self._window_s = coalesce_window_s()
+                    end_s = (anchor_ns - now) * 1e-9 + self._window_s
+                    for r in self._queue:
+                        if r.deadline is not None:
+                            # the window never eats more than half of any
+                            # queued request's remaining budget
+                            end_s = min(end_s, 0.5 * r.deadline.remaining_s())
+                    if end_s <= 0:
+                        break
+                    self._cond.wait(end_s)
+                return self._pop_batch_locked()
 
     def _pop_batch(self) -> List[_Request]:
         with self._cond:
@@ -521,34 +573,37 @@ class _CoalescerBase:
                 bctx.annotate(
                     scheduler=self.name, riders=len(reqs), solo=bool(solo)
                 )
-        try:
-            index: Dict[Any, int] = {}
-            for r in reqs:
-                for it in r.items:
-                    if it not in index:
-                        index[it] = -1
-                        items.append(it)
-            items.sort()
-            for i, it in enumerate(items):
-                index[it] = i
-            for r in reqs:
-                r.slots = [index[it] for it in r.items]
-            if bctx is not None:
-                bctx.annotate(items=len(items), deduped=total - len(items))
-                with trace.use(bctx):
-                    handle = self._launch(items, reqs)
-            else:
+        # a solo dispatch runs on its caller's thread: it stays out of the
+        # scheduler thread's phase counter
+        with trace.use(bctx), observe.span(
+            "sched.launch", counter=None if solo else _C_PHASE["launch"],
+            riders=len(reqs), solo=bool(solo), **_LAUNCH,
+        ) as launch:
+            try:
+                index: Dict[Any, int] = {}
+                for r in reqs:
+                    for it in r.items:
+                        if it not in index:
+                            index[it] = -1
+                            items.append(it)
+                items.sort()
+                for i, it in enumerate(items):
+                    index[it] = i
+                for r in reqs:
+                    r.slots = [index[it] for it in r.items]
+                if bctx is not None:
+                    bctx.annotate(items=len(items), deduped=total - len(items))
                 handle = self._launch(items, reqs)
-        except Exception as exc:
-            # packing or launch failed: every ticket still resolves —
-            # the error lands in _Batch.result() (degrade or re-raise)
-            error = exc
-            for r in reqs:
-                if len(r.slots) != len(r.items):
-                    r.slots = [-1] * len(r.items)
+            except Exception as exc:
+                # packing or launch failed: every ticket still resolves —
+                # the error lands in _Batch.result() (degrade or re-raise)
+                error = exc
+                for r in reqs:
+                    if len(r.slots) != len(r.items):
+                        r.slots = [-1] * len(r.items)
 
-            def handle(_exc: BaseException = error):
-                raise _exc
+                def handle(_exc: BaseException = error):
+                    raise _exc
         batch = _Batch(
             handle, len(items), len(reqs), self._degrade_empty, trace_ctx=bctx
         )
@@ -557,33 +612,13 @@ class _CoalescerBase:
                 self.stats["batches"] += 1
             self.stats["items_dispatched"] += len(items)
             self.stats["dedup_hits"] += total - len(items)
-        t_now = time.perf_counter_ns()
+        batch.t_launch_ns, t_now = launch.t0_ns, launch.t1_ns
+        batch.link = {"riders": len(reqs), "solo": bool(solo)}
+        if bctx is not None:
+            batch.link.update(linked_trace=bctx.trace_id, batch_items=len(items))
         for r in reqs:
-            _H_QUEUE_WAIT.observe_ns(t_now - r.t_enqueue_ns)
-            rt = r.trace
-            if rt is not None:
-                # the rider's LINK span: its duration is the queue wait
-                # (enqueue → handoff, the EXACT interval _H_QUEUE_WAIT
-                # just observed — exemplar and observation must land in
-                # the same bucket), its attrs say which batch it rode
-                # and with how many others; /traces inlines the linked
-                # batch tree under it.
-                if bctx is not None:
-                    rt.add_link(bctx.trace_id)
-                    rt.add_span(
-                        "batch", r.t_enqueue_ns, t_now,
-                        exemplar=_H_QUEUE_WAIT,
-                        linked_trace=bctx.trace_id,
-                        riders=len(reqs), batch_items=len(items),
-                        solo=bool(solo),
-                    )
-                else:
-                    rt.add_span(
-                        "batch", r.t_enqueue_ns, t_now,
-                        exemplar=_H_QUEUE_WAIT,
-                        riders=len(reqs), solo=bool(solo),
-                    )
             r.batch = batch
+            r.t_handoff_ns = t_now
             r.event.set()
         return batch
 
@@ -831,17 +866,13 @@ class ServeScheduler(_CoalescerBase):
             # lock): a full hit is a zero-dispatch serve that skips the
             # coalescing window entirely; any miss (or cache failure)
             # falls through to the shared batch unchanged
-            if ctx is not None:
-                t_c0 = time.perf_counter_ns()
-                with trace.use(ctx):  # tier events annotate this trace
-                    rows = cache.get_rows(items, k_eff, deadline=deadline)
-                ctx.add_span(
-                    "cache.result", t_c0, time.perf_counter_ns(),
-                    status="hit" if rows is not None else "miss",
-                    items=len(items),
-                )
-            else:
+            t_lookup = time.perf_counter_ns()
+            with trace.use(ctx):  # tier events annotate this trace
                 rows = cache.get_rows(items, k_eff, deadline=deadline)
+            observe.interval(
+                "cache.result", t_lookup, time.perf_counter_ns(), tree=ctx,
+                status="hit" if rows is not None else "miss", items=len(items),
+            )
             if rows is not None:
                 with self._qlock:
                     self.stats["cache_hits"] = (
@@ -943,7 +974,7 @@ class ServeScheduler(_CoalescerBase):
             # pre-mutation key.
             meta_gen = result.meta.get("index_generation")
             ctx = req.trace
-            with trace.use(ctx) if ctx is not None else _NOOP_CM:
+            with trace.use(ctx):
                 for (text, gen), row in zip(req.items, rows):
                     if meta_gen is not None and (
                         normalize_generation(meta_gen)

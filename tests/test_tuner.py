@@ -13,6 +13,7 @@ import pytest
 
 from pathway_tpu import config, observe
 from pathway_tpu.cache.store import CacheTier
+from pathway_tpu.observe import slo
 from pathway_tpu.robust import inject
 from pathway_tpu.serve.tuner import Tuner, tuner_from_env
 
@@ -26,6 +27,9 @@ def _clean(monkeypatch):
             monkeypatch.delenv(name)
     config.clear_overrides()
     observe.reset()
+    # the SLO engine keeps its own burn windows: serves of a test file that
+    # ran earlier in this process must not burn through the baseline tick
+    slo.reset()
     inject.disarm()
     yield
     config.clear_overrides()
