@@ -94,6 +94,21 @@ int32_t pn_tokenize_hash(const uint8_t* blob, const int64_t* offsets,
                          int32_t reserved, int32_t* out_ids,
                          int64_t* out_offsets);
 
+/* Pair rows ``CLS a SEP b SEP`` of a batch (HashTokenizer.encode_pairs):
+ * tokenizes the n_texts DISTINCT texts of blob as pn_tokenize_hash does
+ * (tok_ids / tok_offsets are its outputs, here scratch), then writes pair
+ * i = (text a_slot[i], text b_slot[i]), truncated longest-first to budget
+ * (>= 2) tokens, at out_ids + i*stride with ones in out_mask (both zeroed by
+ * the caller, stride >= budget + 3) and its token count in out_lens[i].
+ * Returns 0, or -1 when built without xxhash. */
+int32_t pn_encode_pairs(const uint8_t* blob, const int64_t* offsets,
+                        int64_t n_texts, int32_t vocab_size, int32_t reserved,
+                        const int64_t* a_slot, const int64_t* b_slot,
+                        int64_t n_pairs, int64_t budget, int32_t cls_id,
+                        int32_t sep_id, int64_t stride, int32_t* tok_ids,
+                        int64_t* tok_offsets, int32_t* out_ids,
+                        int32_t* out_mask, int64_t* out_lens);
+
 /* ---- shard routing ----
  * shard(key) = (key & shard_mask) % n_shards (reference
  * src/engine/dataflow/shard.rs:6 + value.rs:38).  Produces per-shard counts

@@ -12,6 +12,8 @@
 //   id = reserved + xxh3_64(token.lower()) % (vocab_size - reserved)
 #include "../include/pathway_native.h"
 
+#include <algorithm>
+
 #if defined(__has_include)
 #if __has_include(<xxhash.h>)
 #define PN_HAVE_XXHASH 1
@@ -34,14 +36,10 @@ inline bool is_space(uint8_t c) {
 inline uint8_t lower(uint8_t c) {
   return (c >= 'A' && c <= 'Z') ? (uint8_t)(c + 32) : c;
 }
-}  // namespace
-#endif
 
-extern "C" int32_t pn_tokenize_hash(const uint8_t* blob,
-                                    const int64_t* offsets, int64_t n_texts,
-                                    int32_t vocab_size, int32_t reserved,
-                                    int32_t* out_ids, int64_t* out_offsets) {
-#ifdef PN_HAVE_XXHASH
+void tokenize(const uint8_t* blob, const int64_t* offsets, int64_t n_texts,
+              int32_t vocab_size, int32_t reserved, int32_t* out_ids,
+              int64_t* out_offsets) {
   const uint64_t mod = (uint64_t)(vocab_size - reserved);
   uint8_t word[4096];  // lowered-token scratch; longer tokens hash streamed
   int64_t out_n = 0;
@@ -89,6 +87,16 @@ extern "C" int32_t pn_tokenize_hash(const uint8_t* blob,
     }
   }
   out_offsets[n_texts] = out_n;
+}
+}  // namespace
+#endif
+
+extern "C" int32_t pn_tokenize_hash(const uint8_t* blob,
+                                    const int64_t* offsets, int64_t n_texts,
+                                    int32_t vocab_size, int32_t reserved,
+                                    int32_t* out_ids, int64_t* out_offsets) {
+#ifdef PN_HAVE_XXHASH
+  tokenize(blob, offsets, n_texts, vocab_size, reserved, out_ids, out_offsets);
   return 0;
 #else
   (void)blob;
@@ -98,6 +106,53 @@ extern "C" int32_t pn_tokenize_hash(const uint8_t* blob,
   (void)reserved;
   (void)out_ids;
   (void)out_offsets;
+  return -1;
+#endif
+}
+
+// Pair rows ``CLS a SEP b SEP`` for a whole batch (HashTokenizer.encode_pairs):
+// tokenize the batch's DISTINCT texts once, then lay out pair i from texts
+// a_slot[i], b_slot[i].  Truncation is HashTokenizer.encode's longest-first
+// loop in closed form: while over ``budget`` (>= 2) it pops from a if
+// len(a) >= len(b) and len(a) > 1, else from b if len(b) > 1 — so a side at
+// or under its half (budget/2 for a, the larger half for b: ties take from
+// a) is kept whole and the other gets the rest; with both over, each gets
+// its half; an empty side stays empty.  Rows are written at ``stride``
+// (>= budget + 3) int32s into zeroed out_ids/out_mask; out_lens[i] is the
+// row's token count.  tok_ids (capacity >= blob length) and tok_offsets
+// (n_texts + 1) are scratch.
+extern "C" int32_t pn_encode_pairs(
+    const uint8_t* blob, const int64_t* offsets, int64_t n_texts,
+    int32_t vocab_size, int32_t reserved, const int64_t* a_slot,
+    const int64_t* b_slot, int64_t n_pairs, int64_t budget, int32_t cls_id,
+    int32_t sep_id, int64_t stride, int32_t* tok_ids, int64_t* tok_offsets,
+    int32_t* out_ids, int32_t* out_mask, int64_t* out_lens) {
+#ifdef PN_HAVE_XXHASH
+  tokenize(blob, offsets, n_texts, vocab_size, reserved, tok_ids, tok_offsets);
+  const int64_t half = budget / 2;
+  for (int64_t i = 0; i < n_pairs; ++i) {
+    const int32_t* a = tok_ids + tok_offsets[a_slot[i]];
+    const int32_t* b = tok_ids + tok_offsets[b_slot[i]];
+    const int64_t la = tok_offsets[a_slot[i] + 1] - tok_offsets[a_slot[i]];
+    const int64_t lb = tok_offsets[b_slot[i] + 1] - tok_offsets[b_slot[i]];
+    const int64_t ka = std::min(la, std::max(half, budget - lb));
+    const int64_t kb = std::min(lb, std::max(budget - half, budget - la));
+    int32_t* row = out_ids + i * stride;
+    int64_t n = 0;
+    row[n++] = cls_id;
+    for (int64_t j = 0; j < ka; ++j) row[n++] = a[j];
+    row[n++] = sep_id;
+    for (int64_t j = 0; j < kb; ++j) row[n++] = b[j];
+    row[n++] = sep_id;
+    std::fill_n(out_mask + i * stride, n, 1);
+    out_lens[i] = n;
+  }
+  return 0;
+#else
+  (void)blob; (void)offsets; (void)n_texts; (void)vocab_size; (void)reserved;
+  (void)a_slot; (void)b_slot; (void)n_pairs; (void)budget; (void)cls_id;
+  (void)sep_id; (void)stride; (void)tok_ids; (void)tok_offsets;
+  (void)out_ids; (void)out_mask; (void)out_lens;
   return -1;
 #endif
 }

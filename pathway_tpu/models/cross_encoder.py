@@ -32,6 +32,9 @@ __all__ = ["CrossEncoderModel"]
 # flight recorder: submit→ready latency (dispatch through the completion
 # fetch) + per-dispatch batch occupancy
 _H_READY = observe.histogram("pathway_serve_model_seconds", model="cross_encoder")
+# pair tokenisation alone, one bracket per packed batch, inside the pipeline's
+# stage2_packrows (which keeps measuring tokenise + pack + pad)
+_S2_TOKENIZE = observe.serve_stage("stage2_pair_tokenize", cpu=False)
 
 
 class _CrossEncoderModule(nn.Module):
@@ -230,20 +233,24 @@ class CrossEncoderModel:
         return complete
 
     # -- sequence packing ---------------------------------------------------
-    def _pack_pairs(self, pairs: Sequence[Tuple[str, str]]):
+    def _pack_pairs(self, pairs: Sequence[Tuple[str, str]], span=None):
         """Tokenize (query, doc) pairs and pack them into length-bucketed
         rows (models/packing.py): the row width is the smallest bucket
         holding the longest pair, so a 20-token pair never burns a full
         ``max_length``-token row of MXU work.  Returns (ids, segments,
         positions, doc_slots, n_seg) with doc_slots[i] = (row, seg-1) of
-        pair i."""
+        pair i.  ``span`` is the caller's open bracket around the packing
+        (the pipeline's ``stage2.pack``): it learns how many of the pairs
+        the native tokenizer took (``native_pairs``)."""
         from .packing import pack_rows, row_length_bucket
 
         qs = [str(p[0]) for p in pairs]
         ds = [str(p[1]) for p in pairs]
-        ids_b, mask_b = self.tokenizer.encode_batch(qs, pairs=ds)
-        ids_b = np.asarray(ids_b)
-        lens = np.asarray(mask_b).sum(axis=1).astype(np.int64)
+        with observe.span("stage2.tokenize", **_S2_TOKENIZE):
+            ids_b, mask_b, native = self.tokenizer.encode_pairs(qs, ds)
+        if span is not None:
+            span.set(native_pairs=len(pairs) if native else 0)
+        lens = mask_b.sum(axis=1, dtype=np.int64)
         L = row_length_bucket(int(lens.max()), self.config.max_len)
         lens = np.minimum(lens, L)
         ids, _mask, segments, positions, doc_slots, n_seg = pack_rows(

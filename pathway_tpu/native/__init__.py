@@ -36,6 +36,7 @@ __all__ = [
     "frame_scan",
     "shard_rows",
     "tokenize_hash",
+    "encode_pairs",
 ]
 
 _REPO_ROOT = Path(__file__).resolve().parents[2]
@@ -141,6 +142,15 @@ def _declare(dll: ctypes.CDLL) -> ctypes.CDLL:
         ]
     except AttributeError:
         pass  # stale .so without the tokenizer entry point
+    try:
+        dll.pn_encode_pairs.restype = _i32
+        dll.pn_encode_pairs.argtypes = [
+            _p_u8, _p_i64, _i64, _i32, _i32, _p_i64, _p_i64, _i64, _i64,
+            _i32, _i32, _i64, ctypes.POINTER(_i32), _p_i64,
+            ctypes.POINTER(_i32), ctypes.POINTER(_i32), _p_i64,
+        ]
+    except AttributeError:
+        pass  # stale .so without the pair entry point
     return dll
 
 
@@ -462,3 +472,53 @@ def tokenize_hash(
     if rc != 0:
         return None
     return out_ids[: out_offsets[n_texts]], out_offsets
+
+
+def encode_pairs(
+    blob: bytes,
+    offsets: np.ndarray,
+    a_slot: np.ndarray,
+    b_slot: np.ndarray,
+    vocab_size: int,
+    reserved: int,
+    budget: int,
+    cls_id: int,
+    sep_id: int,
+    width: int,
+) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """Pair rows ``CLS a SEP b SEP`` over the DISTINCT ASCII texts of
+    ``blob`` (``offsets`` their boundaries): pair i joins texts
+    ``a_slot[i]`` and ``b_slot[i]``, truncated longest-first to ``budget``
+    tokens (models/tokenizer.py ``encode`` semantics).  Returns (ids
+    [n, width] int32 zero-padded, mask [n, width], lens int64[n]) with
+    ``width >= budget + 3``, or None when the native path is unavailable
+    (caller keeps the Python tokenizer)."""
+    dll = lib()
+    if dll is None or not hasattr(dll, "pn_encode_pairs"):
+        return None
+    n_texts, n = len(offsets) - 1, len(a_slot)
+    if width < budget + 3 or budget < 2:
+        raise ValueError(f"width {width} cannot hold budget {budget} + 3")
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    a_slot = np.ascontiguousarray(a_slot, dtype=np.int64)
+    b_slot = np.ascontiguousarray(b_slot, dtype=np.int64)
+    if n and not (
+        len(b_slot) == n
+        and 0 <= min(a_slot.min(), b_slot.min())
+        and max(a_slot.max(), b_slot.max()) < n_texts
+    ):
+        raise ValueError("pair slots must index the texts")
+    tok_ids = np.empty(max(len(blob), 1), dtype=np.int32)
+    tok_offsets = np.empty(n_texts + 1, dtype=np.int64)
+    ids = np.zeros((n, width), dtype=np.int32)
+    mask = np.zeros((n, width), dtype=np.int32)
+    lens = np.empty(n, dtype=np.int64)
+    rc = dll.pn_encode_pairs(
+        _as_u8_ptr(blob), _np_ptr(offsets, _i64), n_texts, vocab_size, reserved,
+        _np_ptr(a_slot, _i64), _np_ptr(b_slot, _i64), n, budget, cls_id, sep_id,
+        width, _np_ptr(tok_ids, _i32), _np_ptr(tok_offsets, _i64),
+        _np_ptr(ids, _i32), _np_ptr(mask, _i32), _np_ptr(lens, _i64),
+    )
+    if rc != 0:
+        return None
+    return ids, mask, lens

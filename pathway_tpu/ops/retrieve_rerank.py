@@ -816,7 +816,9 @@ class RetrieveRerankPipeline:
         # work on stateless helpers, and under the coalescing scheduler
         # batch N+1's pack must overlap batch N's device time
         with observe.span("stage2.pack", after=gather, **_S2_PACKROWS) as pack:
-            ids, segments, positions, doc_slots, n_seg = ce._pack_pairs(pairs)
+            ids, segments, positions, doc_slots, n_seg = ce._pack_pairs(
+                pairs, span=pack
+            )
             rows_real = ids.shape[0]
             Rb = _bucket(rows_real)
             L = ids.shape[1]
