@@ -124,10 +124,6 @@ DECLARED_TRANSFERS: Dict[Tuple[str, str], str] = {
         "(serving books its crossings through submit/complete); the "
         "fetch runs off the index lock"
     ),
-    ("serve/decode.py", "ContinuousDecoder._prefill_group"): (
-        "the prefill JOIN's one deliberate host fetch: first tokens "
-        "reach the riders' tickets before the step loop takes over"
-    ),
     ("serve/decode.py", "ContinuousDecoder._step_chunk"): (
         "THE decode-loop fetch: one sync per step chunk delivers every "
         "slot's tokens (the int() below it reads the HOST copy — a "

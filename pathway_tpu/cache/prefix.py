@@ -140,6 +140,15 @@ class PrefixKVCache:
         the decode's returned buffers — an async device op, no fetch).
         Returns how many blocks were admitted."""
         admitted = 0
+        # a chain's later blocks are reachable only through its earlier
+        # ones, so it may not evict its own head: the matched blocks are
+        # made most recently used, and no more is admitted than the budget
+        # holds beside them and beside this chain's earlier admissions (a
+        # deep model's block is tens of MB; a long prompt's tail would
+        # otherwise push out the prefix it shares with the next prompt)
+        room = self._tier.max_bytes - sum(
+            self._tier.touch(key) for key in keys[:n_matched_blocks]
+        )
         for j in range(n_matched_blocks, len(keys)):
             try:
                 value = get_block(j)
@@ -149,6 +158,9 @@ class PrefixKVCache:
             nbytes = sum(
                 int(getattr(part, "nbytes", 64)) for part in value
             )
+            room -= nbytes
+            if room < 0:
+                break
             if self._tier.put(keys[j], value, nbytes=nbytes, deadline=deadline):
                 admitted += 1
         return admitted

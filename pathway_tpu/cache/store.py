@@ -254,6 +254,16 @@ class CacheTier:
                 self.stats["evictions"] += 1
         return True
 
+    def touch(self, key: Any) -> int:
+        """Make an entry the most recently used without reading it (no hit
+        is counted); its bytes, 0 if it is not there."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is None:
+                return 0
+            self._entries.move_to_end(key)
+            return entry.nbytes
+
     def __contains__(self, key: Any) -> bool:
         with self._lock:
             return key in self._entries

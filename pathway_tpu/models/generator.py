@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +45,8 @@ from ..observe import hbm, profile
 from ..robust import retry_call
 from ._params import unbox as _unbox
 
+from . import looped
+from .looped import LoopedConfig, token_stats
 from .tokenizer import HashTokenizer
 from .transformer import (
     KVTransformerDecoder,
@@ -145,25 +147,37 @@ class TextGenerator:
         dtype=jnp.bfloat16,
         kv_cache: Any = "env",
         eos_id: Any = "env",
+        architecture: Optional[Mapping[str, Any]] = None,
+        params: Any = None,
     ):
-        self.config = TransformerConfig(
-            vocab_size=vocab_size,
-            d_model=dimension,
-            n_heads=resolve_heads(dimension, n_heads),
-            n_layers=n_layers,
-            d_ff=dimension * 4,
-            max_len=max_length,
-            dtype=dtype,
-            pool="none",
-            causal=True,
-        )
+        # ``architecture`` (a published config.json's keys) selects the
+        # looped decoder family (models/looped.py: RMSNorm sandwich, rotary,
+        # gated SiLU, untied head, ``total_ut_steps`` passes over one stack)
+        # at exactly those sizes; ``params`` hands its weights in.  Without
+        # it this is the 4 x dimension LayerNorm/GELU trunk, as ever.
+        self.looped = architecture is not None
+        if self.looped:
+            self.config = LoopedConfig.from_architecture(architecture, dtype)
+            vocab_size, max_length = self.config.vocab_size, self.config.max_len
+        else:
+            self.config = TransformerConfig(
+                vocab_size=vocab_size,
+                d_model=dimension,
+                n_heads=resolve_heads(dimension, n_heads),
+                n_layers=n_layers,
+                d_ff=dimension * 4,
+                max_len=max_length,
+                dtype=dtype,
+                pool="none",
+                causal=True,
+            )
+            self.module = TransformerEncoder(self.config)
+            self._kv_module = KVTransformerDecoder(self.config)
+            self._slot_module = SlotKVDecoder(self.config)
+            # int8 twins (same params; ops/kv_quant.py scales as operands)
+            self._kv_module_q = KVTransformerDecoder(self.config, quant=True)
+            self._slot_module_q = SlotKVDecoder(self.config, quant=True)
         self.tokenizer = HashTokenizer(vocab_size=vocab_size, max_length=max_length)
-        self.module = TransformerEncoder(self.config)
-        self._kv_module = KVTransformerDecoder(self.config)
-        self._slot_module = SlotKVDecoder(self.config)
-        # int8 twins (same params; ops/kv_quant.py scales as operands)
-        self._kv_module_q = KVTransformerDecoder(self.config, quant=True)
-        self._slot_module_q = SlotKVDecoder(self.config, quant=True)
         self._kv_scales = None  # lazy (params exist below)
         # EOS handling: a row that emits this token is FINISHED — further
         # sampling work is masked to PAD and the legacy decode returns as
@@ -187,10 +201,13 @@ class TextGenerator:
         from ..ops.recompile_guard import RecompileTripwire
 
         self._tripwire = RecompileTripwire(f"TextGenerator[{model}]")
-        ids = jnp.zeros((1, 16), jnp.int32)
-        mask = jnp.ones((1, 16), jnp.int32)
-        self.params = self.module.init(jax.random.PRNGKey(seed), ids, mask)["params"]
-        self.params = _unbox(self.params)
+        if self.looped:
+            self.params = self._looped_params(params, seed)
+        else:
+            ids = jnp.zeros((1, 16), jnp.int32)
+            mask = jnp.ones((1, 16), jnp.int32)
+            self.params = self.module.init(jax.random.PRNGKey(seed), ids, mask)["params"]
+            self.params = _unbox(self.params)
         # weight-tied readout: logits = h @ tok_embed.T
         self._vocab_table = None
         # tier-2 prefix/KV cache (pathway_tpu/cache): per-generator —
@@ -203,6 +220,54 @@ class TextGenerator:
         self._use_kv = config.get("generator.kv")
         # HBM ledger (observe/hbm.py): parameter tree bytes
         hbm.track_params("generator", self)
+
+    def _looped_params(self, params, seed: int):
+        """The looped family's weights: handed in (their tree is checked
+        against the architecture's), else seeded random."""
+        if params is None:
+            return looped.init_params(self.config, seed)
+
+        def shapes(tree):
+            return {
+                jax.tree_util.keystr(k): (tuple(v.shape), str(v.dtype))
+                for k, v in jax.tree_util.tree_flatten_with_path(tree)[0]
+            }
+
+        want = shapes(jax.eval_shape(lambda: looped.init_params(self.config, 0)))
+        if shapes(params) != want:
+            diff = sorted(set(shapes(params).items()) ^ set(want.items()))[:4]
+            raise ValueError(f"params do not fit the architecture: {diff}")
+        return params
+
+    def slot_prefix(self, rows, n_blk: int, depth_shape):
+        """The cached-prefix operands of a slot prefill from, per row, the
+        prefix cache's blocks ``(k, v)``: the trunk takes them stacked
+        ``[B, depth, P, H, hd]``; the looped family takes the blocks as
+        they are, row by row (no copy)."""
+        if self.looped:
+            return (
+                tuple(tuple(b[0] for b in row[:n_blk]) for row in rows),
+                tuple(tuple(b[1] for b in row[:n_blk]) for row in rows),
+            )
+        if not n_blk:
+            empty = jnp.zeros((len(rows), *depth_shape), self.config.dtype)
+            return empty, empty
+        return tuple(
+            jnp.stack([
+                jnp.concatenate([b[i] for b in row[:n_blk]], axis=1) for row in rows
+            ]).astype(self.config.dtype)
+            for i in (0, 1)
+        )
+
+    def check_decode_options(self, spec_k: int, kv_quant: str) -> None:
+        """What the slot pool may be asked of this generator.  The looped
+        family has no verify/draft program and no int8 cache rows yet: it
+        says so here, at construction, instead of decoding wrongly."""
+        if self.looped and (spec_k >= 2 or kv_quant == "int8"):
+            raise ValueError(
+                "the looped decoder family serves with speculation off (decode.spec_k 0) and a "
+                f"bf16 cache (decode.kv_quant bf16); asked for spec_k={spec_k}, kv_quant={kv_quant}"
+            )
 
     # -- legacy full re-attend decode (parity reference / fallback) ----------
     def _decode_fn(self, B: int, L: int, steps: int):
@@ -417,6 +482,7 @@ class TextGenerator:
         """Per-(layer, head, channel) int8 K/V scales ``[L, H, hd]``
         for THIS generator's params (ops/kv_quant.py) — computed once,
         shared by every quantized pool over the instance."""
+        self.check_decode_options(0, "int8")
         if self._kv_scales is None:
             from ..ops.kv_quant import kv_pool_scales
 
@@ -443,8 +509,8 @@ class TextGenerator:
         same chain position the solo decode uses — and scatters every
         row into the pool at its slot, wiping the previous occupants.
         Joins arriving together batch into ONE dispatch (``B`` bucketed
-        to powers of two; pad rows scatter to an out-of-bounds slot
-        index and are dropped).  ``T`` is the POOL width:
+        to powers of two; a pad row repeats the first row, slot and all,
+        and so writes the same values again).  ``T`` is the POOL width:
         masked attention is width-invariant (extra key slots carry
         exact-zero probability), which is what keeps a pooled decode
         bit-identical to a solo one whose buffer is exactly
@@ -463,6 +529,13 @@ class TextGenerator:
             return fn
         self._tripwire.observe(key)
         cfg = self.config
+        if self.looped:
+            self.check_decode_options(0, "int8" if quant else "bf16")
+            fn = profile.wrap(
+                "generator.slot_prefill", looped.slot_prefill(cfg, S, T, B, L_sfx, P)
+            )
+            self._fns[key] = fn
+            return fn
         decoder = self._kv_module_q if quant else self._kv_module
         H = cfg.n_heads
         hd = cfg.d_model // H
@@ -524,12 +597,13 @@ class TextGenerator:
                 jnp.all(temps <= 0.0), greedy_only, sample, rngs
             )
             # ONE scatter per buffer: row i lands at pool slot
-            # ``slots[i]``; pad rows carry an out-of-bounds index and
-            # are DROPPED by the scatter (jax's default out-of-bounds
-            # scatter semantics), so padding can never clobber a slot
+            # ``slots[i]``; a pad row repeats row 0 (the engine fills it
+            # so), lands on row 0's slot with row 0's values, and can
+            # never clobber another slot
             pool_k = pool_k.at[slots].set(kbuf)
             pool_v = pool_v.at[slots].set(vbuf)
-            return pool_k, pool_v, toks.astype(jnp.int32), rngs
+            toks = toks.astype(jnp.int32)
+            return pool_k, pool_v, toks, rngs, token_stats(last0, toks)
 
         fn = profile.wrap("generator.slot_prefill", jax.jit(prefill))
         self._fns[key] = fn
@@ -555,6 +629,13 @@ class TextGenerator:
         if fn is not None:
             return fn
         self._tripwire.observe(key)
+        if self.looped:
+            self.check_decode_options(0, "int8" if quant else "bf16")
+            fn = profile.wrap(
+                "generator.slot_step", looped.slot_step(self.config, S, T, chunk)
+            )
+            self._fns[key] = fn
+            return fn
         decoder = self._slot_module_q if quant else self._slot_module
 
         def run(
@@ -607,13 +688,15 @@ class TextGenerator:
                 # lane's chain state is frozen where the solo decode's
                 # chain was when it emitted that request's last token
                 rngs3 = jnp.where(live[:, None], rngs2, rngs)
-                return (pool_k, pool_v, tok2, pos2, act2, left2, rngs3), emitted
+                return (pool_k, pool_v, tok2, pos2, act2, left2, rngs3), (
+                    emitted, token_stats(logits, nxt),
+                )
 
-            (pool_k, pool_v, _, _, _, _, rngs), em = jax.lax.scan(
+            (pool_k, pool_v, _, _, _, _, rngs), (em, extra) = jax.lax.scan(
                 one, (pool_k, pool_v, tok, pos, active, left, rngs),
                 None, length=chunk,
             )
-            return pool_k, pool_v, rngs, em
+            return pool_k, pool_v, rngs, em, extra
 
         fn = profile.wrap("generator.slot_step", jax.jit(run))
         self._fns[key] = fn
@@ -651,6 +734,7 @@ class TextGenerator:
         fn = self._fns.get(key)
         if fn is not None:
             return fn
+        self.check_decode_options(k, "int8" if quant else "bf16")
         self._tripwire.observe(key)
         decoder = self._slot_module_q if quant else self._slot_module
 
@@ -707,13 +791,13 @@ class TextGenerator:
                 left2 = jnp.where(live, left_c - 1, left_c)
                 # one split per EMITTED token — the solo chain position
                 rngs3 = jnp.where(live[:, None], rngs2, rngs)
-                return (acc2, pos2, left2, rngs3), emitted
+                return (acc2, pos2, left2, rngs3), (emitted, token_stats(lg, nxt))
 
             xs = (jnp.swapaxes(logits, 0, 1), follow.T)
-            (_, _, _, rngs), em = jax.lax.scan(
+            (_, _, _, rngs), (em, extra) = jax.lax.scan(
                 one, (live0, pos, left, rngs), xs
             )
-            return pool_k, pool_v, rngs, em
+            return pool_k, pool_v, rngs, em, extra
 
         fn = profile.wrap("generator.slot_verify", jax.jit(run))
         self._fns[key] = fn
@@ -738,6 +822,7 @@ class TextGenerator:
         fn = self._fns.get(key)
         if fn is not None:
             return fn
+        self.check_decode_options(k_draft + 1, "int8" if quant else "bf16")
         self._tripwire.observe(key)
         cfg = self.config
         decoder = SlotKVDecoder(cfg, quant=quant, layers=D)
@@ -872,6 +957,64 @@ class TextGenerator:
                 )
         return [self.render_tokens(row) for row in toks]
 
+    def _generate_looped(
+        self, prompts, max_new_tokens: int, temperature: float, seed: int, eos
+    ) -> List[str]:
+        """Solo decode of the looped family: the slot pool's own prefill
+        and step programs over a private pool, one slot per prompt, as wide
+        as the longest prompt plus the budget."""
+        from ..ops.dispatch_counter import record_fetch
+        from .encoder import _bucket
+
+        cfg = self.config
+        n, b = len(prompts), _bucket(len(prompts))
+        ids, mask = self.tokenizer.encode_batch(
+            [str(p) for p in prompts], max_length=cfg.max_len - max_new_tokens
+        )
+        ids, n_lens = np.asarray(ids), np.asarray(mask).sum(axis=1).astype(np.int32)
+        L = ids.shape[1]
+        T = -(-(L + max_new_tokens) // 64) * 64
+        chunk = min(max_new_tokens, decode_step_bucket())
+        pool = jnp.zeros((b, cfg.cache_depth, T, cfg.n_heads, cfg.head_dim), cfg.dtype)
+        empty = ((),) * b
+        pad = b - n
+        with self._lock:
+            prefill = self._slot_prefill_fn(b, T, b, L, 0)
+            step = self._slot_step_fn(b, T, chunk)
+        rng0 = np.stack([np.asarray(jax.random.PRNGKey(seed))] * b)
+        temps = jnp.full((b,), temperature, jnp.float32)
+        pk, pv, tok, rngs, _ = retry_call(
+            "generator.dispatch", prefill, self.params, pool, jnp.zeros_like(pool),
+            # a pad row repeats row 0 (same slot, same ids): it writes the same values again
+            jnp.asarray(np.r_[np.arange(n), np.zeros(pad)].astype(np.int32)),
+            jnp.asarray(np.r_[ids, ids[:1].repeat(pad, 0)]), jnp.asarray(np.r_[n_lens, n_lens[:1].repeat(pad)]),
+            empty, empty, jnp.asarray(rng0), temps,
+        )
+        # a blocking call by contract: each fetch below is booked
+        record_fetch("generator_solo")
+        rows = [[int(t)] for t in np.asarray(tok)[:n]]
+        pos = np.pad(n_lens, (0, pad))
+        active = np.r_[np.ones(n, bool), np.zeros(pad, bool)]
+        eos_arr = np.full(b, -1 if eos is None else int(eos), np.int32)
+        active[:n] &= np.asarray([r[0] for r in rows]) != eos_arr[:n]
+        left = np.where(active, max_new_tokens - 1, 0).astype(np.int32)
+        while (active & (left > 0)).any():
+            last = np.asarray([rows[i][-1] if i < n else 0 for i in range(b)], np.int32)
+            pk, pv, rngs, em, _ = retry_call(
+                "generator.dispatch", step, self.params, pk, pv, jnp.asarray(last),
+                jnp.asarray(pos), jnp.asarray(active), jnp.asarray(left), rngs, temps,
+                jnp.asarray(eos_arr), jnp.int32(chunk),
+            )
+            record_fetch("generator_solo")
+            for col in np.asarray(em):
+                for i in np.flatnonzero(col[:n] >= 0):
+                    rows[i].append(int(col[i]))
+                    pos[i] += 1
+                    left[i] -= 1
+                    active[i] = col[i] != eos_arr[i]
+        self.last_decode_steps = max(len(r) for r in rows)
+        return [self.render_tokens(r) for r in rows]
+
     def render_tokens(self, row: Sequence[int]) -> str:
         """Canonical token-id rendering (the hashing tokenizer is not
         invertible) — shared by every decode path, including the
@@ -905,6 +1048,10 @@ class TextGenerator:
         eos = self.eos_id if eos_id is _UNSET else eos_id
         if eos is not None and int(eos) == self.tokenizer.PAD:
             raise ValueError("eos_id must differ from the PAD token id")
+        if self.looped:
+            return self._generate_looped(
+                prompts, max_new_tokens, temperature, seed, eos
+            )
         if use_kv if use_kv is not None else self._use_kv:
             return self._generate_kv(
                 prompts, max_new_tokens, temperature, seed, eos=eos

@@ -76,6 +76,12 @@ class TransformerConfig:
     mesh: Any = None
     sequence_axis: Optional[str] = None
 
+    # what a cache of this trunk holds per sequence: one row per layer, one
+    # pass over the stack (models/looped.py states its own)
+    head_dim = property(lambda self: self.d_model // self.n_heads)
+    total_ut_steps = property(lambda self: 1)
+    cache_depth = property(lambda self: self.n_layers)
+
 
 class MlpBlock(nn.Module):
     config: TransformerConfig

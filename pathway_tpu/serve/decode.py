@@ -129,6 +129,26 @@ _H_TTLT = observe.histogram("pathway_generator_ttlt_seconds")
 _H_DRAFT_ACCEPT = observe.histogram(
     "pathway_generator_draft_accepted_tokens"
 )
+# the engine thread's host work, one span each (observe/spans.py), with the
+# thread-CPU twin: a join's tokenising and prefix walk, the prefill's and the
+# step chunk's call path up to the enqueue, and the fetch that waits for the
+# device
+_STAGE = "pathway_generator_stage_seconds"
+_STAGE_CPU = "pathway_generator_stage_cpu_seconds"
+_S_JOIN, _S_PREFILL_DISPATCH, _S_PREFILL_FETCH, _S_STEP_DISPATCH, _S_STEP_FETCH = (
+    {
+        "hist": observe.histogram(_STAGE, stage=stage),
+        "cpu_hist": observe.histogram(_STAGE_CPU, stage=stage),
+    }
+    for stage in ("join", "prefill_dispatch", "prefill_fetch", "step_dispatch", "step_fetch")
+)
+# a rider's waits, as the serve scheduler defines them: enqueue -> the join
+# that took it began; ticket resolved -> the rider is back from its wait
+_H_ADMISSION_WAIT = observe.histogram("pathway_generator_admission_wait_seconds")
+_H_TICKET_WAKE = observe.histogram("pathway_generator_ticket_wake_seconds")
+# what a request's meta carries of every emitted token (models/looped.py
+# ``token_stats``): its logit, the log-sum-exp, the top ids and logits
+_STAT_KEYS = ("logit", "lse", "top_ids", "top_logits")
 
 
 class DecodeResult(str):
@@ -165,7 +185,7 @@ class _SlotState:
 
     __slots__ = (
         "req", "budget", "temperature", "seed", "eos", "tokens", "pos",
-        "left", "t_join_ns", "prompt_ids",
+        "left", "t_join_ns", "prompt_ids", "t_admit_ns", "t_first", "stats",
     )
 
     def __init__(self, req, budget: int, temperature: float, seed: int, eos: int):
@@ -180,6 +200,11 @@ class _SlotState:
         self.t_join_ns = time.perf_counter_ns()
         # prompt token ids (host copy) — the n-gram draft mining corpus
         self.prompt_ids: List[int] = []
+        # when the first token reached the host (time.perf_counter()) and,
+        # per fetch, the emitted tokens' stats (``_STAT_KEYS`` arrays)
+        self.t_admit_ns = 0  # when the join that took the request began
+        self.t_first = 0.0
+        self.stats: List[Tuple[np.ndarray, ...]] = []
 
 
 def _spent_deadline() -> Deadline:
@@ -250,6 +275,9 @@ class ContinuousDecoder(_CoalescerBase):
             else ("int8" if kv_quant == "int8" else "bf16")
         )
         self._quant = self.kv_quant == "int8"
+        check = getattr(generator, "check_decode_options", None)
+        if check is not None:
+            check(self.spec_k, self.kv_quant)  # a family that cannot, refuses here
         self._draft_layers = decode_draft_layers(cfg.n_layers)
         # cooldown: after a draft/verify fault degrades a round to the
         # plain step, skip speculation for this many rounds so a
@@ -275,8 +303,10 @@ class ContinuousDecoder(_CoalescerBase):
         if kv_width is None:
             kv_width = config.get("decode.kv_width")
         self._T = min(cfg.max_len, kv_width) if kv_width else cfg.max_len
-        H = cfg.n_heads
-        hd = cfg.d_model // H
+        # one cache row per (loop step, layer): the architecture says how
+        # many, and how wide a head is
+        self._depth, self._heads, self._head_dim = cfg.cache_depth, cfg.n_heads, cfg.head_dim
+        self._loop_steps = cfg.total_ut_steps
         if self._quant:
             # int8 pool + per-(layer, head, channel) stored scales — the
             # scales are derived from the generator's params off the
@@ -286,11 +316,14 @@ class ContinuousDecoder(_CoalescerBase):
         else:
             self._kscale = self._vscale = None
             pool_dtype = cfg.dtype
-        self._pk = jnp.zeros(
-            (self.slots, cfg.n_layers, self._T, H, hd), pool_dtype
-        )
-        self._pv = jnp.zeros_like(self._pk)
+        self._pool_dtype = pool_dtype
+        self._alloc_pool()
         self._rngs = jnp.zeros((self.slots, 2), jnp.uint32)
+        # seconds a prefill ran while decode lanes were live and waiting,
+        # and the exit gate's mass per loop step over emitted tokens
+        self._stalled_s = 0.0
+        self._exit_mass_sum = np.zeros(self._loop_steps)
+        self._exit_mass_n = 0
         # slot allocation/free under the pool lock; dispatches NEVER
         # hold it (the analyzer's slot-pool lock convention)
         self._pool_lock = threading.Lock()
@@ -309,6 +342,8 @@ class ContinuousDecoder(_CoalescerBase):
             "spec_fallbacks": 0,   # rounds degraded to the plain step
             "draft_offered": 0,    # draft tokens proposed (Σ lanes × k-1)
             "draft_accepted": 0,   # draft tokens accepted by the verify
+            "loop_passes": 0,      # loop steps executed x tokens forwarded
+            "tokens_forwarded": 0, # tokens run through the stack (prefilled + stepped)
         }
         super().__init__(
             name=name or f"decode-{observe.next_id()}",
@@ -326,6 +361,28 @@ class ContinuousDecoder(_CoalescerBase):
             lambda d: d.slots - len(d._free),
             lambda d: d.slots,
         )
+
+    def _alloc_pool(self) -> None:
+        import jax.numpy as jnp
+
+        self._pk = jnp.zeros(
+            (self.slots, self._depth, self._T, self._heads, self._head_dim),
+            self._pool_dtype,
+        )
+        self._pv = jnp.zeros_like(self._pk)
+
+    def _pool_lost(self) -> bool:
+        """A program that donates the pool and then fails leaves it deleted:
+        nothing in flight can go on.  True if the pool had to be made anew."""
+        if not (self._pk.is_deleted() or self._pv.is_deleted()):
+            return False
+        self._alloc_pool()
+        return True
+
+    def kv_bytes_per_token(self) -> int:
+        """Cache bytes one token of one sequence holds: K and V, every
+        (loop step, layer) row."""
+        return 2 * self._depth * self._heads * self._head_dim * self._pk.dtype.itemsize
 
     def hbm_bytes(self) -> int:
         """Device bytes of the persistent slot pool (K + V buffers +
@@ -453,6 +510,7 @@ class ContinuousDecoder(_CoalescerBase):
         gen = self.generator
         cfg = gen.config
         ready: List[dict] = []
+        t_join_ns = time.perf_counter_ns()
         for req in reqs:
             text, steps, temp, seed, eos = req.items[0]
             _H_QUEUE_WAIT.observe_ns(
@@ -480,21 +538,23 @@ class ContinuousDecoder(_CoalescerBase):
                         f"max_new_tokens={steps} leaves no prompt budget "
                         f"(max_len={cfg.max_len})"
                     )
-                ids, mask = gen.tokenizer.encode_batch(
-                    [text], max_length=L_budget
-                )
-                ids = np.asarray(ids)
-                n = int(np.asarray(mask).sum())
-                if ids.shape[1] + steps > self._T:
+                with observe.span("gen.join", **_S_JOIN):
+                    ids, mask = gen.tokenizer.encode_batch(
+                        [text], max_length=L_budget
+                    )
+                    ids = np.asarray(ids)
+                    n = int(np.asarray(mask).sum())
+                    fits = ids.shape[1] + steps <= self._T
+                    P, matches = 0, []
+                    if fits and gen.kv_cache is not None:
+                        P, matches = gen._cached_prefix(
+                            ids, np.asarray([n], np.int32), 1
+                        )
+                if not fits:
                     # narrowed pool (kv_width): this request does not fit
                     # — serve it solo through the legacy path instead
                     self._dispatch_batch([req], solo=True)
                     continue
-                P, matches = 0, []
-                if gen.kv_cache is not None:
-                    P, matches = gen._cached_prefix(
-                        ids, np.asarray([n], np.int32), 1
-                    )
             except Exception as exc:
                 log_once(
                     f"decode.join:{type(exc).__name__}",
@@ -516,7 +576,7 @@ class ContinuousDecoder(_CoalescerBase):
                 req=req, ids=ids, n=n, P=P,
                 match=matches[0] if matches else None,
                 L_sfx=ids.shape[1] - P, steps=steps, temp=temp,
-                seed=seed, eos=eos,
+                seed=seed, eos=eos, t_join_ns=t_join_ns,
             ))
         with self._pool_lock:
             free = len(self._free)
@@ -550,9 +610,8 @@ class ContinuousDecoder(_CoalescerBase):
 
         gen = self.generator
         cfg = gen.config
-        H = cfg.n_heads
-        hd = cfg.d_model // H
         n_real = len(grp)
+        n_live = len(self._active)  # decode lanes this prefill keeps waiting
         with self._pool_lock:
             slots_real = [self._free.pop() for _ in range(n_real)]
         # batch bucket: the model batch buckets (1, 4, 16, ...), so a
@@ -560,78 +619,43 @@ class ContinuousDecoder(_CoalescerBase):
         B = 1
         while B < n_real:
             B *= 4
-        pad = B - n_real
         try:
-            # real rows first; pad rows scatter to the out-of-bounds
-            # index ``slots`` (dropped by the scatter, never a clobber)
-            slot_arr = np.asarray(
-                slots_real + [self.slots] * pad, np.int32
-            )
-            suffix = np.zeros((B, L_sfx), np.int32)
-            n_len = np.zeros(B, np.int32)
-            temps = np.zeros(B, np.float32)
-            rng_rows: List[Any] = []
-            for j, rec in enumerate(grp):
-                row = rec["ids"][0, P:]
-                suffix[j, : row.shape[0]] = row
-                n_len[j] = rec["n"]
-                temps[j] = rec["temp"]
-                rng_rows.append(np.asarray(jax.random.PRNGKey(rec["seed"])))
-            rng_rows += [np.zeros(2, np.uint32)] * pad
-            if P:
-                blk = gen.kv_cache.block
-                zero = np.zeros((cfg.n_layers, P, H, hd), np.float32)
-                rows_k: List[Any] = []
-                rows_v: List[Any] = []
-                for rec in grp:
-                    blocks = rec["match"][1][: P // blk]
-                    rows_k.append(
-                        jnp.concatenate([b[0] for b in blocks], axis=1)
-                    )
-                    rows_v.append(
-                        jnp.concatenate([b[1] for b in blocks], axis=1)
-                    )
-                rows_k += [zero] * pad
-                rows_v += [zero] * pad
-                prefix_k = jnp.stack(
-                    [jnp.asarray(r, cfg.dtype) for r in rows_k]
+            with observe.span(
+                "gen.prefill.dispatch", rows=n_real, batch=B,
+                suffix_tokens=L_sfx, prefix_tokens=P, **_S_PREFILL_DISPATCH,
+            ):
+                t0 = time.perf_counter_ns()
+                suffix = np.zeros((B, L_sfx), np.int32)
+                n_len = np.zeros(B, np.int32)
+                temps = np.zeros(B, np.float32)
+                seeds: List[int] = []
+                for j, rec in enumerate(grp):
+                    row = rec["ids"][0, P:]
+                    suffix[j, : row.shape[0]] = row
+                    n_len[j] = rec["n"]
+                    temps[j] = rec["temp"]
+                    seeds.append(rec["seed"])
+                blocks = [rec["match"][1] if P else [] for rec in grp]
+                pk, pv, toks, rngs_all, extra = self._dispatch_prefill(
+                    B, L_sfx, P, slots_real, suffix, n_len, temps, seeds, blocks,
+                    self._batch_deadline([rec["req"] for rec in grp]),
                 )
-                prefix_v = jnp.stack(
-                    [jnp.asarray(r, cfg.dtype) for r in rows_v]
-                )
-            else:
-                prefix_k = jnp.zeros((B, cfg.n_layers, 0, H, hd), cfg.dtype)
-                prefix_v = jnp.zeros((B, cfg.n_layers, 0, H, hd), cfg.dtype)
-            with gen._lock:
-                fn = gen._slot_prefill_fn(
-                    self.slots, self._T, B, L_sfx, P, self._quant
-                )
-            sc = (self._kscale, self._vscale) if self._quant else ()
-            deadline = self._batch_deadline([rec["req"] for rec in grp])
-            t0 = time.perf_counter_ns()
-            # pathway: allow(recompile-hazard): prefill shapes are bucketed upstream — the tokenizer pads suffix length to /16 multiples, the prefix split is a power-of-two block multiple (PrefixKVCache.bucket_tokens) and the join batch is a power-of-two bucket; the census test bounds the signature set
-            pk, pv, toks, rngs_out = retry_call(
-                "generator.prefill",
-                fn,
-                gen.params,
-                self._pk,
-                self._pv,
-                jnp.asarray(slot_arr),
-                jnp.asarray(suffix),
-                jnp.asarray(n_len),
-                prefix_k,
-                prefix_v,
-                jnp.asarray(np.stack(rng_rows)),
-                jnp.asarray(temps),
-                *sc,
-                deadline=deadline,
-            )
-            firsts = np.asarray(toks)  # pathway: allow(value-flow): the prefill JOIN's one deliberate host fetch — first tokens must reach the riders' tickets before the step loop takes over
+            with observe.span("gen.prefill.fetch", **_S_PREFILL_FETCH):
+                # the prefill JOIN's one deliberate host fetch: first tokens
+                # (and what the program read off their logits) must reach the
+                # riders' tickets before the step loop takes over
+                firsts = np.asarray(toks)
+                extra = jax.device_get(extra)
+            t_first = time.perf_counter()
             t1 = time.perf_counter_ns()
             _H_PREFILL.observe_ns(t1 - t0)
+            if n_live:
+                self._stalled_s += (t1 - t0) * 1e-9
         except Exception as exc:
             for slot in slots_real:
                 self._free_slot(slot)
+            if self._pool_lost():
+                self._evict_all(exc)
             log_once(
                 f"decode.prefill:{type(exc).__name__}",
                 "continuous-decode prefill failed (%r); degrading the "
@@ -649,10 +673,7 @@ class ContinuousDecoder(_CoalescerBase):
                     ),
                 )
             return
-        self._pk, self._pv = pk, pv
-        self._rngs = self._rngs.at[jnp.asarray(slots_real)].set(
-            rngs_out[:n_real]
-        )
+        self._pk, self._pv, self._rngs = pk, pv, rngs_all
         pk_now, pv_now = self._pk, self._pv
         for j, rec in enumerate(grp):
             req = rec["req"]
@@ -692,6 +713,8 @@ class ContinuousDecoder(_CoalescerBase):
                 gen.kv_cache.note_prefill(reused=P, computed=rec["n"] - P)
             self.pool_stats["tokens_prefill"] += rec["n"] - P
             self.pool_stats["tokens_decode"] += 1
+            self.pool_stats["tokens_forwarded"] += rec["n"] - P
+            self.pool_stats["loop_passes"] += (rec["n"] - P) * self._loop_steps
             if req.trace is not None:
                 req.trace.add_span(
                     "decode.prefill", t0, t1,
@@ -702,6 +725,10 @@ class ContinuousDecoder(_CoalescerBase):
                 req, rec["steps"], rec["temp"], rec["seed"], rec["eos"]
             )
             state.tokens = [first]
+            state.t_first = t_first
+            state.t_admit_ns = rec["t_join_ns"]
+            state.stats.append(tuple(extra[k][j : j + 1] for k in _STAT_KEYS))
+            self._note_exit_mass(extra, (j,))
             state.pos = rec["n"]
             state.left = rec["steps"] - 1
             # host copy of the prompt ids: the draft miner's corpus
@@ -710,8 +737,99 @@ class ContinuousDecoder(_CoalescerBase):
             if (rec["eos"] >= 0 and first == rec["eos"]) or state.left <= 0:
                 self._leave(slot, state)
 
+    def _dispatch_prefill(
+        self, B, L_sfx, P, slots_real, suffix, n_len, temps, seeds, blocks, deadline
+    ):
+        """One prefill dispatch of ``B`` rows, the first ``len(slots_real)``
+        of them real: the compiled-fn lookup, the pad rows, the cached prefix
+        operands, the call.  Returns the new pools and rng chains
+        (the caller rebinds them once the fetch has succeeded), the first
+        tokens and what was read off their logits.  Every operand's shape
+        follows from ``(B, L_sfx, P)`` alone, so ``warm`` covers what a join
+        will run."""
+        import jax
+        import jax.numpy as jnp
+
+        gen = self.generator
+        with gen._lock:
+            fn = gen._slot_prefill_fn(
+                self.slots, self._T, B, L_sfx, P, self._quant
+            )
+        # real rows first; a pad row repeats the first row (its slot, its
+        # ids, its prefix, its seed), so it writes the same values again and
+        # no slot index is ever out of bounds.  With no real row at all
+        # (``warm``) every row names slot 0 of the idle pool: a join rewrites
+        # every position it will attend before it attends it
+        n_real = len(slots_real)
+        fill = lambda rows, blank: list(rows) + [rows[0] if n_real else blank] * (B - n_real)  # noqa: E731
+        slot_arr = jnp.asarray(np.asarray(fill(slots_real, 0), np.int32))
+        if n_real:
+            suffix[n_real:], n_len[n_real:], temps[n_real:] = suffix[0], n_len[0], temps[0]
+        rng_rows = fill([np.asarray(jax.random.PRNGKey(seed)) for seed in seeds], np.zeros(2, np.uint32))
+        n_blk = P // gen.kv_cache.block if P else 0
+        blank = []  # a row of zero blocks: only ``warm`` has no real row to repeat
+        if n_blk and not n_real:
+            zero = jnp.zeros((self._depth, P // n_blk, self._heads, self._head_dim), gen.config.dtype)
+            blank = [(zero, zero)] * n_blk
+        prefix_k, prefix_v = gen.slot_prefix(
+            fill(blocks, blank), n_blk, (self._depth, P, self._heads, self._head_dim)
+        )
+        sc = (self._kscale, self._vscale) if self._quant else ()
+        # pathway: allow(recompile-hazard): prefill shapes are bucketed upstream — the tokenizer pads suffix length to /16 multiples, the prefix split is a power-of-two block multiple (PrefixKVCache.bucket_tokens) and the join batch is a power-of-two bucket; the census test bounds the signature set
+        pk, pv, toks, rngs_out, extra = retry_call(
+            "generator.prefill",
+            fn,
+            gen.params,
+            self._pk,
+            self._pv,
+            slot_arr,
+            jnp.asarray(suffix),
+            jnp.asarray(n_len),
+            prefix_k,
+            prefix_v,
+            jnp.asarray(np.stack(rng_rows)),
+            jnp.asarray(temps),
+            *sc,
+            deadline=deadline,
+        )
+        return pk, pv, toks, self._rngs.at[slot_arr].set(rngs_out), extra
+
+    def warm(self, prompt_tokens: Tuple[int, int], prefix_tokens: Sequence[int] = (0,)) -> int:
+        """Compile and run once every program that joins and steps of prompts
+        of ``prompt_tokens`` = (fewest, most) tokens can reach, with cached
+        prefixes of ``prefix_tokens`` tokens: each join batch bucket at each
+        suffix bucket, all rows padding (they write slot 0 of the idle pool,
+        which its next occupant writes again before it reads), and the step
+        chunk with no lane live.  For a server's
+        start-up, before traffic: a shape first met in flight stalls every
+        live lane for its compile.  Returns the programs run."""
+        if self._active:
+            raise RuntimeError("warm() runs on an idle pool: before traffic, not under it")
+        lo, hi = prompt_tokens
+        padded = sorted({min(self._T, -(-n // 16) * 16) for n in range(lo, hi + 1)})
+        sizes = [1]
+        while sizes[-1] < self.slots:
+            sizes.append(sizes[-1] * 4)
+        shapes = set()
+        for P in prefix_tokens:
+            for width in padded:
+                L_pad = 16
+                while L_pad < width - P:
+                    L_pad *= 2
+                shapes.update((B, min(L_pad, self._T - P), P) for B in sizes)
+        for B, L_sfx, P in sorted(shapes):
+            pk, pv, toks, rngs, _ = self._dispatch_prefill(
+                B, L_sfx, P, [], np.zeros((B, L_sfx), np.int32), np.zeros(B, np.int32),
+                np.zeros(B, np.float32), [], [], None,
+            )
+            np.asarray(toks)  # start-up, before traffic: wait for each program to have run
+            self._pk, self._pv, self._rngs = pk, pv, rngs
+        self._step_chunk()
+        return len(shapes) + 1
+
     # -- decode step chunk ---------------------------------------------------
     def _step_chunk(self) -> None:
+        import jax
         import jax.numpy as jnp
 
         gen = self.generator
@@ -732,6 +850,13 @@ class ContinuousDecoder(_CoalescerBase):
         with gen._lock:
             fn = gen._slot_step_fn(S, self._T, self.chunk, self._quant)
         sc = (self._kscale, self._vscale) if self._quant else ()
+        n_steps = self.chunk
+        if getattr(gen, "looped", False):
+            # the looped family's step program takes the number of steps to
+            # run: no further than the nearest budget's end, so a lane leaves
+            # (and the next request joins) at the step it finishes
+            n_steps = min([self.chunk] + [st.left for st in self._active.values()])
+            sc = (jnp.int32(max(n_steps, 1)),)
         deadline = self._batch_deadline(
             [st.req for st in self._active.values()]
         )
@@ -758,16 +883,15 @@ class ContinuousDecoder(_CoalescerBase):
                 jnp.asarray(pos), jnp.asarray(act), jnp.asarray(left),
                 self._rngs, jnp.asarray(temps), jnp.asarray(eos), *sc,
             )
-            if bctx is not None:
-                with trace.use(bctx):
-                    pk, pv, rngs, em = retry_call(
-                        "generator.step", fn, *args, deadline=deadline
-                    )
-            else:
-                pk, pv, rngs, em = retry_call(
+            with trace.use(bctx), observe.span(
+                "gen.step.dispatch", slots=len(self._active), **_S_STEP_DISPATCH
+            ):
+                pk, pv, rngs, em, extra = retry_call(
                     "generator.step", fn, *args, deadline=deadline
                 )
-            em = np.asarray(em)  # [chunk, S]: the per-chunk host fetch  # pathway: allow(value-flow): THE decode-loop fetch — one deliberate sync per step chunk delivers every slot's tokens to its rider
+            with observe.span("gen.step.fetch", **_S_STEP_FETCH):
+                em = np.asarray(em)  # [chunk, S]: the per-chunk host fetch  # pathway: allow(value-flow): THE decode-loop fetch — one deliberate sync per step chunk delivers every slot's tokens to its rider
+                extra = jax.device_get(extra)  # pathway: allow(value-flow): the same fetch's second half — what was read off those tokens' logits, outputs of the same program
         except Exception as exc:
             if bctx is not None:
                 trace.finish(bctx, statuses=("error",))
@@ -778,12 +902,13 @@ class ContinuousDecoder(_CoalescerBase):
                 exc,
             )
             self._evict_all(exc)
+            self._pool_lost()
             return
         t1 = time.perf_counter_ns()
         _H_STEP.observe_ns(t1 - t0)
         self._pk, self._pv, self._rngs = pk, pv, rngs
         self.pool_stats["chunks"] += 1
-        self.pool_stats["steps"] += self.chunk
+        self.pool_stats["steps"] += n_steps
         self.pool_stats["occupancy_sum"] += len(self._active)
         if bctx is not None:
             trace.finish(bctx)
@@ -800,15 +925,15 @@ class ContinuousDecoder(_CoalescerBase):
         for s, st in list(self._active.items()):
             flags: Tuple[str, ...] = ()
             finished = False
-            for i in range(self.chunk):
+            took = 0
+            for i in range(n_steps):
                 t = int(em[i, s])  # pathway: allow(value-flow): `em` was rebound to its HOST copy at the fetch above — the rule's name-level residency tracking cannot see the rebind; no device touch happens here
                 st.tokens.append(t)
-                st.pos += 1
-                st.left -= 1
-                self.pool_stats["tokens_decode"] += 1
-                if (st.eos >= 0 and t == st.eos) or st.left <= 0:
+                took += 1
+                if (st.eos >= 0 and t == st.eos) or st.left - took <= 0:
                     finished = True
                     break
+            self._took(st, s, took, extra)
             if not finished and (
                 st.req.deadline is not None and st.req.deadline.expired()
             ):
@@ -820,6 +945,24 @@ class ContinuousDecoder(_CoalescerBase):
                 leaves.append((s, st, flags))
         for s, st, flags in leaves:
             self._leave(s, st, flags=flags)
+
+    def _took(self, st: _SlotState, lane: int, n: int, extra) -> None:
+        """Book the first ``n`` tokens of a fetched chunk to a lane."""
+        st.pos += n
+        st.left -= n
+        st.stats.append(tuple(extra[k][:n, lane] for k in _STAT_KEYS))
+        self.pool_stats["tokens_decode"] += n
+        self.pool_stats["tokens_forwarded"] += n
+        self.pool_stats["loop_passes"] += n * self._loop_steps
+        self._note_exit_mass(extra, (slice(0, n), lane))
+
+    def _note_exit_mass(self, extra, at) -> None:
+        mass = extra.get("exit_mass")  # [..., loop steps]: the looped family's gate
+        if mass is None:  # one pass over the stack: every token leaves at step 0
+            mass = np.ones(np.shape(extra["lse"]) + (1,))
+        rows = np.asarray(mass[at], np.float64).reshape(-1, self._loop_steps)
+        self._exit_mass_sum += rows.sum(axis=0)
+        self._exit_mass_n += rows.shape[0]
 
     # -- speculative decode: draft → verify → accept -------------------------
     def _spec_ready(self) -> bool:
@@ -897,7 +1040,7 @@ class ContinuousDecoder(_CoalescerBase):
                 return cont[:want]
         return []
 
-    def _spec_round(self) -> None:
+    def _spec_round(self) -> None:  # noqa: C901
         """One draft→verify→accept round over the pool: propose ``k-1``
         tokens per lane (n-gram mining, trunk fallback), verify all
         ``k`` positions in ONE batched dispatch, commit each lane's
@@ -906,6 +1049,7 @@ class ContinuousDecoder(_CoalescerBase):
         of dispatches per token changes.  Any draft/verify fault falls
         back to the plain step chunk for this round — pool untouched,
         token-identical — and arms a cooldown."""
+        import jax
         import jax.numpy as jnp
 
         gen = self.generator
@@ -989,16 +1133,12 @@ class ContinuousDecoder(_CoalescerBase):
                 jnp.asarray(pos), jnp.asarray(act), jnp.asarray(left),
                 self._rngs, jnp.asarray(temps), jnp.asarray(eos), *sc,
             )
-            if bctx is not None:
-                with trace.use(bctx):
-                    pk, pv, rngs, em = retry_call(
-                        "generator.verify", vfn, *args, deadline=deadline
-                    )
-            else:
-                pk, pv, rngs, em = retry_call(
+            with trace.use(bctx):
+                pk, pv, rngs, em, extra = retry_call(
                     "generator.verify", vfn, *args, deadline=deadline
                 )
             em = np.asarray(em)  # [k, S]  # pathway: allow(value-flow): THE decode-loop fetch (speculative flavor) — one deliberate sync per round delivers every slot's accepted tokens to its rider
+            extra = jax.device_get(extra)  # pathway: allow(value-flow): the same fetch's second half — what was read off those tokens' logits, outputs of the same program
         except Exception as exc:
             if bctx is not None:
                 trace.finish(bctx, statuses=("speculation_disabled",))
@@ -1049,13 +1189,11 @@ class ContinuousDecoder(_CoalescerBase):
                 if t < 0:
                     break
                 st.tokens.append(t)
-                st.pos += 1
-                st.left -= 1
                 emitted += 1
-                self.pool_stats["tokens_decode"] += 1
-                if (st.eos >= 0 and t == st.eos) or st.left <= 0:
+                if (st.eos >= 0 and t == st.eos) or st.left - emitted <= 0:
                     finished = True
                     break
+            self._took(st, s, emitted, extra)
             _H_DRAFT_ACCEPT.observe_s(float(emitted))
             self.pool_stats["draft_offered"] += k - 1
             self.pool_stats["draft_accepted"] += max(0, emitted - 1)
@@ -1075,7 +1213,21 @@ class ContinuousDecoder(_CoalescerBase):
         self, slot: int, st: _SlotState, flags: Tuple[str, ...] = ()
     ) -> None:
         gen = self.generator
-        meta: Dict[str, Any] = {"tokens": len(st.tokens), "slot": slot}
+        meta: Dict[str, Any] = {
+            "tokens": len(st.tokens), "slot": slot,
+            # what a caller may check or time (PERF.md): when the first token
+            # reached the host (``time.perf_counter()``), the prompt's ids as
+            # prefilled, the ids emitted, and per emitted token the float32
+            # logit of the token chosen, the log-sum-exp and the top ids and
+            # logits it was chosen from
+            "t_first_token": st.t_first,
+            "prompt_ids": list(st.prompt_ids),
+            "token_ids": list(st.tokens),
+            "logprobs": {
+                key: np.concatenate([part[i] for part in st.stats]).tolist()
+                for i, key in enumerate(_STAT_KEYS)
+            },
+        }
         if flags:
             self.pool_stats["evicted"] += 1
             meta["partial"] = True
@@ -1100,6 +1252,7 @@ class ContinuousDecoder(_CoalescerBase):
             DecodeResult(
                 gen.render_tokens(st.tokens), degraded=flags, meta=meta
             ),
+            t_join_ns=st.t_admit_ns,
         )
 
     def _evict_all(self, exc: BaseException) -> None:
@@ -1145,11 +1298,14 @@ class ContinuousDecoder(_CoalescerBase):
         with self._pool_lock:
             self._free.append(slot)
 
-    def _resolve(self, req, result: DecodeResult) -> None:
+    def _resolve(self, req, result: DecodeResult, t_join_ns: int = 0) -> None:
         req.slots = [0]
         req.batch = _Batch(
             lambda _r=result: [_r], 1, 1, self._degrade_empty
         )
+        # the rider records its own waits from these two reads (``_demux``)
+        req.batch.t_launch_ns = t_join_ns
+        req.batch.link["t_resolved_ns"] = time.perf_counter_ns()
         req.event.set()
         if req.trace is not None:
             trace.finish(req.trace, statuses=tuple(result.degraded))
@@ -1176,7 +1332,12 @@ class ContinuousDecoder(_CoalescerBase):
     def _demux(self, req, batch_result) -> DecodeResult:
         # time-to-last-token, pool and solo paths alike (the waiter's
         # completion is the client-visible "last token")
-        _H_TTLT.observe_ns(time.perf_counter_ns() - req.t_enqueue_ns)
+        t_woke = time.perf_counter_ns()
+        _H_TTLT.observe_ns(t_woke - req.t_enqueue_ns)
+        t_join, t_resolved = req.batch.t_launch_ns, req.batch.link.get("t_resolved_ns", 0)
+        if t_join:  # joined the pool: enqueue -> its join began; resolved -> woke
+            observe.interval("admission_wait", req.t_enqueue_ns, t_join, hist=_H_ADMISSION_WAIT, tree=req.trace)
+            observe.interval("ticket_wake", t_resolved, t_woke, hist=_H_TICKET_WAKE, tree=req.trace)
         out = []
         for slot in req.slots:
             if 0 <= slot < len(batch_result):
@@ -1205,11 +1366,29 @@ class ContinuousDecoder(_CoalescerBase):
             "gauge", "pathway_generator_slots_active", labels,
             len(self._active),
         )
+        yield ("gauge", "pathway_generator_slots_live", labels, len(self._active))
         yield (
             "gauge", "pathway_generator_slots_quarantined", labels,
             self.pool_stats["quarantined"],
         )
-        for phase in ("prefill", "decode"):
+        yield (
+            "gauge", "pathway_generator_kv_bytes_per_token", labels,
+            self.kv_bytes_per_token(),
+        )
+        yield (
+            "counter", "pathway_generator_loop_passes_total", labels,
+            self.pool_stats["loop_passes"],
+        )
+        yield (
+            "counter", "pathway_generator_stalled_seconds_total", labels,
+            self._stalled_s,
+        )
+        for u in range(self._loop_steps if self._exit_mass_n else 0):
+            yield (
+                "gauge", "pathway_generator_exit_mass", {**labels, "step": u},
+                self._exit_mass_sum[u] / self._exit_mass_n,
+            )
+        for phase in ("prefill", "decode", "forwarded"):
             yield (
                 "counter",
                 "pathway_generator_tokens_total",

@@ -1,0 +1,77 @@
+"""The looped family's slot programs, compiled for one v5e chip at Ouro-2.6B's
+widths, with no chip attached: what the chip's compiler refuses costs no chip
+time.  Guards two faults met on the way (ISSUE 28): a join of one row whose
+single-index scatter became a dynamic-update-slice and made the compiler copy
+both 3.4 GB pools, and query/key/value projections kept ``[hidden, heads *
+head_dim]`` that it transposed, 1.2 GB, on every call.  Either puts the
+program past the chip's 15.75 GB beside 5.3 GB of weights and a 7.25 GB pool.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from pathway_tpu.models import looped
+
+ARCH = dict(
+    vocab_size=49152, hidden_size=2048, num_attention_heads=16, num_key_value_heads=16, head_dim=128,
+    intermediate_size=5632, num_hidden_layers=48, total_ut_steps=4, rms_norm_eps=1e-6, rope_theta=1e6,
+    max_position_embeddings=65536,
+)
+SLOTS, WIDTH, BLOCK = 12, 384, 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler for the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(one_chip):
+    cfg = looped.LoopedConfig.from_architecture(ARCH)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: looped.init_params(cfg, 0)))
+    pool = sds((SLOTS, cfg.cache_depth, WIDTH, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+    return cfg, sds, params, pool
+
+
+def _fits(compiled, pool):
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * pool.size * 2, "the pools are not updated in place"
+    assert m.temp_size_in_bytes < 1.0e9, f"{m.temp_size_in_bytes / 1e9:.2f} GB of temporaries: a pool or a weight stack is being copied"
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
+
+
+def test_step_chunk_fits_beside_weights_and_pool(one_chip):
+    cfg, sds, params, pool = _shapes(one_chip)
+    S = SLOTS
+    args = (params, pool, pool, sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.bool_), sds((S,), jnp.int32),
+            sds((S, 2), jnp.uint32), sds((S,), jnp.float32), sds((S,), jnp.int32), sds((), jnp.int32))
+    _fits(looped.slot_step(cfg, S, WIDTH, 8).lower(*args).compile(), pool)
+
+
+@pytest.mark.parametrize("B,L,P", [(1, 128, 32), (1, 384, 0), (16, 256, 32)], ids=["one-row-warm", "one-row-cold", "sixteen-rows-warm"])
+def test_join_fits_beside_weights_and_pool(one_chip, B, L, P):
+    cfg, sds, params, pool = _shapes(one_chip)
+    block = sds((cfg.cache_depth, BLOCK, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+    prefix = tuple((block,) * (P // BLOCK) for _ in range(B))
+    args = (params, pool, pool, sds((B,), jnp.int32), sds((B, L), jnp.int32), sds((B,), jnp.int32), prefix, prefix,
+            sds((B, 2), jnp.uint32), sds((B,), jnp.float32))
+    _fits(looped.slot_prefill(cfg, SLOTS, WIDTH, B, L, P).lower(*args).compile(), pool)
