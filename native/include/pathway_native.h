@@ -109,6 +109,33 @@ int32_t pn_encode_pairs(const uint8_t* blob, const int64_t* offsets,
                         int64_t* tok_offsets, int32_t* out_ids,
                         int32_t* out_mask, int64_t* out_lens);
 
+/* ---- sequence packing (models/packing.py) ----
+ * pack_rows + pad_packed_rows + the rerank pipeline's pair_slot loop in one
+ * call.  ids_b [n, width] holds n >= 1 tokenized sequences of lens[i] tokens
+ * (0 <= lens[i] <= min(L, width)); they are placed best-fit-decreasing into
+ * rows of L tokens exactly as pack_rows' Python body does (stable descending
+ * length; least open capacity that holds the sequence, ties to the lower
+ * row; at most max_docs_per_row a row; a row stays open while it has fewer
+ * than that and >= 2 tokens left).  The bucket RULES stay in Python and come
+ * in as tables: row_buckets[n_row_buckets] ascending, last >= n (the padded
+ * row count Rb is the first >= R); seg_buckets[c - 1] = the segment width Sb
+ * of a batch whose fullest row holds c sequences, c = 1..max_docs_per_row.
+ * Writes ids / segments (1-based per row) / positions (restarting per
+ * sequence) at [Rb, L], pad rows zero, into buffers of row_buckets[last] * L
+ * int32 (their contents on entry do not matter); row_of / seg_of [n] = the
+ * (row, 0-based segment) of sequence i; out_dims = {R, n_seg, Rb, Sb}.  With
+ * slot_ids != NULL also out_pair_slot[Rb * Sb] (capacity row_buckets[last] *
+ * seg_buckets[last]): drop_slot everywhere, slot_ids[i] at row * Sb + seg.
+ * Returns 0, or -1 on arguments it cannot lay out (caller packs in Python). */
+int32_t pn_pack_rows(const int32_t* ids_b, int64_t n, int64_t width,
+                     const int64_t* lens, int64_t L, int64_t max_docs_per_row,
+                     const int64_t* row_buckets, int64_t n_row_buckets,
+                     const int64_t* seg_buckets, const int32_t* slot_ids,
+                     int32_t drop_slot, int32_t* out_ids,
+                     int32_t* out_segments, int32_t* out_positions,
+                     int32_t* out_pair_slot, int64_t* row_of, int64_t* seg_of,
+                     int64_t* out_dims);
+
 /* ---- shard routing ----
  * shard(key) = (key & shard_mask) % n_shards (reference
  * src/engine/dataflow/shard.rs:6 + value.rs:38).  Produces per-shard counts

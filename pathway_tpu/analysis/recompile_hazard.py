@@ -33,7 +33,11 @@ from .registry import dotted_name, is_jit_call, scope_jit_and_device_vars, walk_
 __all__ = ["RecompileHazardRule"]
 
 _UPLOAD_CALLS = {"jnp.asarray", "jnp.array", "jax.numpy.asarray", "jax.numpy.array"}
-_BUCKET_HELPERS = {"_bucket", "seg_bucket", "row_length_bucket", "pad_packed_rows"}
+_BUCKET_HELPERS = {
+    "_bucket", "seg_bucket", "row_length_bucket", "pad_packed_rows",
+    # pack + pad to (_bucket(R), row_length_bucket, seg_bucket) in one call
+    "pack_padded", "_pack_pairs_padded",
+}
 
 
 class RecompileHazardRule(Rule):

@@ -35,6 +35,10 @@ _H_READY = observe.histogram("pathway_serve_model_seconds", model="cross_encoder
 # pair tokenisation alone, one bracket per packed batch, inside the pipeline's
 # stage2_packrows (which keeps measuring tokenise + pack + pad)
 _S2_TOKENIZE = observe.serve_stage("stage2_pair_tokenize", cpu=False)
+# pairs laid out into packed rows, by the path that took them (the native
+# call, or models/packing.py's Python body without the entry point)
+_PACKED_NATIVE = observe.counter("pathway_serve_pack_pairs_total", path="native")
+_PACKED_PYTHON = observe.counter("pathway_serve_pack_pairs_total", path="python")
 
 
 class _CrossEncoderModule(nn.Module):
@@ -233,30 +237,54 @@ class CrossEncoderModel:
         return complete
 
     # -- sequence packing ---------------------------------------------------
-    def _pack_pairs(self, pairs: Sequence[Tuple[str, str]], span=None):
+    def _pack_pairs_padded(
+        self,
+        pairs: Sequence[Tuple[str, str]],
+        slot_ids: Optional[Sequence[int]] = None,
+        drop_slot: int = 0,
+        span=None,
+    ):
         """Tokenize (query, doc) pairs and pack them into length-bucketed
-        rows (models/packing.py): the row width is the smallest bucket
-        holding the longest pair, so a 20-token pair never burns a full
-        ``max_length``-token row of MXU work.  Returns (ids, segments,
-        positions, doc_slots, n_seg) with doc_slots[i] = (row, seg-1) of
-        pair i.  ``span`` is the caller's open bracket around the packing
-        (the pipeline's ``stage2.pack``): it learns how many of the pairs
-        the native tokenizer took (``native_pairs``)."""
-        from .packing import pack_rows, row_length_bucket
+        rows at their compile shape (models/packing.py ``pack_padded`` ->
+        ``PackedRows``): the row width is the smallest bucket holding the
+        longest pair, so a 20-token pair never burns a full
+        ``max_length``-token row of MXU work; rows and segment width are
+        padded to their buckets; ``slot_ids`` asks for the rerank
+        pipeline's scatter table.  ``span`` is the caller's open bracket
+        around the packing (the pipeline's ``stage2.pack``): it learns how
+        many of the pairs the native tokenizer took (``native_pairs``) and
+        how many the native row layout (``native_packed``)."""
+        from .encoder import _bucket
+        from .packing import pack_padded, row_length_bucket
 
         qs = [str(p[0]) for p in pairs]
         ds = [str(p[1]) for p in pairs]
         with observe.span("stage2.tokenize", **_S2_TOKENIZE):
             ids_b, mask_b, native = self.tokenizer.encode_pairs(qs, ds)
-        if span is not None:
-            span.set(native_pairs=len(pairs) if native else 0)
         lens = mask_b.sum(axis=1, dtype=np.int64)
         L = row_length_bucket(int(lens.max()), self.config.max_len)
         lens = np.minimum(lens, L)
-        ids, _mask, segments, positions, doc_slots, n_seg = pack_rows(
-            ids_b, lens, L
+        packed = pack_padded(
+            ids_b, lens, L, _bucket, slot_ids=slot_ids, drop_slot=drop_slot
         )
-        return ids, segments, positions, doc_slots, n_seg
+        (_PACKED_NATIVE if packed.native else _PACKED_PYTHON).inc(len(pairs))
+        if span is not None:
+            span.set(
+                native_pairs=len(pairs) if native else 0,
+                native_packed=len(pairs) if packed.native else 0,
+            )
+        return packed
+
+    def _pack_pairs(self, pairs: Sequence[Tuple[str, str]], span=None):
+        """The bare layout of ``_pack_pairs_padded``, as ``pack_rows`` gives
+        it: (ids [R, L], segments, positions, doc_slots, n_seg) with
+        doc_slots[i] = (row, seg-1) of pair i."""
+        p = self._pack_pairs_padded(pairs, span=span)
+        doc_slots = list(zip(p.row_of.tolist(), p.seg_of.tolist()))
+        return (
+            p.ids[: p.rows], p.segments[: p.rows], p.positions[: p.rows],
+            doc_slots, p.n_seg,
+        )
 
     def _packed_fn(self, R: int, L: int, S: int):
         key = ("packed", R, L, S)
@@ -291,33 +319,26 @@ class CrossEncoderModel:
         host prep — concurrent rerank callers overlap it); the lock
         covers only the compiled-fn cache, and the dispatch launches OFF
         it too (lock-discipline)."""
-        from .encoder import _bucket
-        from .packing import pad_packed_rows, seg_bucket
-
         n = len(pairs)
-        ids, segments, positions, doc_slots, n_seg = self._pack_pairs(pairs)
-        rows_real = ids.shape[0]
-        Rb = _bucket(rows_real)
-        ids, segments, positions = pad_packed_rows(
-            ids, segments, positions, Rb
-        )
-        Sb = seg_bucket(n_seg)
+        packed = self._pack_pairs_padded(pairs)
+        Rb, L = packed.ids.shape
+        Sb = packed.seg_width
         with self._lock:
-            fn = self._packed_fn(Rb, ids.shape[1], Sb)
+            fn = self._packed_fn(Rb, L, Sb)
         out = retry_call(
             "cross_encoder.dispatch",
             fn,
             self.params,
-            jnp.asarray(ids),
-            jnp.asarray(segments),
-            jnp.asarray(positions),
+            jnp.asarray(packed.ids),
+            jnp.asarray(packed.segments),
+            jnp.asarray(packed.positions),
             deadline=deadline,
         )
         if hasattr(out, "copy_to_host_async"):
             out.copy_to_host_async()
         t_dispatch = time.perf_counter_ns()
-        observe.record_occupancy("cross_encoder_packed", rows_real, Rb)
-        flat_ix = np.asarray([r * Sb + s for r, s in doc_slots], np.int64)
+        observe.record_occupancy("cross_encoder_packed", packed.rows, Rb)
+        flat_ix = packed.row_of * Sb + packed.seg_of
 
         def complete() -> np.ndarray:
             inject.fire("cross_encoder.fetch", deadline=deadline)

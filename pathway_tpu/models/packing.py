@@ -11,11 +11,19 @@ packs through the exact same layout code.
 from __future__ import annotations
 
 import bisect
-from typing import List, Tuple
+import functools
+from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["pack_rows", "pad_packed_rows", "row_length_bucket", "seg_bucket"]
+__all__ = [
+    "PackedRows",
+    "pack_padded",
+    "pack_rows",
+    "pad_packed_rows",
+    "row_length_bucket",
+    "seg_bucket",
+]
 
 _ROW_LEN_BUCKETS = (32, 64, 128, 256, 512)
 
@@ -129,4 +137,89 @@ def pack_rows(
         positions.reshape(R, L),
         doc_slots,
         n_seg,
+    )
+
+
+class PackedRows(NamedTuple):
+    """A packed batch at its compile shape (``pack_padded``)."""
+
+    ids: np.ndarray  # [Rb, L] int32, pad rows zero
+    segments: np.ndarray  # [Rb, L], 1-based per row, 0 = masked
+    positions: np.ndarray  # [Rb, L], restarting per sequence
+    pair_slot: Optional[np.ndarray]  # [Rb * Sb] scatter targets, if asked for
+    row_of: np.ndarray  # int64 [n]: the row of input sequence i
+    seg_of: np.ndarray  # int64 [n]: its 0-based segment in that row
+    rows: int  # R, the rows that carry tokens
+    n_seg: int  # the fullest row's sequence count
+    seg_width: int  # Sb = seg_bucket(n_seg)
+    native: bool  # laid out by the native call, not the Python body
+
+
+@functools.lru_cache(maxsize=256)
+def _bucket_tables(
+    n: int, max_docs_per_row: int, row_bucket: Callable[[int], int]
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The bucket rules as the tables the native call reads: every padded
+    row count ``row_bucket`` can answer for 1..n rows, ascending, and
+    ``seg_bucket`` of 1..max_docs_per_row.  (A bucket function is a
+    non-decreasing step function with ``row_bucket(b) == b`` at each step's
+    top, so stepping from top + 1 visits every step.)"""
+    rows = [row_bucket(1)]
+    while rows[-1] < n:
+        rows.append(row_bucket(rows[-1] + 1))
+    tables = (
+        np.asarray(rows, np.int64),
+        np.asarray(
+            [seg_bucket(c) for c in range(1, max_docs_per_row + 1)], np.int64
+        ),
+    )
+    for t in tables:  # one copy serves every caller
+        t.flags.writeable = False
+    return tables
+
+
+def pack_padded(
+    ids_b: np.ndarray,
+    lens: np.ndarray,
+    L: int,
+    row_bucket: Callable[[int], int],
+    max_docs_per_row: int = 8,
+    slot_ids: Optional[Sequence[int]] = None,
+    drop_slot: int = 0,
+) -> PackedRows:
+    """``pack_rows``, then the padding to the compile shape every packed
+    consumer does next: rows up to ``Rb = row_bucket(R)`` (zero rows = fully
+    masked), segment width ``Sb = seg_bucket(n_seg)``.  With ``slot_ids``
+    (one per sequence) also the flat ``[Rb * Sb]`` table whose entry ``row *
+    Sb + seg`` is that sequence's slot id and every other entry ``drop_slot``
+    (the rerank pipeline scatters its score table through it).
+
+    ONE native call (``pn_pack_rows``) whenever the library has the entry
+    point; else ``pack_rows`` + ``pad_packed_rows`` + a loop, equal element
+    for element (tests/test_pack_pairs_native.py)."""
+    from .. import native as _native
+
+    n = len(lens)
+    out = _native.pack_rows(
+        ids_b, lens, L, max_docs_per_row,
+        *_bucket_tables(n, max_docs_per_row, row_bucket),
+        slot_ids=slot_ids, drop_slot=drop_slot,
+    )
+    if out is not None:
+        return PackedRows(*out, native=True)
+    ids, _mask, segments, positions, doc_slots, n_seg = pack_rows(
+        ids_b, lens, L, max_docs_per_row
+    )
+    R = ids.shape[0]
+    Rb, Sb = row_bucket(R), seg_bucket(n_seg)
+    ids, segments, positions = pad_packed_rows(ids, segments, positions, Rb)
+    slots = np.asarray(doc_slots, np.int64).reshape(n, 2)
+    pair_slot = None
+    if slot_ids is not None:
+        pair_slot = np.full(Rb * Sb, drop_slot, np.int32)
+        for i, (r, s) in enumerate(doc_slots):
+            pair_slot[r * Sb + s] = slot_ids[i]
+    return PackedRows(
+        ids, segments, positions, pair_slot, slots[:, 0], slots[:, 1],
+        R, n_seg, Sb, native=False,
     )

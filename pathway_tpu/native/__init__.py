@@ -37,6 +37,7 @@ __all__ = [
     "shard_rows",
     "tokenize_hash",
     "encode_pairs",
+    "pack_rows",
 ]
 
 _REPO_ROOT = Path(__file__).resolve().parents[2]
@@ -45,7 +46,16 @@ _SO_PATH = _NATIVE_DIR / "build" / "libpathway_native.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+# the same library through a handle whose calls KEEP the GIL (``_lib_for``)
+_lib_held: Optional[ctypes.PyDLL] = None
 _tried = False
+
+# A call through ``ctypes.CDLL`` drops the GIL and has to win it back when
+# it returns; under the serve path's 32 callers that wait outlasts a short
+# call itself (PERF.md section 6, ISSUE 29).  Calls that scan at most this
+# many bytes keep it; a connector's CSV scan or a bulk tokenise of
+# megabytes goes on releasing it.
+_HOLD_GIL_BYTES = 1 << 20
 
 _i64 = ctypes.c_int64
 _u64 = ctypes.c_uint64
@@ -53,6 +63,7 @@ _u32 = ctypes.c_uint32
 _i32 = ctypes.c_int32
 _u8 = ctypes.c_uint8
 _p_u8 = ctypes.POINTER(_u8)
+_p_i32 = ctypes.POINTER(_i32)
 _p_i64 = ctypes.POINTER(_i64)
 _p_u64 = ctypes.POINTER(_u64)
 
@@ -90,8 +101,8 @@ def build(force: bool = False) -> bool:
             (_NATIVE_DIR / "build").mkdir(exist_ok=True)
             srcs = sorted(str(p) for p in (_NATIVE_DIR / "src").glob("*.cc"))
             subprocess.run(
-                ["g++", "-O3", "-fPIC", "-std=c++17", "-shared", *srcs,
-                 "-o", str(_SO_PATH)],
+                ["g++", "-O3", "-fPIC", "-std=c++17", "-Iinclude", "-shared",
+                 *srcs, "-o", str(_SO_PATH)],
                 cwd=_NATIVE_DIR,
                 check=True,
                 capture_output=True,
@@ -151,13 +162,22 @@ def _declare(dll: ctypes.CDLL) -> ctypes.CDLL:
         ]
     except AttributeError:
         pass  # stale .so without the pair entry point
+    try:
+        dll.pn_pack_rows.restype = _i32
+        dll.pn_pack_rows.argtypes = [
+            _p_i32, _i64, _i64, _p_i64, _i64, _i64, _p_i64, _i64, _p_i64,
+            _p_i32, _i32, _p_i32, _p_i32, _p_i32, _p_i32, _p_i64, _p_i64,
+            _p_i64,
+        ]
+    except AttributeError:
+        pass  # stale .so without the packing entry point
     return dll
 
 
 def lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, building it on first use; None if disabled
     or unbuildable."""
-    global _lib, _tried
+    global _lib, _lib_held, _tried
     if _lib is not None:
         return _lib
     with _lock:
@@ -169,10 +189,19 @@ def lib() -> Optional[ctypes.CDLL]:
         if not build():
             return None
         try:
+            # the twin first: whoever sees ``_lib`` set finds it there
+            _lib_held = _declare(ctypes.PyDLL(str(_SO_PATH)))
             _lib = _declare(ctypes.CDLL(str(_SO_PATH)))
         except OSError:
-            _lib = None
+            _lib = _lib_held = None
         return _lib
+
+
+def _lib_for(nbytes: int) -> Optional[ctypes.CDLL]:
+    """``lib()`` for a call that scans ``nbytes``: the GIL-keeping handle
+    for a short one (``_HOLD_GIL_BYTES``)."""
+    dll = lib()
+    return _lib_held if dll is not None and nbytes <= _HOLD_GIL_BYTES else dll
 
 
 def available() -> bool:
@@ -493,7 +522,7 @@ def encode_pairs(
     [n, width] int32 zero-padded, mask [n, width], lens int64[n]) with
     ``width >= budget + 3``, or None when the native path is unavailable
     (caller keeps the Python tokenizer)."""
-    dll = lib()
+    dll = _lib_for(len(blob))
     if dll is None or not hasattr(dll, "pn_encode_pairs"):
         return None
     n_texts, n = len(offsets) - 1, len(a_slot)
@@ -522,3 +551,78 @@ def encode_pairs(
     if rc != 0:
         return None
     return ids, mask, lens
+
+
+# ---------------------------------------------------------------- packing
+
+
+def pack_rows(
+    ids_b: np.ndarray,
+    lens: np.ndarray,
+    L: int,
+    max_docs_per_row: int,
+    row_buckets: np.ndarray,
+    seg_buckets: np.ndarray,
+    slot_ids: Optional[np.ndarray] = None,
+    drop_slot: int = 0,
+) -> Optional[tuple]:
+    """``models.packing.pack_rows`` + ``pad_packed_rows`` (+ the rerank
+    pipeline's ``pair_slot`` scatter when ``slot_ids`` is given) in ONE
+    native call, same layout to the element.  ``row_buckets`` (ascending
+    padded row counts, last >= n) and ``seg_buckets`` (segment width by the
+    fullest row's sequence count, 1..max_docs_per_row) carry the callers'
+    bucket rules.  Returns (ids [Rb, L], segments, positions, pair_slot
+    [Rb * Sb] or None, row_of int64[n], seg_of int64[n], R, n_seg, Sb), or
+    None when the native path is unavailable or refuses the input (caller
+    keeps the Python body)."""
+    ids_b = np.ascontiguousarray(ids_b, dtype=np.int32)
+    dll = _lib_for(ids_b.nbytes)
+    if dll is None or not hasattr(dll, "pn_pack_rows"):
+        return None
+    n = len(lens)
+    row_buckets = np.ascontiguousarray(row_buckets, dtype=np.int64)
+    seg_buckets = np.ascontiguousarray(seg_buckets, dtype=np.int64)
+    if (
+        n == 0
+        or ids_b.ndim != 2
+        or len(ids_b) != n
+        or len(row_buckets) == 0
+        or row_buckets[-1] < n
+        or len(seg_buckets) != max_docs_per_row
+    ):
+        return None
+    lens = np.ascontiguousarray(lens, dtype=np.int64)
+    rows_cap = int(row_buckets[-1])
+    # sized for the worst case (a row per sequence), sliced to [Rb, L]
+    # below: a leading slice of a C array is itself contiguous
+    ids = np.empty((rows_cap, L), dtype=np.int32)
+    segments = np.empty((rows_cap, L), dtype=np.int32)
+    positions = np.empty((rows_cap, L), dtype=np.int32)
+    row_of = np.empty(n, dtype=np.int64)
+    seg_of = np.empty(n, dtype=np.int64)
+    dims = np.empty(4, dtype=np.int64)
+    pair_slot = None
+    slot_ptr = slot_out = None
+    if slot_ids is not None:
+        slot_ids = np.ascontiguousarray(slot_ids, dtype=np.int32)
+        if len(slot_ids) != n:
+            raise ValueError("one slot id per sequence")
+        pair_slot = np.empty(rows_cap * int(seg_buckets[-1]), dtype=np.int32)
+        slot_ptr, slot_out = _np_ptr(slot_ids, _i32), _np_ptr(pair_slot, _i32)
+    rc = dll.pn_pack_rows(
+        _np_ptr(ids_b, _i32), n, ids_b.shape[1], _np_ptr(lens, _i64), L,
+        max_docs_per_row, _np_ptr(row_buckets, _i64), len(row_buckets),
+        _np_ptr(seg_buckets, _i64), slot_ptr, drop_slot,
+        _np_ptr(ids, _i32), _np_ptr(segments, _i32), _np_ptr(positions, _i32),
+        slot_out, _np_ptr(row_of, _i64), _np_ptr(seg_of, _i64),
+        _np_ptr(dims, _i64),
+    )
+    if rc != 0:
+        return None
+    R, n_seg, Rb, Sb = dims.tolist()
+    if pair_slot is not None:
+        pair_slot = pair_slot[: Rb * Sb]
+    return (
+        ids[:Rb], segments[:Rb], positions[:Rb], pair_slot,
+        row_of, seg_of, R, n_seg, Sb,
+    )

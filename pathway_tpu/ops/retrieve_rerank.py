@@ -809,26 +809,19 @@ class RetrieveRerankPipeline:
                 deadline=deadline, stage1_flags=stage1_flags, meta=meta,
                 pool=Kc,
             )
-        from ..models.packing import pad_packed_rows, seg_bucket
-
         Qb = _bucket(nq)
         # pack OFF every lock: tokenization + row packing are pure host
         # work on stateless helpers, and under the coalescing scheduler
         # batch N+1's pack must overlap batch N's device time
         with observe.span("stage2.pack", after=gather, **_S2_PACKROWS) as pack:
-            ids, segments, positions, doc_slots, n_seg = ce._pack_pairs(
-                pairs, span=pack
+            # pair_slot: slot_ids where a pair sits, Qb * Kc (out of range
+            # -> dropped by the scatter) on pad segments
+            packed = ce._pack_pairs_padded(
+                pairs, slot_ids=slot_ids, drop_slot=Qb * Kc, span=pack
             )
-            rows_real = ids.shape[0]
-            Rb = _bucket(rows_real)
-            L = ids.shape[1]
-            ids, segments, positions = pad_packed_rows(
-                ids, segments, positions, Rb
-            )
-            Sb = seg_bucket(n_seg)
-            pair_slot = np.full(Rb * Sb, Qb * Kc, np.int32)  # default: dropped
-            for i, (r, s) in enumerate(doc_slots):
-                pair_slot[r * Sb + s] = slot_ids[i]
+            rows_real = packed.rows
+            Rb, L = packed.ids.shape
+            Sb = packed.seg_width
             pack.set(rows=Rb)
         with observe.span(
             "stage2.dispatch", after=pack, **_S2_DISPATCH
@@ -841,10 +834,10 @@ class RetrieveRerankPipeline:
                 "rerank.dispatch",
                 fn,
                 ce.params,
-                jnp.asarray(ids),
-                jnp.asarray(segments),
-                jnp.asarray(positions),
-                jnp.asarray(pair_slot),
+                jnp.asarray(packed.ids),
+                jnp.asarray(packed.segments),
+                jnp.asarray(packed.positions),
+                jnp.asarray(packed.pair_slot),
                 deadline=deadline,
                 policy=_STAGE2_RETRY,
                 breaker=self._breaker,
