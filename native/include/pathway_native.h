@@ -13,9 +13,6 @@
 extern "C" {
 #endif
 
-/* ---- version ---- */
-int64_t pn_abi_version(void);
-
 /* ---- CSV scanning (RFC-4180: quoted fields, "" escapes, \r\n) ----
  *
  * Two-pass API over an in-memory buffer:

@@ -5,7 +5,7 @@
 //
 // The algorithm must be bit-identical to python-xxhash's xxh3_64_intdigest,
 // so we use the canonical header-only xxHash implementation when one is
-// discoverable at build time (pyarrow vendors it; the Makefile passes its
+// discoverable at build time (pyarrow vendors it; native.build() passes its
 // include dir).  Without the header, pn_hash_rows reports "unavailable" and
 // the Python side keeps its per-row loop — behavior identical, just slower.
 #include "../include/pathway_native.h"

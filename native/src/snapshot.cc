@@ -55,6 +55,4 @@ int64_t pn_frame_scan(const uint8_t* buf, int64_t len, int64_t* offsets,
   return count;
 }
 
-int64_t pn_abi_version(void) { return 1; }
-
 }  // extern "C"

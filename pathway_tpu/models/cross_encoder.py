@@ -36,7 +36,7 @@ _H_READY = observe.histogram("pathway_serve_model_seconds", model="cross_encoder
 # stage2_packrows (which keeps measuring tokenise + pack + pad)
 _S2_TOKENIZE = observe.serve_stage("stage2_pair_tokenize", cpu=False)
 # pairs laid out into packed rows, by the path that took them (the native
-# call, or models/packing.py's Python body without the entry point)
+# call, or models/packing.py's Python body without the library)
 _PACKED_NATIVE = observe.counter("pathway_serve_pack_pairs_total", path="native")
 _PACKED_PYTHON = observe.counter("pathway_serve_pack_pairs_total", path="python")
 

@@ -194,9 +194,9 @@ def pack_padded(
     Sb + seg`` is that sequence's slot id and every other entry ``drop_slot``
     (the rerank pipeline scatters its score table through it).
 
-    ONE native call (``pn_pack_rows``) whenever the library has the entry
-    point; else ``pack_rows`` + ``pad_packed_rows`` + a loop, equal element
-    for element (tests/test_pack_pairs_native.py)."""
+    ONE native call (``pn_pack_rows``) whenever the library is loaded; else
+    ``pack_rows`` + ``pad_packed_rows`` + a loop, equal element for element
+    (tests/test_pack_pairs_native.py)."""
     from .. import native as _native
 
     n = len(lens)

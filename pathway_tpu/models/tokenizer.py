@@ -100,7 +100,7 @@ class HashTokenizer:
     ) -> Tuple[np.ndarray, np.ndarray, bool]:
         """``encode_batch(texts, pairs=pairs)`` plus the path that made it:
         (ids, mask, native).  The native path is taken whenever the input
-        allows it (ASCII batch, library with the tokenizer entry point) and
+        allows it (ASCII batch, the native library loaded) and
         is bit-identical to the per-pair loop; the pairs are counted under
         the path they took."""
         max_length = max_length or self.max_length
@@ -197,7 +197,7 @@ class HashTokenizer:
         a batch under the rerank cell's 32 callers (0.5 ms alone: some thirty
         array calls, each a chance to hand the GIL over).  Same ids, mask and
         width as ``encode`` + ``_pad``; None for non-ASCII batches or without
-        the native entry point."""
+        the native library."""
         n = len(texts)
         if n == 0:
             return None

@@ -3,15 +3,22 @@
 The reference implements its host-side hot loops — connector scanners/parsers,
 value serialization for key hashing, snapshot framing, shard routing — in Rust
 (src/connectors/, src/engine/value.rs, src/persistence/); here they live in
-C++ built to ``libpathway_native.so`` and loaded through ctypes.  Everything
-degrades gracefully: if the library is missing and cannot be built (or
-``PATHWAY_TPU_DISABLE_NATIVE=1``), pure-Python fallbacks with identical
-semantics take over — tests assert native/fallback agreement bit-for-bit.
+C++ and are loaded through ctypes.  ``build()`` holds the one compile command;
+the library is ``native/build/libpathway_native-<hex>.so``, ``<hex>`` the hash
+of that command and of every source and header, so a file of that name IS the
+library of these sources: it is loaded if it exists and built (to a temporary
+name, then renamed into place) if it does not.  If it cannot be built (one
+warning, with the compiler's words) or ``PATHWAY_TPU_DISABLE_NATIVE=1``,
+pure-Python fallbacks with identical semantics take over — tests assert
+native/fallback agreement bit-for-bit.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import importlib.util
+import logging
 import os
 import subprocess
 import threading
@@ -40,9 +47,9 @@ __all__ = [
     "pack_rows",
 ]
 
-_REPO_ROOT = Path(__file__).resolve().parents[2]
-_NATIVE_DIR = _REPO_ROOT / "native"
-_SO_PATH = _NATIVE_DIR / "build" / "libpathway_native.so"
+_log = logging.getLogger(__name__)
+
+_NATIVE_DIR = Path(__file__).resolve().parents[2] / "native"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -68,53 +75,65 @@ _p_i64 = ctypes.POINTER(_i64)
 _p_u64 = ctypes.POINTER(_u64)
 
 
-def _sources_newer_than_so() -> bool:
-    if not _SO_PATH.exists():
-        return True
-    so_mtime = _SO_PATH.stat().st_mtime
-    for src in list((_NATIVE_DIR / "src").glob("*.cc")) + list(
-        (_NATIVE_DIR / "include").glob("*.h")
-    ):
-        if src.stat().st_mtime > so_mtime:
-            return True
-    return False
+def _recipe() -> List[str]:
+    """The compile command, run in ``native/``, without its ``-o``.  xxh3
+    row hashing and the tokenizer need the header-only xxHash, which pyarrow
+    vendors in every environment we target; without it ``hash.cc`` compiles
+    to a stub and Python hashes rows itself (slower, same results)."""
+    argv = [os.environ.get("CXX") or "g++", "-O3", "-fPIC", "-std=c++17",
+            "-Wall", "-Wextra", "-Iinclude"]
+    pyarrow = importlib.util.find_spec("pyarrow")
+    if pyarrow is not None and pyarrow.submodule_search_locations:
+        xxhash = Path(pyarrow.submodule_search_locations[0],
+                      "include", "arrow", "vendored", "xxhash")
+        if (xxhash / "xxhash.h").exists():
+            argv.append(f"-I{xxhash}")
+    srcs = sorted(p.name for p in (_NATIVE_DIR / "src").glob("*.cc"))
+    return [*argv, "-shared", *(f"src/{name}" for name in srcs)]
 
 
-def build(force: bool = False) -> bool:
-    """Build libpathway_native.so (make, falling back to a direct g++ call).
-    Returns True if the library exists afterwards.  ``force`` rebuilds
-    whatever the mtimes say (a copied tree does not keep them)."""
-    if not _NATIVE_DIR.exists():
-        return False
-    if not force and not _sources_newer_than_so():
-        return True
+def _library_path(recipe: Sequence[str]) -> Path:
+    """Where the library of ``recipe`` and of the sources as they stand
+    lives: the name is the hash of everything the object code depends on."""
+    h = hashlib.sha256("\0".join(recipe).encode())
+    for p in sorted([*(_NATIVE_DIR / "src").glob("*.cc"),
+                     *(_NATIVE_DIR / "include").glob("*.h")]):
+        h.update(b"\0" + p.name.encode() + b"\0" + p.read_bytes())
+    return _NATIVE_DIR / "build" / f"libpathway_native-{h.hexdigest()[:16]}.so"
+
+
+def build() -> Optional[Path]:
+    """The library of this checkout's sources, compiled first if no file of
+    its name exists; None (after one warning) if it cannot be compiled.
+    The compiler writes a name of this process's own, which is then renamed
+    onto the final one: concurrent first users each publish the same bytes
+    whole, and a file that a process may have mapped is never written."""
+    if not (_NATIVE_DIR / "src").is_dir():
+        return None
+    recipe = _recipe()
+    so = _library_path(recipe)
+    if so.exists():
+        return so
+    tmp = so.with_name(f"{so.name}.{os.getpid()}-{threading.get_ident()}.tmp")
     try:
+        so.parent.mkdir(exist_ok=True)
         subprocess.run(
-            ["make", "-s", "-B"] if force else ["make", "-s"],
-            cwd=_NATIVE_DIR,
-            check=True,
-            capture_output=True,
-            timeout=120,
+            [*recipe, "-o", str(tmp)], cwd=_NATIVE_DIR, check=True,
+            capture_output=True, text=True, timeout=120,
         )
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
-        try:
-            (_NATIVE_DIR / "build").mkdir(exist_ok=True)
-            srcs = sorted(str(p) for p in (_NATIVE_DIR / "src").glob("*.cc"))
-            subprocess.run(
-                ["g++", "-O3", "-fPIC", "-std=c++17", "-Iinclude", "-shared",
-                 *srcs, "-o", str(_SO_PATH)],
-                cwd=_NATIVE_DIR,
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        except (subprocess.SubprocessError, FileNotFoundError, OSError):
-            return False
-    return _SO_PATH.exists()
+        os.replace(tmp, so)
+    except (subprocess.SubprocessError, OSError) as e:
+        tmp.unlink(missing_ok=True)
+        said = (getattr(e, "stderr", None) or "").strip()[-2000:]
+        _log.warning(
+            "native library not built (%s); Python fallbacks serve%s",
+            e, f":\n{said}" if said else "",
+        )
+        return None
+    return so
 
 
 def _declare(dll: ctypes.CDLL) -> ctypes.CDLL:
-    dll.pn_abi_version.restype = _i64
     dll.pn_csv_count.restype = _i32
     dll.pn_csv_count.argtypes = [_p_u8, _i64, _u8, _u8, _p_i64, _p_i64]
     dll.pn_csv_scan.restype = _i32
@@ -135,42 +154,30 @@ def _declare(dll: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_void_p),
         _p_u8, _i64, _p_i64,
     ]
-    try:
-        dll.pn_hash_rows.restype = _i32
-        dll.pn_hash_rows.argtypes = [_p_u8, _i64, _p_i64, _i64, _p_u64]
-    except AttributeError:
-        pass  # stale .so without the hashing entry point
+    dll.pn_hash_rows.restype = _i32
+    dll.pn_hash_rows.argtypes = [_p_u8, _i64, _p_i64, _i64, _p_u64]
     dll.pn_crc32.restype = _u32
     dll.pn_crc32.argtypes = [_p_u8, _i64, _u32]
     dll.pn_frame_scan.restype = _i64
     dll.pn_frame_scan.argtypes = [_p_u8, _i64, _p_i64, _p_i64, _i64, _p_i64]
     dll.pn_shard_rows.restype = None
     dll.pn_shard_rows.argtypes = [_p_u64, _i64, _u32, _u64, _p_i64, _p_i64]
-    try:
-        dll.pn_tokenize_hash.restype = _i32
-        dll.pn_tokenize_hash.argtypes = [
-            _p_u8, _p_i64, _i64, _i32, _i32, ctypes.POINTER(_i32), _p_i64,
-        ]
-    except AttributeError:
-        pass  # stale .so without the tokenizer entry point
-    try:
-        dll.pn_encode_pairs.restype = _i32
-        dll.pn_encode_pairs.argtypes = [
-            _p_u8, _p_i64, _i64, _i32, _i32, _p_i64, _p_i64, _i64, _i64,
-            _i32, _i32, _i64, ctypes.POINTER(_i32), _p_i64,
-            ctypes.POINTER(_i32), ctypes.POINTER(_i32), _p_i64,
-        ]
-    except AttributeError:
-        pass  # stale .so without the pair entry point
-    try:
-        dll.pn_pack_rows.restype = _i32
-        dll.pn_pack_rows.argtypes = [
-            _p_i32, _i64, _i64, _p_i64, _i64, _i64, _p_i64, _i64, _p_i64,
-            _p_i32, _i32, _p_i32, _p_i32, _p_i32, _p_i32, _p_i64, _p_i64,
-            _p_i64,
-        ]
-    except AttributeError:
-        pass  # stale .so without the packing entry point
+    dll.pn_tokenize_hash.restype = _i32
+    dll.pn_tokenize_hash.argtypes = [
+        _p_u8, _p_i64, _i64, _i32, _i32, ctypes.POINTER(_i32), _p_i64,
+    ]
+    dll.pn_encode_pairs.restype = _i32
+    dll.pn_encode_pairs.argtypes = [
+        _p_u8, _p_i64, _i64, _i32, _i32, _p_i64, _p_i64, _i64, _i64,
+        _i32, _i32, _i64, ctypes.POINTER(_i32), _p_i64,
+        ctypes.POINTER(_i32), ctypes.POINTER(_i32), _p_i64,
+    ]
+    dll.pn_pack_rows.restype = _i32
+    dll.pn_pack_rows.argtypes = [
+        _p_i32, _i64, _i64, _p_i64, _i64, _i64, _p_i64, _i64, _p_i64,
+        _p_i32, _i32, _p_i32, _p_i32, _p_i32, _p_i32, _p_i64, _p_i64,
+        _p_i64,
+    ]
     return dll
 
 
@@ -186,14 +193,16 @@ def lib() -> Optional[ctypes.CDLL]:
         _tried = True
         if config.get("native.disable"):
             return None
-        if not build():
+        so = build()
+        if so is None:
             return None
         try:
             # the twin first: whoever sees ``_lib`` set finds it there
-            _lib_held = _declare(ctypes.PyDLL(str(_SO_PATH)))
-            _lib = _declare(ctypes.CDLL(str(_SO_PATH)))
-        except OSError:
+            _lib_held = _declare(ctypes.PyDLL(str(so)))
+            _lib = _declare(ctypes.CDLL(str(so)))
+        except OSError as e:
             _lib = _lib_held = None
+            _log.warning("native library %s does not load (%s); Python fallbacks serve", so, e)
         return _lib
 
 
@@ -410,7 +419,7 @@ def hash_rows(buf: bytes, row_offsets: np.ndarray) -> Optional[np.ndarray]:
     None when the library is absent or was built without xxhash — callers
     hash row-by-row in Python instead (internals/keys.ref_scalars_batch)."""
     dll = lib()
-    if dll is None or not hasattr(dll, "pn_hash_rows"):
+    if dll is None:
         return None
     n = len(row_offsets) - 1
     offs = np.ascontiguousarray(row_offsets, dtype=np.int64)
@@ -488,7 +497,7 @@ def tokenize_hash(
     int64[n+1]), or None when the native path is unavailable (caller keeps
     the Python tokenizer)."""
     dll = lib()
-    if dll is None or not hasattr(dll, "pn_tokenize_hash"):
+    if dll is None:
         return None
     n_texts = len(offsets) - 1
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
@@ -523,7 +532,7 @@ def encode_pairs(
     ``width >= budget + 3``, or None when the native path is unavailable
     (caller keeps the Python tokenizer)."""
     dll = _lib_for(len(blob))
-    if dll is None or not hasattr(dll, "pn_encode_pairs"):
+    if dll is None:
         return None
     n_texts, n = len(offsets) - 1, len(a_slot)
     if width < budget + 3 or budget < 2:
@@ -577,7 +586,7 @@ def pack_rows(
     keeps the Python body)."""
     ids_b = np.ascontiguousarray(ids_b, dtype=np.int32)
     dll = _lib_for(ids_b.nbytes)
-    if dll is None or not hasattr(dll, "pn_pack_rows"):
+    if dll is None:
         return None
     n = len(lens)
     row_buckets = np.ascontiguousarray(row_buckets, dtype=np.int64)

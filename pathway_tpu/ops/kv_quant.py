@@ -22,8 +22,9 @@ Two properties drive the design:
 - **every read goes through the same dequant** — prefill and decode
   both attend ``dequantize(int8)`` (models/transformer.py quant twins),
   so warm and cold joins see identical attention inputs and int8
-  decodes are deterministic; the bf16-vs-int8 token drift is bounded by
-  tests/test_decode.py against a pinned golden.
+  decodes are deterministic; the drift from a float cache is bounded
+  in tests/test_decode.py on the logits the tokens were chosen from
+  (``INT8_LOGIT_LIMIT``), with two planted read faults above the limit.
 """
 
 from __future__ import annotations

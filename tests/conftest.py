@@ -29,3 +29,26 @@ def fresh_graph():
     pw.reset()
     yield
     pw.reset()
+
+
+@pytest.fixture(scope="session")
+def needs_native():
+    """For a test of the native library itself.  It skips only where
+    ``native.disable`` is set; anywhere else a library that did not build
+    fails the test, with what the compiler said."""
+    import logging
+
+    from pathway_tpu import config, native
+
+    if config.get("native.disable"):
+        pytest.skip("native.disable is set")
+    if not native.available():
+        said = []
+        handler = logging.Handler()
+        handler.emit = lambda record: said.append(record.getMessage())
+        logging.getLogger(native.__name__).addHandler(handler)
+        try:
+            native.build()
+        finally:
+            logging.getLogger(native.__name__).removeHandler(handler)
+        pytest.fail("the native library is missing:\n" + "\n".join(said))

@@ -788,35 +788,90 @@ def test_int8_warm_prefix_join_identical_to_cold():
         eng.stop()
 
 
-def test_int8_pinned_golden():
-    """The int8 drift contract: the exact CPU token output for a fixed
-    config/prompt/seed is PINNED (tests/goldens/int8_decode.json) — a
-    quantization change that moves tokens must re-pin the golden
-    deliberately, with the drift reviewed."""
-    import json
-    import os
+# The int8 drift contract is a number with a limit: the widest gap between
+# what the int8 pool's engine read off the float32 logits a token was chosen
+# from (the log-sum-exp, each top id's logit) and what the same engine reads
+# with a float cache, same prompts, same weights.  Logits, never tokens: with
+# random weights the largest logit changes on rounding.  Sound: 0.0069 here,
+# 0.0053-0.0141 over eight weight seeds; the planted faults below 0.041 and
+# 0.142 here, 0.032-0.050 and 0.083-0.166 over those seeds (logits about 0.4
+# wide; CPU, jaxlib 0.9.0; a float32 model read the same to the third digit).
+INT8_LOGIT_LIMIT = 0.02
 
-    path = os.path.join(
-        os.path.dirname(__file__), "goldens", "int8_decode.json"
-    )
-    with open(path) as fh:
-        golden = json.load(fh)
-    gen = make_generator()
+
+def _served(kv_quant):
     eng = ContinuousDecoder(
-        gen, slots=2, step_bucket=4, name="dec-i8-golden",
-        kv_quant="int8", spec_k=3,
+        make_generator(), slots=3, step_bucket=4,
+        name=f"dec-drift-{kv_quant}", kv_quant=kv_quant, spec_k=3,
     )
     try:
-        got = [
-            str(o) for o in eng.generate(
-                golden["prompts"],
-                max_new_tokens=golden["max_new_tokens"],
-                temperature=0.0, seed=golden["seed"],
+        return [
+            o.meta for o in eng.generate(
+                PROMPTS, max_new_tokens=8, temperature=0.0, seed=0
             )
         ]
     finally:
         eng.stop()
-    assert got == golden["outputs"]
+
+
+def _logit_gap(got, want):
+    """(widest gap, tokens compared) over the positions of each request at
+    which both engines had read the same context: up to and including the
+    first token they chose differently."""
+    widest, compared = 0.0, 0
+    for a, b in zip(got, want):
+        ta, tb = a["token_ids"], b["token_ids"]
+        same = next(
+            (j for j, (x, y) in enumerate(zip(ta, tb)) if x != y), len(ta)
+        )
+        la, lb = a["logprobs"], b["logprobs"]
+        for j in range(min(same + 1, len(ta), len(tb))):
+            top_a = dict(zip(la["top_ids"][j], la["top_logits"][j]))
+            top_b = dict(zip(lb["top_ids"][j], lb["top_logits"][j]))
+            assert top_a.keys() & top_b.keys()
+            widest = max(
+                widest, abs(la["lse"][j] - lb["lse"][j]),
+                *(abs(top_a[t] - top_b[t]) for t in top_a.keys() & top_b.keys()),
+            )
+            compared += 1
+    return widest, compared
+
+
+@pytest.fixture(scope="module")
+def float_cache_served():
+    return _served("bf16")
+
+
+def _channel_scales_dropped(real):
+    # every channel read at the mean scale of its pool
+    return lambda q, s, *dtype: real(q, s.mean() + 0 * s, *dtype)
+
+
+def _read_one_slot_off(real):
+    import jax.numpy as jnp
+
+    return lambda q, s, *dtype: real(jnp.roll(q, 1, axis=-3), s, *dtype)
+
+
+@pytest.mark.parametrize(
+    "planted", [None, _channel_scales_dropped, _read_one_slot_off],
+    ids=["sound", "channel_scales_dropped", "read_one_slot_off"],
+)
+def test_int8_logits_stay_within_their_limit_of_the_float_cache(
+    float_cache_served, monkeypatch, planted
+):
+    """The limit sits between the sound reading and a fault planted at the
+    pool's read (a fresh generator traces its programs anew, so the planted
+    read is what its int8 programs compile)."""
+    from pathway_tpu.ops import kv_quant
+
+    if planted is not None:
+        monkeypatch.setattr(kv_quant, "dequantize_kv", planted(kv_quant.dequantize_kv))
+    gap, compared = _logit_gap(_served("int8"), float_cache_served)
+    if planted is None:
+        assert compared >= 32 and gap <= INT8_LOGIT_LIMIT, (gap, compared)
+    else:
+        assert gap > INT8_LOGIT_LIMIT, (gap, compared)
 
 
 def test_suffix_corpus_drafts_repeat_requests_wholesale():

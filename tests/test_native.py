@@ -2,8 +2,14 @@
 identical bit-for-bit, and the integrated paths (keys, csv connector,
 persistence framing) must work with either."""
 
+import logging
+import os
 import pickle
+import re
+import shutil
 import struct
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -11,6 +17,8 @@ import pytest
 from pathway_tpu import native
 from pathway_tpu.native import fallback
 from pathway_tpu.internals import keys as K
+
+from .utils import REPO_ROOT
 
 
 CSV_CASES = [
@@ -29,12 +37,115 @@ CSV_CASES = [
 ]
 
 
-def test_native_library_builds():
-    import os
+def test_native_library_builds(needs_native):
+    assert native.available()
 
-    if os.environ.get("PATHWAY_TPU_DISABLE_NATIVE", "") not in ("", "0"):
-        pytest.skip("native explicitly disabled")
-    assert native.available(), "native library should build in this environment"
+
+# -- which file is the library of these sources (ISSUE 30) -------------------
+
+
+@pytest.fixture
+def own_native_dir(tmp_path, monkeypatch):
+    """The module pointed at a copy of ``native/`` with no build directory,
+    and made to forget the library it has loaded (both undone afterwards)."""
+    root = tmp_path / "native"
+    for sub in ("src", "include"):
+        shutil.copytree(native._NATIVE_DIR / sub, root / sub)
+    monkeypatch.setattr(native, "_NATIVE_DIR", root)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_held", None)
+    monkeypatch.setattr(native, "_tried", False)
+    return root
+
+
+def test_six_first_users_at_once_all_load_one_whole_library(needs_native, own_native_dir):
+    """Tier-1's six workers, a serve fleet's replicas: every process that
+    finds no library builds it and publishes it by rename, so each loads a
+    whole file and one file is left.  A barrier on stdin makes them start
+    together whatever their imports took."""
+    child = (
+        "import pathlib, sys, pathway_tpu.native as n\n"
+        "n._NATIVE_DIR = pathlib.Path(sys.argv[1])\n"
+        "print('ready', flush=True)\n"
+        "sys.stdin.readline()\n"
+        "print(n.available())\n"
+    )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", child, str(own_native_dir)], cwd=REPO_ROOT,
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+            text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        for _ in range(6)
+    ]
+    try:
+        for p in procs:
+            assert p.stdout.readline().strip() == "ready", p.stderr.read()
+        for p in procs:
+            p.stdin.write("\n")
+            p.stdin.flush()
+        said = [p.communicate(timeout=300) for p in procs]
+    finally:
+        for p in procs:
+            p.kill()
+    assert [out.strip() for out, _ in said] == ["True"] * 6, said
+    left = sorted(p.name for p in (own_native_dir / "build").iterdir())
+    assert left == [native._library_path(native._recipe()).name], left
+
+
+def test_the_name_is_a_function_of_recipe_and_sources(own_native_dir, monkeypatch):
+    def name():
+        return native._library_path(native._recipe()).name
+
+    first = name()
+    assert first == name() and first.endswith(".so")
+    seen = {first}
+    for rel in ("src/csv.cc", "include/pathway_native.h"):
+        path = own_native_dir / rel
+        was = path.read_bytes()
+        path.write_bytes(was + b"\n")
+        seen.add(name())
+        path.write_bytes(was)
+        assert name() == first
+    (own_native_dir / "src" / "extra.cc").write_text("int pn_extra;\n")
+    seen.add(name())
+    (own_native_dir / "src" / "extra.cc").unlink()
+    monkeypatch.setenv("CXX", "some-other-c++")
+    seen.add(name())
+    assert len(seen) == 5, seen
+
+
+@pytest.mark.parametrize("compiler", ["fails", "missing"])
+def test_a_failed_compile_leaves_no_file_and_says_so_once(
+    needs_native, own_native_dir, monkeypatch, caplog, compiler
+):
+    cxx = own_native_dir / "cxx"
+    if compiler == "fails":
+        # writes half an output, as a linker killed mid-way would
+        cxx.write_text(
+            '#!/bin/sh\nwhile [ "$1" != -o ]; do shift; done\n'
+            'echo half > "$2"\necho "boom: no such flag" >&2\nexit 1\n'
+        )
+        cxx.chmod(0o755)
+    monkeypatch.setenv("CXX", str(cxx))
+    with caplog.at_level(logging.WARNING, logger=native.__name__):
+        assert native.lib() is None and native.lib() is None
+        assert not native.available()
+    assert len(caplog.records) == 1, caplog.text
+    assert ("boom: no such flag" if compiler == "fails" else str(cxx)) in caplog.text
+    assert list((own_native_dir / "build").iterdir()) == []
+    # what a caller sees is the Python body's answer
+    assert native.crc32(b"hello") == 0x3610A686
+    assert native.tokenize_hash(b"a", np.array([0, 1]), 64, 8) is None
+
+
+def test_the_library_has_every_name_the_header_declares(needs_native):
+    header = (native._NATIVE_DIR / "include" / "pathway_native.h").read_text()
+    declared = sorted(set(re.findall(r"\b(pn_\w+)\(", header)))
+    assert len(declared) >= 13 and "pn_pack_rows" in declared
+    dll = native.lib()
+    missing = [name for name in declared if not hasattr(dll, name)]
+    assert not missing, missing
 
 
 @pytest.mark.parametrize("data", CSV_CASES)

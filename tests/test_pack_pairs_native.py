@@ -9,8 +9,6 @@ before, stay as the fallback and are the oracle here: every array and every
 
 from __future__ import annotations
 
-import types
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -28,16 +26,7 @@ from pathway_tpu.ops.retrieve_rerank import RetrieveRerankPipeline
 from pathway_tpu.ops.serving import FusedEncodeSearch
 
 
-@pytest.fixture(scope="module")
-def pack_entry_point():
-    """Skips where the native library has no packing entry point (asked
-    inside a test: the first call may build the library)."""
-    dll = native.lib()
-    if dll is None or not hasattr(dll, "pn_pack_rows"):
-        pytest.skip("native library has no packing entry point")
-
-
-needs_native = pytest.mark.usefixtures("pack_entry_point")
+needs_native = pytest.mark.usefixtures("needs_native")  # tests/conftest.py
 
 
 def _oracle(ids_b, lens, L, max_docs, slot_ids, drop_slot, row_bucket=_bucket):
@@ -200,42 +189,13 @@ def _python_expected(ids_b, lens, slot_ids, drop):
     assert (got.rows, got.n_seg, got.seg_width) == want[5:]
 
 
-@pytest.mark.parametrize("library", ["missing", "stale"])
-def test_without_the_entry_point_the_python_body_answers(monkeypatch, library):
-    """No library at all, or one built before this entry point existed (the
-    guard ``pn_encode_pairs`` uses): the Python body, same arrays."""
-    dll = None if library == "missing" else types.SimpleNamespace(
-        pn_abi_version=lambda: 1
-    )
-    monkeypatch.setattr(native, "lib", lambda: dll)
-    monkeypatch.setattr(native, "_lib_held", dll)  # its GIL-keeping twin
+def test_without_the_library_the_python_body_answers(monkeypatch):
+    """No library (it is disabled, or did not compile): the Python body,
+    same arrays."""
+    monkeypatch.setattr(native, "lib", lambda: None)
     rng = np.random.default_rng(5)
     lens = rng.integers(3, 64, size=50).astype(np.int64)
     _python_expected(_tokens(lens, 64), lens, list(range(50)), 50)
-
-
-def test_the_gxx_fallback_builds_every_source(monkeypatch, tmp_path):
-    """``native.build()`` without ``make``: the direct ``g++`` call (it gets
-    ``-Iinclude``) must build a library that has this entry point too."""
-    import ctypes
-    import shutil
-    import subprocess
-
-    if shutil.which("g++") is None:
-        pytest.skip("no g++ here")
-    real_run = subprocess.run
-
-    def no_make(cmd, *args, **kwargs):
-        if cmd[0] == "make":
-            raise FileNotFoundError("make")
-        assert "-Iinclude" in cmd
-        return real_run(cmd, *args, **kwargs)
-
-    so = tmp_path / "libpathway_native.so"
-    monkeypatch.setattr(native, "_SO_PATH", so)
-    monkeypatch.setattr(native.subprocess, "run", no_make)
-    assert native.build(force=True) and so.exists()
-    assert hasattr(ctypes.CDLL(str(so)), "pn_pack_rows")
 
 
 # -- the pair path: counter, span attribute, scores ---------------------------

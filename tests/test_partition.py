@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import pytest
 
 from pathway_tpu import observe
-from pathway_tpu.cache import ResultCache, normalize_generation
+from pathway_tpu.cache import ResultCache, normalize_generation, query_key
 from pathway_tpu.models.encoder import SentenceEncoder
 from pathway_tpu.ops import dispatch_counter
 from pathway_tpu.ops.ivf import IvfKnnIndex
@@ -299,8 +299,11 @@ def test_partition_absorb_invalidates_fleet_wide_cache_keys(enc):
         r1 = front.serve([q], k=5)
         assert not any(int(k) == 100 for k, _s in r1[0])
         # absorb on host 1 (owner of 100): max(gens) stays 3, the VECTOR
-        # changes — the cached r1 must not survive
-        absorb(100, f"fresh doc about {q}")
+        # changes — the cached r1 must not survive.  The fresh documents ARE
+        # the query: cosine 1, so they rank first whatever the encoder's
+        # random weights make of the other 24 (a near-match text scored 0.69
+        # against a fifth-best 0.73 and read as a stale result)
+        absorb(100, q)
         gens = _wait_gens(fleet.fabric, (3, 2, 1))
         assert gens == (3, 2, 1)
         assert max(gens) == 3  # a scalar max key would NOT change
@@ -310,17 +313,26 @@ def test_partition_absorb_invalidates_fleet_wide_cache_keys(enc):
         # the window case: admit under the current vector, land an
         # absorb before the window dispatches — the result crossing the
         # generation boundary is served but never cached
+        cache = ResultCache()
         slow_front = ServeScheduler(
-            fleet.fabric, window_us=400_000, result_cache=ResultCache(),
+            fleet.fabric, window_us=400_000, result_cache=cache,
             name="part-front-w",
         )
         try:
             ticket = slow_front.submit([q], k=5)
-            absorb(103, f"second fresh doc about {q}")  # inside the window
+            absorb(103, q)  # inside the window
+            gens = _wait_gens(fleet.fabric, (3, 3, 1))
+            assert gens == (3, 3, 1)
             stale_risk = ticket.result(timeout=30.0)
             assert stale_risk  # served, never raised
+            # stored under its admission key only if every owner dispatched
+            # it at that generation (an absorb that outlasted the window)
+            crossed = stale_risk.meta["index_generation"] != (3, 2, 1)
+            stored = cache.get_rows([query_key(q, (3, 2, 1))], 5)
+            assert (stored is None) == crossed, (stale_risk.meta, stored)
             r3 = slow_front.serve([q], k=5)
             assert any(int(k) == 103 for k, _s in r3[0]), r3
+            assert slow_front.stats.get("cache_hits", 0) == 0
         finally:
             slow_front.stop()
     finally:

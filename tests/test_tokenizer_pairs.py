@@ -24,16 +24,8 @@ from pathway_tpu.ops.knn import DeviceKnnIndex
 from pathway_tpu.ops.retrieve_rerank import RetrieveRerankPipeline
 from pathway_tpu.ops.serving import FusedEncodeSearch
 
-@pytest.fixture(scope="module")
-def pair_entry_point():
-    """Skips where the native library has no pair tokenizer entry point
-    (asked inside a test: the first call may build the library)."""
-    slots = np.zeros(1, np.int64)
-    if native.encode_pairs(b"a", np.array([0, 1]), slots, slots, 64, 8, 2, 1, 2, 5) is None:
-        pytest.skip("native library has no pair tokenizer entry point")
 
-
-needs_native = pytest.mark.usefixtures("pair_entry_point")
+needs_native = pytest.mark.usefixtures("needs_native")  # tests/conftest.py
 
 WORDS = [
     "alpha", "Beta", "it's", "x_1", "don't", "foo,", "bar.", "(baz)", "q?",
