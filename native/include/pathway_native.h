@@ -81,19 +81,15 @@ int64_t pn_frame_scan(const uint8_t* buf, int64_t len, int64_t* offsets,
 
 /* ---- hashing tokenizer (ASCII fast path; models/tokenizer.py) ----
  * blob = concatenated ASCII texts, offsets[n_texts+1] their boundaries.
- * Emits word-hash ids ([\w']+ runs and single punctuation chars, lowered,
- * xxh3 % (vocab_size - reserved) + reserved) into out_ids (capacity >=
- * blob length: every token spans >= 1 byte) with per-text out_offsets.
- * Returns 0, or -1 when built without xxhash (caller uses the Python
- * tokenizer). */
-int32_t pn_tokenize_hash(const uint8_t* blob, const int64_t* offsets,
-                         int64_t n_texts, int32_t vocab_size,
-                         int32_t reserved, int32_t* out_ids,
-                         int64_t* out_offsets);
+ * Both entry points scan each text into word-hash ids ([\w']+ runs and
+ * single punctuation chars, lowered, xxh3 % (vocab_size - reserved) +
+ * reserved), then lay out framed rows.  Both return -1 when built without
+ * xxhash (caller uses the Python tokenizer). */
 
 /* Pair rows ``CLS a SEP b SEP`` of a batch (HashTokenizer.encode_pairs):
- * tokenizes the n_texts DISTINCT texts of blob as pn_tokenize_hash does
- * (tok_ids / tok_offsets are its outputs, here scratch), then writes pair
+ * scans the n_texts DISTINCT texts of blob once each (into the scratch
+ * tok_ids, capacity >= blob length: every token spans >= 1 byte, and
+ * tok_offsets, n_texts + 1), then writes pair
  * i = (text a_slot[i], text b_slot[i]), truncated longest-first to budget
  * (>= 2) tokens, at out_ids + i*stride with ones in out_mask (both zeroed by
  * the caller, stride >= budget + 3) and its token count in out_lens[i].
@@ -105,6 +101,25 @@ int32_t pn_encode_pairs(const uint8_t* blob, const int64_t* offsets,
                         int32_t sep_id, int64_t stride, int32_t* tok_ids,
                         int64_t* tok_offsets, int32_t* out_ids,
                         int32_t* out_mask, int64_t* out_lens);
+
+/* Single-text rows ``CLS t... SEP`` of a batch, padded
+ * (HashTokenizer.encode_batch): scans the n_texts >= 1 texts of blob, keeps
+ * at most max_length - 2 (>= 0) tokens of each, and takes the shared width
+ * L = widths[longest framed row]: widths[0..max_length] is the caller's
+ * width rule as a table (models/tokenizer.py _width).  Rows longer than L (a
+ * pad_to under the longest row) keep L - 2 tokens.  Writes n_rows >= n_texts
+ * rows at stride L into out_ids / out_mask (capacity n_rows * width_cap
+ * int32 each, their contents on entry do not matter): cls_id, the tokens,
+ * sep_id, pad_id to the end, ones under the framed tokens; rows past
+ * n_texts all pad_id / 0.  *out_width = L.  Returns 0, or -1 when built
+ * without xxhash or on arguments it cannot lay out (offsets that descend,
+ * L < 2, L > width_cap). */
+int32_t pn_encode_batch(const uint8_t* blob, const int64_t* offsets,
+                        int64_t n_texts, int32_t vocab_size, int32_t reserved,
+                        int64_t max_length, const int64_t* widths,
+                        int64_t width_cap, int32_t cls_id, int32_t sep_id,
+                        int32_t pad_id, int64_t n_rows, int32_t* out_ids,
+                        int32_t* out_mask, int64_t* out_width);
 
 /* ---- sequence packing (models/packing.py) ----
  * pack_rows + pad_packed_rows + the rerank pipeline's pair_slot loop in one

@@ -13,6 +13,8 @@
 #include "../include/pathway_native.h"
 
 #include <algorithm>
+#include <memory>
+#include <new>
 
 #if defined(__has_include)
 #if __has_include(<xxhash.h>)
@@ -91,25 +93,6 @@ void tokenize(const uint8_t* blob, const int64_t* offsets, int64_t n_texts,
 }  // namespace
 #endif
 
-extern "C" int32_t pn_tokenize_hash(const uint8_t* blob,
-                                    const int64_t* offsets, int64_t n_texts,
-                                    int32_t vocab_size, int32_t reserved,
-                                    int32_t* out_ids, int64_t* out_offsets) {
-#ifdef PN_HAVE_XXHASH
-  tokenize(blob, offsets, n_texts, vocab_size, reserved, out_ids, out_offsets);
-  return 0;
-#else
-  (void)blob;
-  (void)offsets;
-  (void)n_texts;
-  (void)vocab_size;
-  (void)reserved;
-  (void)out_ids;
-  (void)out_offsets;
-  return -1;
-#endif
-}
-
 // Pair rows ``CLS a SEP b SEP`` for a whole batch (HashTokenizer.encode_pairs):
 // tokenize the batch's DISTINCT texts once, then lay out pair i from texts
 // a_slot[i], b_slot[i].  Truncation is HashTokenizer.encode's longest-first
@@ -153,6 +136,63 @@ extern "C" int32_t pn_encode_pairs(
   (void)a_slot; (void)b_slot; (void)n_pairs; (void)budget; (void)cls_id;
   (void)sep_id; (void)stride; (void)tok_ids; (void)tok_offsets;
   (void)out_ids; (void)out_mask; (void)out_lens;
+  return -1;
+#endif
+}
+
+// Single-text rows ``CLS t... SEP`` of a batch, padded
+// (HashTokenizer.encode_batch): tokenize every text, keep at most
+// max_length - 2 tokens of each, take the shared width L = widths[longest
+// framed row] (the width RULE stays in Python, models/tokenizer.py ``_width``,
+// and comes in as that table of max_length + 1 entries), cut rows to L - 2
+// tokens where a caller's ``pad_to`` is shorter than they are, and write
+// n_rows >= n_texts rows AT STRIDE L: the caller sizes out_ids / out_mask for
+// width_cap >= L a row and takes a view of their head, no copy.  Rows past
+// n_texts are pad_id / 0.  The ragged token ids are scratch of this call's
+// own (every token spans >= 1 byte of the blob).
+extern "C" int32_t pn_encode_batch(
+    const uint8_t* blob, const int64_t* offsets, int64_t n_texts,
+    int32_t vocab_size, int32_t reserved, int64_t max_length,
+    const int64_t* widths, int64_t width_cap, int32_t cls_id, int32_t sep_id,
+    int32_t pad_id, int64_t n_rows, int32_t* out_ids, int32_t* out_mask,
+    int64_t* out_width) {
+#ifdef PN_HAVE_XXHASH
+  if (max_length < 2 || n_texts < 1 || n_rows < n_texts) return -1;
+  for (int64_t t = 0; t < n_texts; ++t)
+    if (offsets[t + 1] < offsets[t]) return -1;
+  const int64_t blob_len = offsets[n_texts] - offsets[0];
+  std::unique_ptr<int32_t[]> ids_buf(new (std::nothrow) int32_t[blob_len + 1]);
+  std::unique_ptr<int64_t[]> off_buf(new (std::nothrow) int64_t[n_texts + 1]);
+  if (!ids_buf || !off_buf) return -1;
+  int32_t* tok_ids = ids_buf.get();
+  int64_t* tok_offsets = off_buf.get();
+  tokenize(blob, offsets, n_texts, vocab_size, reserved, tok_ids, tok_offsets);
+  int64_t longest = 0;
+  for (int64_t t = 0; t < n_texts; ++t)
+    longest = std::max(longest, tok_offsets[t + 1] - tok_offsets[t]);
+  const int64_t L = widths[std::min(longest, max_length - 2) + 2];
+  if (L < 2 || L > width_cap) return -1;
+  const int64_t keep = std::min(max_length, L) - 2;
+  for (int64_t t = 0; t < n_texts; ++t) {
+    const int32_t* tok = tok_ids + tok_offsets[t];
+    const int64_t k = std::min(tok_offsets[t + 1] - tok_offsets[t], keep);
+    int32_t* row = out_ids + t * L;
+    row[0] = cls_id;
+    std::copy_n(tok, k, row + 1);
+    row[k + 1] = sep_id;
+    std::fill(row + k + 2, row + L, pad_id);
+    int32_t* m = out_mask + t * L;
+    std::fill_n(m, k + 2, 1);
+    std::fill(m + k + 2, m + L, 0);
+  }
+  std::fill(out_ids + n_texts * L, out_ids + n_rows * L, pad_id);
+  std::fill(out_mask + n_texts * L, out_mask + n_rows * L, 0);
+  *out_width = L;
+  return 0;
+#else
+  (void)blob; (void)offsets; (void)n_texts; (void)vocab_size; (void)reserved;
+  (void)max_length; (void)widths; (void)width_cap; (void)cls_id; (void)sep_id;
+  (void)pad_id; (void)n_rows; (void)out_ids; (void)out_mask; (void)out_width;
   return -1;
 #endif
 }

@@ -9,6 +9,7 @@ trained in-framework are consistent because the mapping is deterministic.
 from __future__ import annotations
 
 import re
+from functools import lru_cache
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -23,12 +24,26 @@ _WORD_RE = re.compile(r"[\w']+|[^\w\s]")
 # pairs tokenised by ``encode_pairs``, by the path that took them
 _PAIRS_NATIVE = observe.counter("pathway_tokenizer_pairs_total", path="native")
 _PAIRS_PYTHON = observe.counter("pathway_tokenizer_pairs_total", path="python")
+# single texts tokenised by ``encode_batch``, likewise
+_TEXTS_NATIVE = observe.counter("pathway_tokenizer_texts_total", path="native")
+_TEXTS_PYTHON = observe.counter("pathway_tokenizer_texts_total", path="python")
 
 
 def _width(longest: int, max_length: int, pad_to: int | None) -> int:
     """The shared padded length: ``pad_to``, else the longest row rounded up
     to a multiple of 16 (to bound jit shape variants), at most max_length."""
     return pad_to or min(max_length, ((longest + 15) // 16) * 16)
+
+
+@lru_cache(maxsize=64)
+def _width_table(max_length: int, pad_to: int | None) -> Tuple[np.ndarray, int]:
+    """``_width`` by longest row, 0..max_length, and its largest entry: how
+    the rule reaches the native batch call (no second copy of it in C++)."""
+    widths = np.array(
+        [_width(n, max_length, pad_to) for n in range(max_length + 1)], np.int64
+    )
+    widths.setflags(write=False)  # one table, handed to every caller
+    return widths, int(widths.max())
 
 
 class HashTokenizer:
@@ -80,16 +95,29 @@ class HashTokenizer:
         pairs: Sequence[str] | None = None,
         max_length: int | None = None,
         pad_to: int | None = None,
+        rows: int | None = None,
+        span=None,
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Returns (ids [B, L], mask [B, L]) padded to a shared length."""
+        """Returns (ids [B, L], mask [B, L]) padded to a shared length, and
+        to ``rows`` rows where that is more than the texts (pad rows: all
+        ``PAD``, mask 0).  Single texts take the native path whenever the
+        input allows it (ASCII batch, the native library loaded), same
+        arrays as ``encode`` + ``_pad`` to the element, and are counted
+        under the path they took; ``span``, the caller's open bracket
+        around the call, learns it too (``native_texts``)."""
         if pairs is not None:
             return self.encode_pairs(texts, pairs, max_length, pad_to)[:2]
         max_length = max_length or self.max_length
-        fast = self._encode_batch_native(texts, max_length, pad_to)
-        if fast is not None:
-            return fast
-        return self._pad([self.encode(t, None, max_length) for t in texts],
-                         max_length, pad_to)
+        rows = max(rows or 0, len(texts))
+        out = self._encode_batch_native(texts, max_length, pad_to, rows)
+        native = out is not None
+        if not native:
+            out = self._pad([self.encode(t, None, max_length) for t in texts],
+                            max_length, pad_to, rows)
+        (_TEXTS_NATIVE if native else _TEXTS_PYTHON).inc(len(texts))
+        if span is not None:
+            span.set(native_texts=len(texts) if native else 0)
+        return out
 
     def encode_pairs(
         self,
@@ -116,11 +144,16 @@ class HashTokenizer:
         return ids, mask, False
 
     def _pad(
-        self, encoded: List[List[int]], max_length: int, pad_to: int | None
+        self,
+        encoded: List[List[int]],
+        max_length: int,
+        pad_to: int | None,
+        rows: int = 0,
     ) -> Tuple[np.ndarray, np.ndarray]:
         L = _width(max((len(e) for e in encoded), default=1), max_length, pad_to)
-        ids = np.full((len(encoded), L), self.PAD, dtype=np.int32)
-        mask = np.zeros((len(encoded), L), dtype=np.int32)
+        rows = max(rows, len(encoded))
+        ids = np.full((rows, L), self.PAD, dtype=np.int32)
+        mask = np.zeros((rows, L), dtype=np.int32)
         for i, e in enumerate(encoded):
             e = e[:L]
             ids[i, : len(e)] = e
@@ -143,44 +176,26 @@ class HashTokenizer:
         return joined.encode(), offsets
 
     def _encode_batch_native(
-        self, texts: Sequence[str], max_length: int, pad_to: int | None
+        self, texts: Sequence[str], max_length: int, pad_to: int | None, rows: int
     ) -> Tuple[np.ndarray, np.ndarray] | None:
-        """Whole-batch tokenization through the C++ scanner, with
-        vectorised CLS/SEP framing and padding.  The per-word Python loop
-        was the ingest bottleneck: the TPU encoder consumes docs >10x
-        faster than the host could tokenize them.  Returns None (caller
-        keeps the Python path) for non-ASCII batches or without the native
-        library."""
-        n = len(texts)
-        if n == 0:
+        """The rows of a whole batch of single texts in ONE native call
+        (``pn_encode_batch``): scan, truncate, frame ``CLS t... SEP`` and pad
+        to the shared width and to ``rows`` rows.  The numpy form of the
+        framing (some eighteen array calls around a GIL-releasing scan) took
+        0.06 ms alone and 1.0-4.8 ms on the serve path's one scheduler
+        thread (PERF.md section 6, ISSUE 31).  None (caller keeps the Python
+        path) for non-ASCII batches or without the native library."""
+        if len(texts) == 0:
             return None
         blob = self._ascii_blob([t if isinstance(t, str) else str(t) for t in texts])
         if blob is None:
             return None
         from .. import native as _native
 
-        out = _native.tokenize_hash(*blob, self.vocab_size, self._RESERVED)
-        if out is None:
-            return None
-        tok_ids, tok_off = out
-        counts = np.diff(tok_off)
-        trunc = np.minimum(counts, max_length - 2)
-        longest = int(trunc.max()) + 2 if n else 1
-        L = _width(longest, max_length, pad_to)
-        trunc = np.minimum(trunc, L - 2)
-        ids = np.full((n, L), self.PAD, dtype=np.int32)
-        total = int(trunc.sum())
-        if total:
-            starts = np.cumsum(trunc) - trunc
-            pos = np.arange(total, dtype=np.int64) - np.repeat(starts, trunc)
-            src = np.repeat(tok_off[:-1], trunc) + pos
-            ids[np.repeat(np.arange(n), trunc), pos + 1] = tok_ids[src]
-        ids[:, 0] = self.CLS
-        ids[np.arange(n), trunc + 1] = self.SEP
-        mask = (
-            np.arange(L, dtype=np.int64)[None, :] < (trunc + 2)[:, None]
-        ).astype(np.int32)
-        return ids, mask
+        return _native.encode_batch(
+            *blob, self.vocab_size, self._RESERVED, max_length,
+            *_width_table(max_length, pad_to), self.CLS, self.SEP, self.PAD, rows,
+        )
 
     def _encode_pairs_native(
         self,

@@ -176,19 +176,26 @@ class WordPieceTokenizer:
         pairs: Sequence[str] | None = None,
         max_length: int | None = None,
         pad_to: int | None = None,
+        rows: int | None = None,
+        span=None,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Returns (ids [B, L], mask [B, L]) padded to a shared length —
         same contract as HashTokenizer.encode_batch (length rounded to a
-        multiple of 16 to bound jit shape variants)."""
+        multiple of 16 to bound jit shape variants; ``rows`` above the
+        texts' count adds all-PAD rows; ``span`` learns that no text took
+        a native path)."""
         max_length = max_length or self.max_length
+        if span is not None:
+            span.set(native_texts=0)
         encoded = [
             self.encode(t, pairs[i] if pairs is not None else None, max_length)
             for i, t in enumerate(texts)
         ]
         longest = max((len(e) for e in encoded), default=1)
         L = pad_to or min(max_length, ((longest + 15) // 16) * 16)
-        ids = np.full((len(encoded), L), self.PAD, dtype=np.int32)
-        mask = np.zeros((len(encoded), L), dtype=np.int32)
+        rows = max(rows or 0, len(encoded))
+        ids = np.full((rows, L), self.PAD, dtype=np.int32)
+        mask = np.zeros((rows, L), dtype=np.int32)
         for i, e in enumerate(encoded):
             e = e[:L]
             ids[i, : len(e)] = e

@@ -42,7 +42,7 @@ __all__ = [
     "crc32",
     "frame_scan",
     "shard_rows",
-    "tokenize_hash",
+    "encode_batch",
     "encode_pairs",
     "pack_rows",
 ]
@@ -162,9 +162,10 @@ def _declare(dll: ctypes.CDLL) -> ctypes.CDLL:
     dll.pn_frame_scan.argtypes = [_p_u8, _i64, _p_i64, _p_i64, _i64, _p_i64]
     dll.pn_shard_rows.restype = None
     dll.pn_shard_rows.argtypes = [_p_u64, _i64, _u32, _u64, _p_i64, _p_i64]
-    dll.pn_tokenize_hash.restype = _i32
-    dll.pn_tokenize_hash.argtypes = [
-        _p_u8, _p_i64, _i64, _i32, _i32, ctypes.POINTER(_i32), _p_i64,
+    dll.pn_encode_batch.restype = _i32
+    dll.pn_encode_batch.argtypes = [
+        _p_u8, _p_i64, _i64, _i32, _i32, _i64, _p_i64, _i64, _i32, _i32,
+        _i32, _i64, _p_i32, _p_i32, _p_i64,
     ]
     dll.pn_encode_pairs.restype = _i32
     dll.pn_encode_pairs.argtypes = [
@@ -489,27 +490,57 @@ def shard_rows(
 # ---------------------------------------------------------------- tokenizer
 
 
-def tokenize_hash(
-    blob: bytes, offsets: np.ndarray, vocab_size: int, reserved: int
+def encode_batch(
+    blob: bytes,
+    offsets: np.ndarray,
+    vocab_size: int,
+    reserved: int,
+    max_length: int,
+    widths: np.ndarray,
+    width_cap: int,
+    cls_id: int,
+    sep_id: int,
+    pad_id: int,
+    rows: int,
 ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-    """Batch hashing tokenizer over concatenated ASCII texts
-    (models/tokenizer.py semantics): returns (ids ragged int32, tok_offsets
-    int64[n+1]), or None when the native path is unavailable (caller keeps
-    the Python tokenizer)."""
-    dll = lib()
+    """Rows ``CLS t... SEP`` of the ASCII texts of ``blob`` (``offsets``
+    their boundaries), each truncated to ``max_length - 2`` tokens
+    (models/tokenizer.py ``encode`` semantics), padded with ``pad_id`` to
+    the shared width ``widths[longest row]`` (the caller's width rule as a
+    table int64[max_length + 1], ``width_cap`` its largest entry) and to
+    ``rows >= n_texts`` rows, in ONE native call that keeps the GIL for a
+    short blob.  Returns (ids [rows, L] int32, mask [rows, L]), views of the
+    head of buffers sized for the widest L, or None when the native path is
+    unavailable or refuses the input (caller keeps the Python tokenizer)."""
+    dll = _lib_for(len(blob))
     if dll is None:
         return None
     n_texts = len(offsets) - 1
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
-    out_ids = np.empty(max(len(blob), 1), dtype=np.int32)
-    out_offsets = np.empty(n_texts + 1, dtype=np.int64)
-    rc = dll.pn_tokenize_hash(
-        _as_u8_ptr(blob), _np_ptr(offsets, _i64), n_texts,
-        vocab_size, reserved, _np_ptr(out_ids, _i32), _np_ptr(out_offsets, _i64),
+    widths = np.ascontiguousarray(widths, dtype=np.int64)
+    if (
+        n_texts < 1
+        or rows < n_texts
+        or len(widths) != max_length + 1
+        # every boundary inside the blob (the call refuses ones that descend)
+        or offsets[0] != 0
+        or offsets[-1] != len(blob)
+    ):
+        return None
+    # sized for the widest row, written at the width taken: the head of
+    # each buffer IS the [rows, L] array, no copy
+    ids = np.empty(rows * width_cap, dtype=np.int32)
+    mask = np.empty(rows * width_cap, dtype=np.int32)
+    width = _i64(0)
+    rc = dll.pn_encode_batch(
+        _as_u8_ptr(blob), _np_ptr(offsets, _i64), n_texts, vocab_size, reserved,
+        max_length, _np_ptr(widths, _i64), width_cap, cls_id, sep_id, pad_id,
+        rows, _np_ptr(ids, _i32), _np_ptr(mask, _i32), ctypes.byref(width),
     )
     if rc != 0:
         return None
-    return out_ids[: out_offsets[n_texts]], out_offsets
+    L = width.value
+    return ids[: rows * L].reshape(rows, L), mask[: rows * L].reshape(rows, L)
 
 
 def encode_pairs(

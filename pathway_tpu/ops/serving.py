@@ -1035,19 +1035,12 @@ class FusedEncodeSearch:
         # one thread's lock hold (tokenizers are stateless; the bucket
         # padding matches encoder.encode's, so B in the compile key still
         # takes a handful of values — round-1 advice)
+        n_real = len(texts)
         with observe.span("stage1.tokenize", **_S1_TOKENIZE) as tokenize:
-            ids, mask = self.encoder.tokenizer.encode_batch(texts)
-            ids = np.asarray(ids)
-            mask = np.asarray(mask)
-            n_real = ids.shape[0]
-            b = _bucket(n_real)
-            if b > n_real:
-                ids = np.concatenate(
-                    [ids, np.zeros((b - n_real, ids.shape[1]), ids.dtype)]
-                )
-                mask = np.concatenate(
-                    [mask, np.zeros((b - n_real, mask.shape[1]), mask.dtype)]
-                )
+            # the tokenizer lays out the bucket's pad rows (ids PAD, mask 0)
+            ids, mask = self.encoder.tokenizer.encode_batch(
+                texts, rows=_bucket(n_real), span=tokenize
+            )
         # tokenize_pack runs from t_start to the dispatch returning; the
         # wait for the serve locks from t_ready to the dispatch bracket
         t_start, t_ready = tokenize.t0_ns, tokenize.t1_ns

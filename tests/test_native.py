@@ -136,7 +136,9 @@ def test_a_failed_compile_leaves_no_file_and_says_so_once(
     assert list((own_native_dir / "build").iterdir()) == []
     # what a caller sees is the Python body's answer
     assert native.crc32(b"hello") == 0x3610A686
-    assert native.tokenize_hash(b"a", np.array([0, 1]), 64, 8) is None
+    assert native.encode_batch(
+        b"a", np.array([0, 1]), 64, 8, 16, np.full(17, 16), 16, 1, 2, 0, 1
+    ) is None
 
 
 def test_the_library_has_every_name_the_header_declares(needs_native):

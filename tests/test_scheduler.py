@@ -257,12 +257,17 @@ def test_tokenize_runs_off_the_serve_lock(stack):
     tokenize_pack histogram still covering the prep)."""
     enc, _, index = stack
     serve = FusedEncodeSearch(enc, index, k=4)
-    calls = []
+    calls, asked = [], []
     orig = enc.tokenizer.encode_batch
 
     def checked(*args, **kwargs):
         calls.append(serve._lock.locked())
-        return orig(*args, **kwargs)
+        ids, mask = orig(*args, **kwargs)
+        # the tokenizer lays out the batch bucket's pad rows too (ISSUE 31)
+        asked.append(kwargs["rows"])
+        assert len(ids) == len(mask) == kwargs["rows"]
+        assert not ids[len(args[0]):].any() and not mask[len(args[0]):].any()
+        return ids, mask
 
     enc.tokenizer.encode_batch = checked
     try:
@@ -271,10 +276,12 @@ def test_tokenize_runs_off_the_serve_lock(stack):
         )
         count_before = hist.snapshot()[2]
         assert serve.submit([QUERIES[0]])()[0]
+        assert len(serve.submit(QUERIES[:3])()) == 3
     finally:
         enc.tokenizer.encode_batch = orig
     assert calls and not any(calls), "tokenization ran under the serve lock"
-    assert hist.snapshot()[2] == count_before + 1
+    assert asked == [1, 4]  # _bucket(1), _bucket(3)
+    assert hist.snapshot()[2] == count_before + 2
 
 
 def test_shared_batcher_matches_predict_and_dedups(stack):
