@@ -45,8 +45,8 @@ from ..observe import hbm, profile
 from ..robust import retry_call
 from ._params import unbox as _unbox
 
-from . import looped
-from .looped import LoopedConfig, token_stats
+from . import looped, moe
+from .looped import token_stats
 from .tokenizer import HashTokenizer
 from .transformer import (
     KVTransformerDecoder,
@@ -150,14 +150,17 @@ class TextGenerator:
         architecture: Optional[Mapping[str, Any]] = None,
         params: Any = None,
     ):
-        # ``architecture`` (a published config.json's keys) selects the
-        # looped decoder family (models/looped.py: RMSNorm sandwich, rotary,
-        # gated SiLU, untied head, ``total_ut_steps`` passes over one stack)
-        # at exactly those sizes; ``params`` hands its weights in.  Without
-        # it this is the 4 x dimension LayerNorm/GELU trunk, as ever.
-        self.looped = architecture is not None
-        if self.looped:
-            self.config = LoopedConfig.from_architecture(architecture, dtype)
+        # ``architecture`` (a published config.json's keys) selects a decoder
+        # family at exactly those sizes, and its own keys say which: routed
+        # experts (``moe_num_primary_experts``; models/moe.py: grouped-query
+        # heads, per-layer rotary and window layouts, a router before
+        # attention) or else the looped family (models/looped.py: RMSNorm
+        # sandwich, rotary, gated SiLU, ``total_ut_steps`` passes over one
+        # stack).  ``params`` hands its weights in.  Without it this is the
+        # 4 x dimension LayerNorm/GELU trunk, as ever (``family`` None).
+        self.family = None if architecture is None else (moe if "moe_num_primary_experts" in architecture else looped)
+        if self.family is not None:
+            self.config = self.family.Config.from_architecture(architecture, dtype)
             vocab_size, max_length = self.config.vocab_size, self.config.max_len
         else:
             self.config = TransformerConfig(
@@ -201,8 +204,8 @@ class TextGenerator:
         from ..ops.recompile_guard import RecompileTripwire
 
         self._tripwire = RecompileTripwire(f"TextGenerator[{model}]")
-        if self.looped:
-            self.params = self._looped_params(params, seed)
+        if self.family is not None:
+            self.params = self._family_params(params, seed)
         else:
             ids = jnp.zeros((1, 16), jnp.int32)
             mask = jnp.ones((1, 16), jnp.int32)
@@ -221,11 +224,11 @@ class TextGenerator:
         # HBM ledger (observe/hbm.py): parameter tree bytes
         hbm.track_params("generator", self)
 
-    def _looped_params(self, params, seed: int):
-        """The looped family's weights: handed in (their tree is checked
+    def _family_params(self, params, seed: int):
+        """A decoder family's weights: handed in (their tree is checked
         against the architecture's), else seeded random."""
         if params is None:
-            return looped.init_params(self.config, seed)
+            return self.family.init_params(self.config, seed)
 
         def shapes(tree):
             return {
@@ -233,7 +236,7 @@ class TextGenerator:
                 for k, v in jax.tree_util.tree_flatten_with_path(tree)[0]
             }
 
-        want = shapes(jax.eval_shape(lambda: looped.init_params(self.config, 0)))
+        want = shapes(jax.eval_shape(lambda: self.family.init_params(self.config, 0)))
         if shapes(params) != want:
             diff = sorted(set(shapes(params).items()) ^ set(want.items()))[:4]
             raise ValueError(f"params do not fit the architecture: {diff}")
@@ -244,7 +247,7 @@ class TextGenerator:
         prefix cache's blocks ``(k, v)``: the trunk takes them stacked
         ``[B, depth, P, H, hd]``; the looped family takes the blocks as
         they are, row by row (no copy)."""
-        if self.looped:
+        if self.family is not None:
             return (
                 tuple(tuple(b[0] for b in row[:n_blk]) for row in rows),
                 tuple(tuple(b[1] for b in row[:n_blk]) for row in rows),
@@ -260,14 +263,30 @@ class TextGenerator:
         )
 
     def check_decode_options(self, spec_k: int, kv_quant: str) -> None:
-        """What the slot pool may be asked of this generator.  The looped
-        family has no verify/draft program and no int8 cache rows yet: it
-        says so here, at construction, instead of decoding wrongly."""
-        if self.looped and (spec_k >= 2 or kv_quant == "int8"):
+        """What the slot pool may be asked of this generator.  The decoder
+        families have no verify/draft program and no int8 cache rows yet:
+        they say so here, at construction, instead of decoding wrongly."""
+        if self.family is not None and (spec_k >= 2 or kv_quant == "int8"):
             raise ValueError(
-                "the looped decoder family serves with speculation off (decode.spec_k 0) and a "
+                f"the {self.family.FAMILY} decoder family serves with speculation off (decode.spec_k 0) and a "
                 f"bf16 cache (decode.kv_quant bf16); asked for spec_k={spec_k}, kv_quant={kv_quant}"
             )
+
+    def kv_pool_layout(self, T: int):
+        """The slot pool's kinds of rows at width ``T`` as ``(kind, cache
+        rows deep, rows a layer)``: one rectangle, or what the family states
+        (models/moe.py: full layers beside window layers' rings)."""
+        layout = getattr(self.config, "pool_layout", None)
+        return layout(T) if layout is not None else (("full", self.config.cache_depth, T),)
+
+    def alloc_kv_pool(self, slots: int, T: int, dtype):
+        """One of the two pools (keys, or values) for ``slots`` sequences:
+        ``[slots, depth, rows, key/value heads, head_dim]`` per kind of row,
+        a bare array where there is one kind."""
+        cfg = self.config
+        heads = getattr(cfg, "n_kv_heads", cfg.n_heads)
+        pools = tuple(jnp.zeros((slots, depth, rows, heads, cfg.head_dim), dtype) for _, depth, rows in self.kv_pool_layout(T))
+        return pools[0] if len(pools) == 1 else pools
 
     # -- legacy full re-attend decode (parity reference / fallback) ----------
     def _decode_fn(self, B: int, L: int, steps: int):
@@ -529,10 +548,12 @@ class TextGenerator:
             return fn
         self._tripwire.observe(key)
         cfg = self.config
-        if self.looped:
+        if self.family is not None:
             self.check_decode_options(0, "int8" if quant else "bf16")
+            # the prefix tier's block: a family may hand the tier its blocks itself (models/moe.py)
+            block = self.kv_cache.block if self.kv_cache is not None else 0
             fn = profile.wrap(
-                "generator.slot_prefill", looped.slot_prefill(cfg, S, T, B, L_sfx, P)
+                "generator.slot_prefill", self.family.slot_prefill(cfg, S, T, B, L_sfx, P, block)
             )
             self._fns[key] = fn
             return fn
@@ -629,10 +650,10 @@ class TextGenerator:
         if fn is not None:
             return fn
         self._tripwire.observe(key)
-        if self.looped:
+        if self.family is not None:
             self.check_decode_options(0, "int8" if quant else "bf16")
             fn = profile.wrap(
-                "generator.slot_step", looped.slot_step(self.config, S, T, chunk)
+                "generator.slot_step", self.family.slot_step(self.config, S, T, chunk)
             )
             self._fns[key] = fn
             return fn
@@ -957,10 +978,10 @@ class TextGenerator:
                 )
         return [self.render_tokens(row) for row in toks]
 
-    def _generate_looped(
+    def _generate_family(
         self, prompts, max_new_tokens: int, temperature: float, seed: int, eos
     ) -> List[str]:
-        """Solo decode of the looped family: the slot pool's own prefill
+        """Solo decode of a decoder family: the slot pool's own prefill
         and step programs over a private pool, one slot per prompt, as wide
         as the longest prompt plus the budget."""
         from ..ops.dispatch_counter import record_fetch
@@ -975,7 +996,7 @@ class TextGenerator:
         L = ids.shape[1]
         T = -(-(L + max_new_tokens) // 64) * 64
         chunk = min(max_new_tokens, decode_step_bucket())
-        pool = jnp.zeros((b, cfg.cache_depth, T, cfg.n_heads, cfg.head_dim), cfg.dtype)
+        pool = self.alloc_kv_pool(b, T, cfg.dtype)
         empty = ((),) * b
         pad = b - n
         with self._lock:
@@ -984,7 +1005,7 @@ class TextGenerator:
         rng0 = np.stack([np.asarray(jax.random.PRNGKey(seed))] * b)
         temps = jnp.full((b,), temperature, jnp.float32)
         pk, pv, tok, rngs, _ = retry_call(
-            "generator.dispatch", prefill, self.params, pool, jnp.zeros_like(pool),
+            "generator.dispatch", prefill, self.params, pool, jax.tree_util.tree_map(jnp.zeros_like, pool),
             # a pad row repeats row 0 (same slot, same ids): it writes the same values again
             jnp.asarray(np.r_[np.arange(n), np.zeros(pad)].astype(np.int32)),
             jnp.asarray(np.r_[ids, ids[:1].repeat(pad, 0)]), jnp.asarray(np.r_[n_lens, n_lens[:1].repeat(pad)]),
@@ -1048,8 +1069,8 @@ class TextGenerator:
         eos = self.eos_id if eos_id is _UNSET else eos_id
         if eos is not None and int(eos) == self.tokenizer.PAD:
             raise ValueError("eos_id must differ from the PAD token id")
-        if self.looped:
-            return self._generate_looped(
+        if self.family is not None:
+            return self._generate_family(
                 prompts, max_new_tokens, temperature, seed, eos
             )
         if use_kv if use_kv is not None else self._use_kv:

@@ -43,6 +43,7 @@ __all__ = [
     "token_stats",
 ]
 
+FAMILY = "looped"  # what models/generator.py calls this family where it refuses an option
 TOP_LOGPROBS = 8  # ids and logits kept per emitted token (what serving APIs call logprobs)
 
 
@@ -101,6 +102,9 @@ class LoopedConfig:
     n_layers = property(lambda self: self.num_hidden_layers)
     max_len = property(lambda self: self.max_position_embeddings)
     cache_depth = property(lambda self: self.total_ut_steps * self.num_hidden_layers)
+
+
+Config = LoopedConfig  # every decoder family's module names its configuration so
 
 
 def init_params(cfg: LoopedConfig, seed: int, scale: float = 0.02) -> Dict[str, Any]:
@@ -280,7 +284,7 @@ def _prefix_rows(prefix, d, dtype):
     ]).astype(dtype)
 
 
-def slot_prefill(cfg: LoopedConfig, S: int, T: int, B: int, L_sfx: int, P: int) -> Callable:
+def slot_prefill(cfg: LoopedConfig, S: int, T: int, B: int, L_sfx: int, P: int, block: int = 0) -> Callable:
     """JOIN of ``B`` rows: ``(params, pool_k, pool_v, slots [B], suffix_ids
     [B, L_sfx], n_len [B], prefix_k, prefix_v, rngs [B, 2], temps [B]) ->
     (pool_k, pool_v, first [B], rngs, extra)``.  ``prefix_k`` / ``prefix_v``
@@ -295,7 +299,8 @@ def slot_prefill(cfg: LoopedConfig, S: int, T: int, B: int, L_sfx: int, P: int) 
     ids), so it writes the same values again.  Rows past ``P + L_sfx`` keep
     the last occupant's values: no query sees a key past its own position,
     and a step writes a position before it attends it.  The pools are
-    donated and updated in place."""
+    donated and updated in place.  (``block``, the prefix tier's, is every
+    family's to take: this one's blocks are cut from the pool after the join.)"""
 
     def run(params, pool_k, pool_v, slots, suffix_ids, n_len, prefix_k, prefix_v, rngs, temps):
         pos = jnp.broadcast_to((P + jnp.arange(L_sfx, dtype=jnp.int32))[None, :], (B, L_sfx))

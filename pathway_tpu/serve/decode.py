@@ -149,6 +149,11 @@ _H_TICKET_WAKE = observe.histogram("pathway_generator_ticket_wake_seconds")
 # what a request's meta carries of every emitted token (models/looped.py
 # ``token_stats``): its logit, the log-sum-exp, the top ids and logits
 _STAT_KEYS = ("logit", "lse", "top_ids", "top_logits")
+# the most tokens one join program carries (rows x padded suffix): a cohort
+# over it is cut into joins of fewer rows, one after another.  Sixteen rows of
+# a few hundred tokens pass whole; sixteen prompts of thousands of tokens do
+# not become one program of a hundred thousand
+JOIN_TOKEN_BUDGET = 8192
 
 
 class DecodeResult(str):
@@ -304,9 +309,15 @@ class ContinuousDecoder(_CoalescerBase):
             kv_width = config.get("decode.kv_width")
         self._T = min(cfg.max_len, kv_width) if kv_width else cfg.max_len
         # one cache row per (loop step, layer): the architecture says how
-        # many, and how wide a head is
-        self._depth, self._heads, self._head_dim = cfg.cache_depth, cfg.n_heads, cfg.head_dim
+        # many, how many key/value heads and how wide a head; the generator,
+        # which kinds of rows (one rectangle ``T`` wide, or full layers
+        # beside window layers' rings: ``(kind, depth, rows)`` each)
+        self._layout = generator.kv_pool_layout(self._T)
+        self._depth = sum(depth for _, depth, _ in self._layout)
+        self._heads, self._head_dim = getattr(cfg, "n_kv_heads", cfg.n_heads), cfg.head_dim
         self._loop_steps = cfg.total_ut_steps
+        # choices of expert one forwarded token makes (0: no routed experts)
+        self._expert_choices = cfg.n_layers * getattr(cfg, "moe_num_active_primary_experts", 0)
         if self._quant:
             # int8 pool + per-(layer, head, channel) stored scales — the
             # scales are derived from the generator's params off the
@@ -344,6 +355,14 @@ class ContinuousDecoder(_CoalescerBase):
             "draft_accepted": 0,   # draft tokens accepted by the verify
             "loop_passes": 0,      # loop steps executed x tokens forwarded
             "tokens_forwarded": 0, # tokens run through the stack (prefilled + stepped)
+            "joins": 0,            # join programs run
+            "join_tokens": 0,      # tokens they carried (rows x padded suffix)
+            "join_splits": 0,      # cohorts cut to JOIN_TOKEN_BUDGET
+            # routed experts (models/moe.py), by phase: (token, expert) choices
+            # made, experts that got a token summed over (program, layer), and
+            # the busiest expert's tokens summed likewise
+            **{f"{what}_{phase}": 0 for what in ("expert_tokens", "experts_touched", "expert_load_max")
+               for phase in ("prefill", "decode")},
         }
         super().__init__(
             name=name or f"decode-{observe.next_id()}",
@@ -363,18 +382,18 @@ class ContinuousDecoder(_CoalescerBase):
         )
 
     def _alloc_pool(self) -> None:
-        import jax.numpy as jnp
+        self._pk = self.generator.alloc_kv_pool(self.slots, self._T, self._pool_dtype)
+        self._pv = self.generator.alloc_kv_pool(self.slots, self._T, self._pool_dtype)
 
-        self._pk = jnp.zeros(
-            (self.slots, self._depth, self._T, self._heads, self._head_dim),
-            self._pool_dtype,
-        )
-        self._pv = jnp.zeros_like(self._pk)
+    def _pool_buffers(self) -> List[Any]:
+        import jax
+
+        return jax.tree_util.tree_leaves((self._pk, self._pv))
 
     def _pool_lost(self) -> bool:
         """A program that donates the pool and then fails leaves it deleted:
         nothing in flight can go on.  True if the pool had to be made anew."""
-        if not (self._pk.is_deleted() or self._pv.is_deleted()):
+        if not any(buf.is_deleted() for buf in self._pool_buffers()):
             return False
         self._alloc_pool()
         return True
@@ -382,14 +401,14 @@ class ContinuousDecoder(_CoalescerBase):
     def kv_bytes_per_token(self) -> int:
         """Cache bytes one token of one sequence holds: K and V, every
         (loop step, layer) row."""
-        return 2 * self._depth * self._heads * self._head_dim * self._pk.dtype.itemsize
+        return 2 * self._depth * self._heads * self._head_dim * np.dtype(self._pool_dtype).itemsize
 
     def hbm_bytes(self) -> int:
         """Device bytes of the persistent slot pool (K + V buffers +
         per-slot rng chains) — ``.nbytes`` metadata, never a sync."""
         return sum(
             int(getattr(buf, "nbytes", 0))
-            for buf in (self._pk, self._pv, self._rngs)
+            for buf in (*self._pool_buffers(), self._rngs)
         )
 
     def hbm_components(self) -> Dict[str, int]:
@@ -602,7 +621,23 @@ class ContinuousDecoder(_CoalescerBase):
             L_pad = 16
             while L_pad < L:
                 L_pad *= 2
-            self._prefill_group(grp, min(L_pad, self._T - P), P)
+            L_sfx = min(L_pad, self._T - P)
+            # the cohort, cut to the token budget: joins of as many rows as
+            # the widest batch bucket under it holds, one after another
+            rows = self._join_rows(L_sfx)
+            if len(grp) > rows:
+                self.pool_stats["join_splits"] += 1
+            for a in range(0, len(grp), rows):
+                self._prefill_group(grp[a : a + rows], L_sfx, P)
+
+    @staticmethod
+    def _join_rows(L_sfx: int) -> int:
+        """The widest join batch bucket (1, 4, 16, ...) whose rows of
+        ``L_sfx`` tokens stay inside ``JOIN_TOKEN_BUDGET``; one row always goes."""
+        rows = 1
+        while rows * 4 * L_sfx <= JOIN_TOKEN_BUDGET:
+            rows *= 4
+        return rows
 
     def _prefill_group(self, grp: List[dict], L_sfx: int, P: int) -> None:
         import jax
@@ -622,7 +657,7 @@ class ContinuousDecoder(_CoalescerBase):
         try:
             with observe.span(
                 "gen.prefill.dispatch", rows=n_real, batch=B,
-                suffix_tokens=L_sfx, prefix_tokens=P, **_S_PREFILL_DISPATCH,
+                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, **_S_PREFILL_DISPATCH,
             ):
                 t0 = time.perf_counter_ns()
                 suffix = np.zeros((B, L_sfx), np.int32)
@@ -645,6 +680,9 @@ class ContinuousDecoder(_CoalescerBase):
                 # (and what the program read off their logits) must reach the
                 # riders' tickets before the step loop takes over
                 firsts = np.asarray(toks)
+                # the prompt's keys and values, where a family hands them to the
+                # prefix tier beside the pool: they stay on the device
+                prompt_kv = extra.pop("prompt_kv", None)
                 extra = jax.device_get(extra)
             t_first = time.perf_counter()
             t1 = time.perf_counter_ns()
@@ -675,6 +713,9 @@ class ContinuousDecoder(_CoalescerBase):
             return
         self._pk, self._pv, self._rngs = pk, pv, rngs_all
         pk_now, pv_now = self._pk, self._pv
+        self.pool_stats["joins"] += 1
+        self.pool_stats["join_tokens"] += B * L_sfx
+        self._note_expert_load("prefill", extra, sum(rec["n"] - P for rec in grp))
         for j, rec in enumerate(grp):
             req = rec["req"]
             slot = slots_real[j]
@@ -685,7 +726,12 @@ class ContinuousDecoder(_CoalescerBase):
             if gen.kv_cache is not None:
                 blk = gen.kv_cache.block
                 matched, _blocks, chain = rec["match"]
-                if self._quant:
+                if prompt_kv is not None:
+                    # per row, the suffix's blocks as the program cut them:
+                    # block ``jb`` of the prompt is the suffix's ``jb - P / blk``-th
+                    def capture(jb, _j=j):
+                        return prompt_kv[0][_j][jb - P // blk], prompt_kv[1][_j][jb - P // blk]
+                elif self._quant:
                     # int8 pool: captured blocks dequantize back to the
                     # cache's bf16 convention; a warm join re-quantizes
                     # them — idempotent (ops/kv_quant.py), so warm pool
@@ -816,7 +862,8 @@ class ContinuousDecoder(_CoalescerBase):
                 L_pad = 16
                 while L_pad < width - P:
                     L_pad *= 2
-                shapes.update((B, min(L_pad, self._T - P), P) for B in sizes)
+                L_sfx = min(L_pad, self._T - P)
+                shapes.update((B, L_sfx, P) for B in sizes if B <= self._join_rows(L_sfx))
         for B, L_sfx, P in sorted(shapes):
             pk, pv, toks, rngs, _ = self._dispatch_prefill(
                 B, L_sfx, P, [], np.zeros((B, L_sfx), np.int32), np.zeros(B, np.int32),
@@ -851,8 +898,8 @@ class ContinuousDecoder(_CoalescerBase):
             fn = gen._slot_step_fn(S, self._T, self.chunk, self._quant)
         sc = (self._kscale, self._vscale) if self._quant else ()
         n_steps = self.chunk
-        if getattr(gen, "looped", False):
-            # the looped family's step program takes the number of steps to
+        if gen.family is not None:
+            # a decoder family's step program takes the number of steps to
             # run: no further than the nearest budget's end, so a lane leaves
             # (and the next request joins) at the step it finishes
             n_steps = min([self.chunk] + [st.left for st in self._active.values()])
@@ -910,6 +957,7 @@ class ContinuousDecoder(_CoalescerBase):
         self.pool_stats["chunks"] += 1
         self.pool_stats["steps"] += n_steps
         self.pool_stats["occupancy_sum"] += len(self._active)
+        self._note_expert_load("decode", extra, n_steps * len(self._active), n_steps)
         if bctx is not None:
             trace.finish(bctx)
             for st in riders:
@@ -955,6 +1003,17 @@ class ContinuousDecoder(_CoalescerBase):
         self.pool_stats["tokens_forwarded"] += n
         self.pool_stats["loop_passes"] += n * self._loop_steps
         self._note_exit_mass(extra, (slice(0, n), lane))
+
+    def _note_expert_load(self, phase: str, extra, tokens: int, n_steps: Optional[int] = None) -> None:
+        """Book what a program with routed experts read off its routers, per
+        layer (and per step, of which the first ``n_steps`` ran): the experts
+        that got a token and the busiest one's tokens."""
+        touched = extra.get("experts_touched")
+        if touched is None:
+            return
+        self.pool_stats[f"expert_tokens_{phase}"] += tokens * self._expert_choices
+        self.pool_stats[f"experts_touched_{phase}"] += int(np.sum(touched[:n_steps]))
+        self.pool_stats[f"expert_load_max_{phase}"] += int(np.sum(extra["expert_load_max"][:n_steps]))
 
     def _note_exit_mass(self, extra, at) -> None:
         mass = extra.get("exit_mass")  # [..., loop steps]: the looped family's gate
@@ -1379,6 +1438,16 @@ class ContinuousDecoder(_CoalescerBase):
             "counter", "pathway_generator_loop_passes_total", labels,
             self.pool_stats["loop_passes"],
         )
+        for kind, depth, rows in self._layout:
+            yield ("gauge", "pathway_generator_kv_rows", {**labels, "kind": kind}, depth * rows)
+        yield ("counter", "pathway_generator_join_splits_total", labels, self.pool_stats["join_splits"])
+        for phase in ("prefill", "decode"):
+            for family, stat in (
+                ("pathway_generator_expert_tokens_total", "expert_tokens"),
+                ("pathway_generator_experts_touched_total", "experts_touched"),
+                ("pathway_generator_expert_load_max_total", "expert_load_max"),
+            ):
+                yield ("counter", family, {**labels, "phase": phase}, self.pool_stats[f"{stat}_{phase}"])
         yield (
             "counter", "pathway_generator_stalled_seconds_total", labels,
             self._stalled_s,

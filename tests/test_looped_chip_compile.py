@@ -75,3 +75,60 @@ def test_join_fits_beside_weights_and_pool(one_chip, B, L, P):
     args = (params, pool, pool, sds((B,), jnp.int32), sds((B, L), jnp.int32), sds((B,), jnp.int32), prefix, prefix,
             sds((B, 2), jnp.uint32), sds((B,), jnp.float32))
     _fits(looped.slot_prefill(cfg, SLOTS, WIDTH, B, L, P).lower(*args).compile(), pool)
+
+
+# ---------------------------------------------------------------------------
+# the sparse-expert family (models/moe.py) at SmallThinker-21B-A3B's widths and the cell's 8 layers (ISSUE 32): 7.9 GB
+# of weights beside a pool of two kinds of rows.  What the compiler could have forced and these hold it to: the donated
+# pools (full layers' rows and window layers' rings) written in place by in-bounds scatters, the ring written whole
+# by a join and one row a step, and the grouped expert product reading each layer's experts where they lie (a scanned,
+# sliced stack would be a 0.75 GB copy a layer: 9 GB a step), the join handing the prefix tier its blocks itself
+# ---------------------------------------------------------------------------
+
+MOE_ARCH = dict(
+    vocab_size=151936, hidden_size=2560, num_attention_heads=28, num_key_value_heads=4, head_dim=128, moe_ffn_hidden_size=768,
+    moe_num_primary_experts=64, moe_num_active_primary_experts=6, num_hidden_layers=8, rope_layout=[0, 1, 1, 1] * 2,
+    sliding_window_layout=[0, 1, 1, 1] * 2, sliding_window_size=4096, rms_norm_eps=1e-6, rope_theta=1.5e6, max_position_embeddings=16384,
+)
+MOE_SLOTS, MOE_WIDTH = 12, 6784
+
+
+def _moe_shapes(one_chip, monkeypatch):
+    from pathway_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_KERNEL", "gmm")  # what a TPU process chooses by its backend
+    cfg = moe.MoeConfig.from_architecture(MOE_ARCH)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: moe.init_params(cfg, 0)))
+    pool = tuple(sds((MOE_SLOTS, depth, rows, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16) for _, depth, rows in cfg.pool_layout(MOE_WIDTH))
+    assert [p.shape for p in pool] == [(12, 2, 6784, 4, 128), (12, 6, 4096, 4, 128)]
+    return moe, cfg, sds, params, pool
+
+
+def _moe_fits(compiled, pool, temp_limit):
+    m = compiled.memory_analysis()
+    pool_bytes = 2 * sum(p.size * 2 for p in pool)  # keys and values: 0.94 GB
+    assert m.alias_size_in_bytes >= pool_bytes, "the pools are not updated in place"
+    assert m.temp_size_in_bytes < temp_limit, f"{m.temp_size_in_bytes / 1e9:.2f} GB of temporaries: a pool or an expert stack is being copied"
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
+
+
+def test_moe_step_chunk_fits_beside_weights_and_pool(one_chip, monkeypatch):
+    moe, cfg, sds, params, pool = _moe_shapes(one_chip, monkeypatch)
+    S = MOE_SLOTS
+    args = (params, pool, pool, sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.bool_), sds((S,), jnp.int32),
+            sds((S, 2), jnp.uint32), sds((S,), jnp.float32), sds((S,), jnp.int32), sds((), jnp.int32))
+    compiled = moe.slot_step(cfg, S, MOE_WIDTH, 8).lower(*args).compile()
+    _moe_fits(compiled, pool, 0.5e9)
+    assert "%gmm" in compiled.as_text(), "the grouped expert product is not the Pallas kernel"
+
+
+# the cold join is a second half-minute of the chip's compiler on every core, beside timing tests: run it with -m slow
+@pytest.mark.parametrize("L,P", [(6752, 32), pytest.param(6784, 0, marks=pytest.mark.slow)], ids=["one-row-warm", "one-row-cold"])
+def test_moe_join_fits_beside_weights_and_pool(one_chip, monkeypatch, L, P):
+    moe, cfg, sds, params, pool = _moe_shapes(one_chip, monkeypatch)
+    block = sds((cfg.cache_depth, BLOCK, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    prefix = ((block,) * (P // BLOCK),)
+    args = (params, pool, pool, sds((1,), jnp.int32), sds((1, L), jnp.int32), sds((1,), jnp.int32), prefix, prefix,
+            sds((1, 2), jnp.uint32), sds((1,), jnp.float32))
+    _moe_fits(moe.slot_prefill(cfg, MOE_SLOTS, MOE_WIDTH, 1, L, P, block=BLOCK).lower(*args).compile(), pool, 2.0e9)
