@@ -272,6 +272,14 @@ class TextGenerator:
                 f"bf16 cache (decode.kv_quant bf16); asked for spec_k={spec_k}, kv_quant={kv_quant}"
             )
 
+    def join_attention(self, L_sfx: int) -> str:
+        """What attends the prompt in a join of ``L_sfx`` suffix tokens:
+        "kernel" where a family's flash kernel takes it, "blocks" where its
+        query blocks do (models/moe.py ``prompt_attention``), "dense" where
+        the scores are one array."""
+        choose = getattr(self.family, "prompt_attention", None)
+        return choose(self.config, L_sfx) if choose is not None else "dense"
+
     def kv_pool_layout(self, T: int):
         """The slot pool's kinds of rows at width ``T`` as ``(kind, cache
         rows deep, rows a layer)``: one rectangle, or what the family states

@@ -17,8 +17,8 @@ What shares code with the looped family (models/looped.py): ``_rms``,
 ``_rope``, ``_mm``, ``_mm_t``, ``_head``, ``_sample``, ``token_stats`` and
 the ``append(carry, l, k, v)`` seam through which the full forward, the
 slot prefill and the slot step hand one block the rows it attends.  What
-is this family's own: the block (router, grouped-query attention in query
-blocks, the grouped expert product) and a slot pool of two kinds of rows:
+is this family's own: the block (router, grouped-query attention, the
+grouped expert product) and a slot pool of two kinds of rows:
 
 - full layers keep ``T`` rows a slot, a key at the row of its position;
 - window layers keep a ring of ``min(sliding_window_size, T)`` rows a slot:
@@ -40,10 +40,23 @@ of 113 ms): each layer's experts are arrays of their own, and the product
 is reached through a ``lax.switch`` on the period's index whose branches
 each hold one layer's, by reference.
 
+A prompt's attention (the join's and the full forward's) goes through one
+seam, ``_attend_prompt``: on a TPU a banded flash kernel (the Pallas splash
+attention that ships with JAX) that keeps scores, running maximum, sum and
+accumulator in VMEM and walks only the (queries x keys) blocks the mask
+leaves something of; elsewhere ``_attend_blocks``, query blocks whose
+scores pass through memory: the fallback and the tests' oracle.  A step's
+one query a lane is ``_attend_rows``.
+
 Precision: bfloat16 weights and matrix-product inputs with float32
 accumulation; the residual stream, norms, rotary angles and softmax in
 float32; router logits and the choice of experts in float32 at ``highest``
-precision from the float32 normed state.
+precision from the float32 normed state.  The queries reach the attention
+in float32 and are rounded to bfloat16 there, once: the kernel takes the
+softmax's scale into that rounding (``q * head_dim ** -0.5`` in float32,
+then bfloat16) and hands the values' product its float32 probabilities;
+the block path rounds ``q``, scales the float32 scores and rounds the
+probabilities to bfloat16.
 """
 
 from __future__ import annotations
@@ -60,7 +73,12 @@ from .looped import _head, _mm, _mm_t, _prefix_rows, _rms, _rope, _sample, token
 __all__ = ["MoeConfig", "forward", "init_params", "slot_prefill", "slot_step"]
 
 FAMILY = "sparse-expert"
-QUERY_BLOCK = 512  # queries a join attends at a time: scores are [B, heads, QUERY_BLOCK, keys], never [.., L, L]
+QUERY_BLOCK = 512  # queries the block path attends at a time: its scores are [B, heads, QUERY_BLOCK, keys], never [.., L, L]
+# a prompt's attention: None chooses by what the program observes (``prompt_attention``), "kernel" or "blocks" names
+# it: a test that compiles for a chip it does not have, or runs the kernel interpreted on the CPU
+ATTENTION_KERNEL = None
+ATTENTION_INTERPRET = False
+KERNEL_BLOCK = 512  # the kernel's tile, queries and keys alike: chosen on the chip (PERF.md section 6, ISSUE 33)
 # the grouped product's kernel: None chooses by the backend (the Pallas grouped matmul on a TPU, ``ragged_dot``
 # elsewhere); a test that compiles for a chip it does not have names it
 GROUPED_KERNEL = None
@@ -277,10 +295,60 @@ def _attend_rows(q, K, V, seen):
     return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(V.dtype), V, preferred_element_type=jnp.float32).reshape(S, 1, H, hd)
 
 
+def prompt_attention(cfg: "MoeConfig", L: int) -> str:
+    """What attends a prompt's ``L`` tokens: "kernel" on a TPU where the
+    heads fill the kernel's lanes and the prompt at least one of its tiles,
+    else "blocks" (the tests' tiny models, a short prompt, any other backend)."""
+    how = ATTENTION_KERNEL or ("kernel" if jax.default_backend() == "tpu" else "blocks")
+    return how if cfg.head_dim % 128 == 0 and L >= KERNEL_BLOCK else "blocks"
+
+
+def _attend_prompt(q, K, V, first_pos: int, window: int, how: str, block: int = 0):
+    """The seam: ``q [B, L, H, hd]`` (float32) at positions ``first_pos +
+    [0, L)`` over ``K`` / ``V [B, Tk, Hkv, hd]`` whose row index is the key's
+    position; a key is seen at or before the query's position and, on a
+    window layer, less than ``window`` before it.  ``how`` is
+    ``prompt_attention``'s word, ``block`` a test's own in place of the
+    path's.  Returns ``[B, L, H, hd]``."""
+    if how == "blocks":
+        return _attend_blocks(q.astype(K.dtype), K, V, first_pos, window, block or QUERY_BLOCK)
+    return _attend_kernel(q, K, V, first_pos, window, block or KERNEL_BLOCK, ATTENTION_INTERPRET)
+
+
+def _attend_kernel(q, K, V, first_pos: int, window: int, block: int, interpret: bool = False):
+    """The flash kernel (``splash_attention``, shipped with JAX): per query
+    head a grid over (query tile, key tile) of ``block`` each, the key/value
+    head's tiles shared by its group's query heads where they lie (no copy);
+    tiles the mask empties (above the diagonal; on a window layer further
+    than ``window`` behind) are never fetched, tiles it leaves whole are not
+    masked, and the mask of the tiles its edge crosses is computed in the
+    kernel from the query's position.  Scores, maximum, sum and accumulator
+    stay in VMEM, float32; the scores' product takes bfloat16 queries and
+    keys, the values' the probabilities as they are, float32, and the
+    bfloat16 values widened (the block path rounds the probabilities to
+    bfloat16 first: never more exact than this).  Lengths are padded to whole
+    tiles: a padded key lies after every real query, so the causal rule hides
+    it, and padded queries are cut off.  The kernel multiplies no scale, so
+    ``q`` is scaled in float32 before its one rounding.  Returns bfloat16,
+    what ``wo``'s product takes."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    B, L, H, hd = q.shape
+    Tk = K.shape[1]
+    Lp, Tp = -(-L // block) * block, -(-Tk // block) * block
+    seen = splash.LocalMask((Lp, Tp), (window - 1, 0), first_pos) if window else splash.CausalMask((Lp, Tp), first_pos)
+    kernel = splash.make_splash_mha_single_device(
+        splash.MultiHeadMask([seen] * H), block_sizes=splash.BlockSizes(block_q=block, block_kv=block), interpret=interpret
+    )
+    heads_first = lambda x, n: jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0), (0, 0))).transpose(0, 2, 1, 3)  # noqa: E731
+    out = jax.vmap(kernel)(heads_first((q * hd ** -0.5).astype(K.dtype), Lp), heads_first(K, Tp), heads_first(V, Tp))
+    return out.transpose(0, 2, 1, 3)[:, :L]
+
+
 def _attend_blocks(q, K, V, first_pos: int, window: int, block: int):
-    """``q [B, L, H, hd]`` at positions ``first_pos + [0, L)`` over ``K`` /
-    ``V [B, Tk, Hkv, hd]`` whose row index is the key's position, ``block``
-    queries at a time.  A key is seen at or before the query's position and,
+    """The block path: ``q [B, L, H, hd]`` at positions ``first_pos + [0,
+    L)`` over ``K`` / ``V [B, Tk, Hkv, hd]`` whose row index is the key's
+    position, ``block`` queries at a time.  A key is seen at or before the query's position and,
     on a window layer, less than ``window`` before it; a window layer's
     query block reads only the ``window + block`` keys that reach it."""
     B, L, H, hd = q.shape
@@ -311,9 +379,10 @@ def _block(cfg: MoeConfig, w, experts, l, j: int, x, q_pos, real, carry, append)
     """Layer ``l`` (the ``j``-th of its period: its kind is static) applied
     to the residual stream ``x [B, L, D]`` (float32).  ``append(carry, l, j,
     q, k, v)`` files this layer's keys and values and returns the attention's
-    result; ``experts(m, ids, gates)`` is the layer's expert branch; ``real
-    [B, L]`` says which tokens count in the expert load.  Returns the new
-    state, the carry, the load and the layer's keys and values."""
+    result (``q`` float32: the attention rounds it); ``experts(m, ids,
+    gates)`` is the layer's expert branch; ``real [B, L]`` says which tokens
+    count in the expert load.  Returns the new state, the carry, the load and
+    the layer's keys and values."""
     B, L, D = x.shape
     H, Hkv, hd, eps = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.rms_norm_eps
     a = _rms(x, w["in_norm"], eps)
@@ -324,7 +393,7 @@ def _block(cfg: MoeConfig, w, experts, l, j: int, x, q_pos, real, carry, append)
         q, k = _rope(q, q_pos, cfg.rope_theta), _rope(k, q_pos, cfg.rope_theta)
     v = _mm_t(a, w["wv"]).reshape(B, L, Hkv, hd)
     k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
-    carry, o = append(carry, l, j, q.astype(cfg.dtype), k, v)
+    carry, o = append(carry, l, j, q, k, v)
     x = x + _mm(o.reshape(B, L, H * hd), w["wo"])
     m = _rms(x, w["post_norm"], eps)
     f = experts(m.reshape(B * L, D), ids, gates)
@@ -367,10 +436,11 @@ def forward(cfg: MoeConfig, params, ids, held=None, query_block: int = 0):
     expert layer (and lets it feed the next layer: a share, not the model)."""
     B, L = ids.shape
     pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None, :], (B, L))
+    how = prompt_attention(cfg, L)
 
     def append(carry, l, j, q, k, v):
         window = cfg.sliding_window_size if cfg.sliding_window_layout[j] else 0
-        return carry, _attend_blocks(q, k, v, 0, window, query_block or QUERY_BLOCK)
+        return carry, _attend_prompt(q, k, v, 0, window, how, query_block)
 
     x = _stack(cfg, params, ids, pos, jnp.ones((B, L), bool), (), append, held)[0]
     return _head(params, x)
@@ -407,16 +477,17 @@ def slot_prefill(cfg: MoeConfig, S: int, T: int, B: int, L_sfx: int, P: int, blo
     window layer's ring is written whole, row ``r`` with the last position
     under ``n_len`` congruent to ``r`` (a cached prefix lands only where the
     window still holds it; pad positions past ``n_len`` never land).  The
-    suffix attends in query blocks, window layers only the band of keys that
-    reaches the block.  ``extra`` carries, beside the first token's stats,
-    the per-layer expert load over real tokens (a pad row counts as the row
-    it repeats) and, where the prefix tier's ``block`` is given,
+    suffix attends through ``_attend_prompt``, window layers only the band of
+    keys that reaches a query.  ``extra`` carries, beside the first token's
+    stats, the per-layer expert load over real tokens (a pad row counts as
+    the row it repeats) and, where the prefix tier's ``block`` is given,
     ``prompt_kv``: the suffix's keys and values cut into the tier's blocks,
     per row and block ``[layers, block, Hkv, hd]``, which stay on the device
     (a ring no longer holds a long prompt's first blocks, and a slice a
     block after the join cost the engine 0.45 s of dispatches a join).  The
     pools are donated and updated in place."""
     ring = cfg.pool_layout(T)[1][2]
+    how = prompt_attention(cfg, L_sfx)
 
     def run(params, pool_k, pool_v, slots, suffix_ids, n_len, prefix_k, prefix_v, rngs, temps):
         pos = jnp.broadcast_to((P + jnp.arange(L_sfx, dtype=jnp.int32))[None, :], (B, L_sfx))
@@ -442,7 +513,7 @@ def slot_prefill(cfg: MoeConfig, S: int, T: int, B: int, L_sfx: int, P: int, blo
                 full_k = full_k.at[rows, d, : P + L_sfx].set(k[twice], mode="promise_in_bounds")
                 full_v = full_v.at[rows, d, : P + L_sfx].set(v[twice], mode="promise_in_bounds")
                 window = 0
-            return ((full_k, ring_k), (full_v, ring_v)), _attend_blocks(q, k, v, P, window, QUERY_BLOCK)
+            return ((full_k, ring_k), (full_v, ring_v)), _attend_prompt(q, k, v, P, window, how)
 
         real = pos < n_len[:, None]
         x, (pool_k, pool_v), (touched, busiest), kept = _stack(
@@ -495,7 +566,7 @@ def slot_step(cfg: MoeConfig, S: int, T: int, chunk: int) -> Callable:
                     full_v = full_v.at[lanes, d, at_full].set(v[:, 0], mode="promise_in_bounds")
                     K, V, seen = full_k, full_v, seen_full
                 K, V = (jax.lax.dynamic_index_in_dim(x, d, axis=1, keepdims=False) for x in (K, V))
-                return ((full_k, ring_k), (full_v, ring_v)), _attend_rows(q, K, V, seen)
+                return ((full_k, ring_k), (full_v, ring_v)), _attend_rows(q.astype(K.dtype), K, V, seen)
 
             x, (pool_k, pool_v), (touched, busiest), _ = _stack(
                 cfg, params, tok[:, None], pos[:, None], live[:, None], (pool_k, pool_v), append

@@ -357,6 +357,7 @@ class ContinuousDecoder(_CoalescerBase):
             "tokens_forwarded": 0, # tokens run through the stack (prefilled + stepped)
             "joins": 0,            # join programs run
             "join_tokens": 0,      # tokens they carried (rows x padded suffix)
+            "join_tokens_kernel": 0,  # of those, tokens a flash kernel attended (generator.join_attention)
             "join_splits": 0,      # cohorts cut to JOIN_TOKEN_BUDGET
             # routed experts (models/moe.py), by phase: (token, expert) choices
             # made, experts that got a token summed over (program, layer), and
@@ -654,10 +655,11 @@ class ContinuousDecoder(_CoalescerBase):
         B = 1
         while B < n_real:
             B *= 4
+        attention = gen.join_attention(L_sfx)
         try:
             with observe.span(
                 "gen.prefill.dispatch", rows=n_real, batch=B,
-                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, **_S_PREFILL_DISPATCH,
+                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, attention=attention, **_S_PREFILL_DISPATCH,
             ):
                 t0 = time.perf_counter_ns()
                 suffix = np.zeros((B, L_sfx), np.int32)
@@ -715,6 +717,8 @@ class ContinuousDecoder(_CoalescerBase):
         pk_now, pv_now = self._pk, self._pv
         self.pool_stats["joins"] += 1
         self.pool_stats["join_tokens"] += B * L_sfx
+        if attention == "kernel":
+            self.pool_stats["join_tokens_kernel"] += B * L_sfx
         self._note_expert_load("prefill", extra, sum(rec["n"] - P for rec in grp))
         for j, rec in enumerate(grp):
             req = rec["req"]

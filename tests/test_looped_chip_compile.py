@@ -97,6 +97,7 @@ def _moe_shapes(one_chip, monkeypatch):
     from pathway_tpu.models import moe
 
     monkeypatch.setattr(moe, "GROUPED_KERNEL", "gmm")  # what a TPU process chooses by its backend
+    monkeypatch.setattr(moe, "ATTENTION_KERNEL", "kernel")
     cfg = moe.MoeConfig.from_architecture(MOE_ARCH)
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: moe.init_params(cfg, 0)))
@@ -131,4 +132,25 @@ def test_moe_join_fits_beside_weights_and_pool(one_chip, monkeypatch, L, P):
     prefix = ((block,) * (P // BLOCK),)
     args = (params, pool, pool, sds((1,), jnp.int32), sds((1, L), jnp.int32), sds((1,), jnp.int32), prefix, prefix,
             sds((1, 2), jnp.uint32), sds((1,), jnp.float32))
-    _moe_fits(moe.slot_prefill(cfg, MOE_SLOTS, MOE_WIDTH, 1, L, P, block=BLOCK).lower(*args).compile(), pool, 2.0e9)
+    compiled = moe.slot_prefill(cfg, MOE_SLOTS, MOE_WIDTH, 1, L, P, block=BLOCK).lower(*args).compile()
+    # 1.53 GB warm, 1.48 cold (the sorted pairs' rows and the expert products, not the attention: the block path read
+    # 1.52); a query block's float32 scores [4, 7, 512, 6784], 0.39 GB, on top of that would break the limit
+    _moe_fits(compiled, pool, 1.7e9)
+    assert "splash_mha_fwd" in compiled.as_text(), "the prompt's attention is not the flash kernel"
+
+
+@pytest.mark.parametrize("B,L,P", [(1, 6752, 32), (1, 6784, 0), (4, 2048, 32)], ids=["warm", "cold", "four-rows"])
+@pytest.mark.parametrize("window", [0, 4096], ids=["full-layer", "window-layer"])
+def test_moe_prompt_attention_keeps_its_scores_on_the_chip(one_chip, monkeypatch, window, B, L, P):
+    """The join's attention alone (ISSUE 33), at the cell's shapes: the flash kernel compiles for v5e (its tiles fit
+    VMEM) and leaves under a megabyte in HBM beside its operands and its result: a query block's float32 scores
+    ``[4, 7, 512, 6784]``, 0.39 GB, or a window layer's ``[4, 7, 512, 4608]``, 0.26 GB, would break the limit (the
+    block path reads 0.60 and 0.47 GB here).  Four rows of a shorter prompt, the widest batch of a join, go through
+    the same kernel under ``vmap``."""
+    moe, cfg, sds, _, _ = _moe_shapes(one_chip, monkeypatch)
+    q = sds((B, L, cfg.n_heads, cfg.head_dim), jnp.float32)
+    kv = sds((B, P + L, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    how = moe.prompt_attention(cfg, L)
+    compiled = jax.jit(lambda q, K, V: moe._attend_prompt(q, K, V, P, window, how)).lower(q, kv, kv).compile()
+    assert how == "kernel" and "splash_mha_fwd" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9

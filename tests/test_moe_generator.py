@@ -417,3 +417,93 @@ def test_warm_runs_every_join_shape_so_that_traffic_compiles_none(gen, monkeypat
     assert n >= 5 and set(gen._fns) == known
     joins = [k for k in known if k[0] == "slot_prefill" and k[1:3] == (5, 96)]
     assert joins and max(k[3] * k[4] for k in joins) <= 256 and (1, 96, 0) in {k[3:] for k in joins}
+
+
+# ---------------------------------------------------------------------------
+# the prompt's attention through the flash kernel (ISSUE 33): on a TPU ``_attend_prompt`` runs the Pallas splash
+# attention that ships with JAX; here it runs interpreted, at tiles of 128, against ``_attend_blocks``
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("L", [384, 300], ids=["whole-tiles", "a-ragged-tile"])
+@pytest.mark.parametrize("P", [0, 32], ids=["cold", "behind-a-prefix"])
+@pytest.mark.parametrize("window", [0, 100, 1024], ids=["full", "window", "window-wider-than-the-keys"])
+def test_the_flash_kernel_gives_the_block_paths_attention(window, P, L):
+    """Seven query heads to a key/value head of 128, queries at ``P + [0, L)`` over ``P + L`` keys: tiles above the
+    diagonal and behind the window are skipped, the edge's are masked, ragged lengths padded and cut."""
+    rng = np.random.default_rng(L + P + window)
+    q = jnp.asarray(rng.normal(size=(1, L, 14, 128)), jnp.float32)
+    K, V = (jnp.asarray(rng.normal(size=(1, P + L, 2, 128)), jnp.bfloat16) for _ in range(2))
+    want = moe._attend_blocks(q.astype(jnp.bfloat16), K, V, P, window, 512)
+    got = jax.jit(lambda q, K, V: moe._attend_kernel(q, K, V, P, window, 128, interpret=True))(q, K, V)
+    assert got.shape == want.shape == (1, L, 14, 128) and got.dtype == jnp.bfloat16
+    # bfloat16's last place at values up to 4, and the scale taken into q's rounding
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < 0.03 and float(jnp.abs(want).max()) > 1.0
+
+
+@pytest.mark.parametrize("named,head_dim,L,want", [
+    (None, 128, 4096, "blocks"), ("kernel", 128, 4096, "kernel"), ("kernel", 128, 511, "blocks"), ("kernel", 16, 4096, "blocks"),
+    ("blocks", 128, 4096, "blocks"),
+], ids=["this-backend-is-no-tpu", "named", "shorter-than-a-tile", "heads-narrower-than-the-lanes", "blocks-named"])
+def test_the_kernel_engages_by_what_the_program_observes(monkeypatch, named, head_dim, L, want):
+    monkeypatch.setattr(moe, "ATTENTION_KERNEL", named)
+    assert moe.prompt_attention(moe.MoeConfig.from_architecture({**ARCH, "head_dim": head_dim}), L) == want
+
+
+ARCH_128 = {**ARCH, "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 128, "num_hidden_layers": 4,
+            "rope_layout": [0, 1, 1, 1], "sliding_window_layout": [0, 1, 1, 1], "sliding_window_size": 96, "max_position_embeddings": 512}
+
+
+@pytest.fixture(scope="module")
+def served_both_ways():
+    """One prompt past the window, cold and then behind the shared block, through the pool with the kernel named
+    (interpreted, tiles of 128) and with the block path: results, pool counters and the join spans' words."""
+    rng = np.random.default_rng(21)
+    prompts = [SHARED + " " + _words(rng, n) for n in (150, 170)]
+    params = moe.init_params(moe.MoeConfig.from_architecture(ARCH_128), 7, scale=SCALE)
+    out = {}
+    was = moe.ATTENTION_KERNEL, moe.ATTENTION_INTERPRET, moe.KERNEL_BLOCK, decode.observe.span
+    try:
+        for how in ("kernel", "blocks"):
+            moe.ATTENTION_KERNEL, moe.ATTENTION_INTERPRET, moe.KERNEL_BLOCK = how, True, 128
+            spans = []
+
+            def spy(name, *a, _seen=spans, **kw):
+                _seen.append((name, kw))
+                return was[3](name, *a, **kw)
+
+            decode.observe.span = spy
+            g = TextGenerator(architecture=ARCH_128, params=params)
+            dec = ContinuousDecoder(g, slots=2, kv_width=320, step_bucket=4)
+            try:
+                results = [dec.submit(p, max_new_tokens=4)() for p in prompts]
+                stats = dict(dec.pool_stats)
+                short = dec.submit("a b c", max_new_tokens=2)()
+            finally:
+                dec.stop()
+            words = [kw["attention"] for name, kw in spans if name == "gen.prefill.dispatch"]
+            out[how] = dict(results=results, short=short, stats=stats, after_short=dict(dec.pool_stats), words=words)
+    finally:
+        moe.ATTENTION_KERNEL, moe.ATTENTION_INTERPRET, moe.KERNEL_BLOCK, decode.observe.span = was
+    return out
+
+
+def test_a_join_through_the_kernel_gives_the_block_paths_logits(served_both_ways):
+    """Cold (256 suffix tokens, two tiles) and warm behind the shared block (``first_pos`` 32): what each request
+    read off its logits agrees with the block path's as two servings of one prompt do, first token and steps."""
+    for a, b in zip(served_both_ways["kernel"]["results"], served_both_ways["blocks"]["results"]):
+        assert not a.degraded and not b.degraded and len(a.meta["token_ids"]) == 4
+        first = [np.abs(np.asarray(a.meta["logprobs"][key][0]) - np.asarray(b.meta["logprobs"][key][0])).max()
+                 for key in ("logit", "lse", "top_logits")]
+        assert max(first) < TOL and _alike(a, b)
+
+
+@pytest.mark.parametrize("how", ["kernel", "blocks"])
+def test_the_counter_says_how_many_join_tokens_the_kernel_attended(served_both_ways, how):
+    got = served_both_ways[how]
+    stats, after = got["stats"], got["after_short"]
+    assert stats["joins"] == 2 and stats["join_tokens"] == 2 * 256
+    assert stats["join_tokens_kernel"] == (stats["join_tokens"] if how == "kernel" else 0)
+    # a prompt shorter than a tile joins through the blocks whatever is named: the counter stands still
+    assert after["join_tokens"] == stats["join_tokens"] + 16 and after["join_tokens_kernel"] == stats["join_tokens_kernel"]
+    assert got["words"] == [how, how, "blocks"] and not got["short"].degraded
