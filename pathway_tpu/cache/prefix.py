@@ -21,6 +21,13 @@ prefills only the remainder.
 Only FULL blocks of real (non-pad) tokens are cached, and at least one
 real suffix token is always left for the prefill (the decode needs the
 last prompt position's hidden state, which K/V blocks do not carry).
+
+A model whose layers also hold recurrent state (models/hybrid.py) can
+restart a prompt only where that state is known, so a block's value may
+carry, after ``(k, v)``, a SNAPSHOT of the state at the block's end: further
+parts of the same entry, counted in its bytes, evicted with it.  Snapshots
+are filed only at the positions ``bucket_tokens`` can return; the generator
+cuts a match back to the last such block that carries one.
 """
 
 from __future__ import annotations
@@ -56,6 +63,8 @@ class PrefixKVCache:
         # prompt tokens served from cached K/V, computed = tokens the
         # prefill actually ran the trunk over
         self.stats_tokens = {"reused": 0, "computed": 0}
+        # state snapshots admitted beside blocks, and their bytes
+        self.stats_state = {"snapshots": 0, "bytes": 0}
         from .. import observe
 
         observe.register_provider(self)
@@ -83,6 +92,11 @@ class PrefixKVCache:
 
     def clear(self) -> None:
         self._tier.clear()
+
+    @staticmethod
+    def snapshot(value) -> tuple:
+        """The state snapshot a block's value carries after ``(k, v)``: its parts, or ``()``."""
+        return tuple(value[2:])
 
     # -- lookup --------------------------------------------------------------
     def cacheable_blocks(self, n_real: int) -> int:
@@ -163,7 +177,14 @@ class PrefixKVCache:
                 break
             if self._tier.put(keys[j], value, nbytes=nbytes, deadline=deadline):
                 admitted += 1
+                if self.snapshot(value):
+                    self.stats_state["snapshots"] += 1
+                    self.stats_state["bytes"] += sum(int(part.nbytes) for part in self.snapshot(value))
         return admitted
+
+    def state_bytes(self) -> int:
+        """Bytes of the state snapshots the tier holds now (a walk over its entries: for a read-out, not a hot path)."""
+        return sum(int(part.nbytes) for _, value, _ in self._tier.warm_entries() for part in self.snapshot(value))
 
     def note_prefill(self, reused: int, computed: int) -> None:
         self.stats_tokens["reused"] += int(reused)

@@ -45,7 +45,7 @@ from ..observe import hbm, profile
 from ..robust import retry_call
 from ._params import unbox as _unbox
 
-from . import looped, moe
+from . import hybrid, looped, moe
 from .looped import token_stats
 from .tokenizer import HashTokenizer
 from .transformer import (
@@ -151,14 +151,18 @@ class TextGenerator:
         params: Any = None,
     ):
         # ``architecture`` (a published config.json's keys) selects a decoder
-        # family at exactly those sizes, and its own keys say which: routed
-        # experts (``moe_num_primary_experts``; models/moe.py: grouped-query
+        # family at exactly those sizes, and its own keys say which: linear-
+        # attention layers among full ones (``linear_num_value_heads`` /
+        # ``full_attention_interval``; models/hybrid.py: gated delta-rule
+        # layers whose state lives beside the cache rows, routed experts of
+        # which this chip holds a range, a shared expert), routed experts
+        # (``moe_num_primary_experts``; models/moe.py: grouped-query
         # heads, per-layer rotary and window layouts, a router before
         # attention) or else the looped family (models/looped.py: RMSNorm
         # sandwich, rotary, gated SiLU, ``total_ut_steps`` passes over one
         # stack).  ``params`` hands its weights in.  Without it this is the
         # 4 x dimension LayerNorm/GELU trunk, as ever (``family`` None).
-        self.family = None if architecture is None else (moe if "moe_num_primary_experts" in architecture else looped)
+        self.family = None if architecture is None else self._family_of(architecture)
         if self.family is not None:
             self.config = self.family.Config.from_architecture(architecture, dtype)
             vocab_size, max_length = self.config.vocab_size, self.config.max_len
@@ -224,6 +228,12 @@ class TextGenerator:
         # HBM ledger (observe/hbm.py): parameter tree bytes
         hbm.track_params("generator", self)
 
+    @staticmethod
+    def _family_of(architecture: Mapping[str, Any]):
+        if "linear_num_value_heads" in architecture or "full_attention_interval" in architecture:
+            return hybrid
+        return moe if "moe_num_primary_experts" in architecture else looped
+
     def _family_params(self, params, seed: int):
         """A decoder family's weights: handed in (their tree is checked
         against the architecture's), else seeded random."""
@@ -246,7 +256,17 @@ class TextGenerator:
         """The cached-prefix operands of a slot prefill from, per row, the
         prefix cache's blocks ``(k, v)``: the trunk takes them stacked
         ``[B, depth, P, H, hd]``; the looped family takes the blocks as
-        they are, row by row (no copy)."""
+        they are, row by row (no copy); a family with state beside its rows
+        (models/hybrid.py) takes per row ``(rows, snapshot)``: the blocks'
+        rows joined ``[depth, P, H, hd]`` and the state filed with the last
+        block, ``None`` both where there is no prefix."""
+        if self.state_layout():
+            # 17 MB of rows a 4,096-token prefix here: joined once, a program of a hundred operands a row less
+            snapshot = self.kv_cache.snapshot if n_blk else None
+            return tuple(
+                tuple((jnp.concatenate([b[i] for b in row[:n_blk]], axis=1), snapshot(row[n_blk - 1])[i]) if n_blk else (None, None) for row in rows)
+                for i in (0, 1)
+            )
         if self.family is not None:
             return (
                 tuple(tuple(b[0] for b in row[:n_blk]) for row in rows),
@@ -295,6 +315,31 @@ class TextGenerator:
         heads = getattr(cfg, "n_kv_heads", cfg.n_heads)
         pools = tuple(jnp.zeros((slots, depth, rows, heads, cfg.head_dim), dtype) for _, depth, rows in self.kv_pool_layout(T))
         return pools[0] if len(pools) == 1 else pools
+
+    def state_layout(self):
+        """What a slot holds besides rows, whatever its length, as ``(kind,
+        layers, shape a layer, dtype)``: nothing, or what the family states
+        (models/hybrid.py: the delta rule's state and the convolution's rows)."""
+        layout = getattr(self.config, "state_layout", None)
+        return layout() if layout is not None else ()
+
+    def alloc_pool(self, slots: int, T: int, dtype):
+        """The slot pool: the two trees a join and a step are handed and
+        hand back (both donated).  Keys and values are allocated alike; a
+        family with state puts one kind beside each, ``[layers, slots, ...]``."""
+        pools = tuple(self.alloc_kv_pool(slots, T, dtype) for _ in range(2))
+        state = tuple(jnp.zeros((layers, slots) + shape, dt) for _, layers, shape, dt in self.state_layout())
+        return tuple(zip(pools, state)) if state else pools
+
+    def blank_snapshot(self):
+        """One sequence's state, all zeros: what ``warm`` files with a blank prefix's last block."""
+        return tuple(jnp.zeros((layers,) + shape, dt) for _, layers, shape, dt in self.state_layout())
+
+    def snapshot_positions(self, P: int, L_sfx: int) -> tuple:
+        """The positions inside a join's suffix whose state the join hands
+        back for the prefix tier (none without a tier, or without state)."""
+        where = getattr(self.family, "snapshot_positions", None)
+        return where(P, L_sfx, self.kv_cache.block) if where is not None and self.kv_cache is not None else ()
 
     # -- legacy full re-attend decode (parity reference / fallback) ----------
     def _decode_fn(self, B: int, L: int, steps: int):
@@ -501,8 +546,14 @@ class TextGenerator:
         matches = [
             self.kv_cache.match(ids[i], int(n_lens[i])) for i in range(n)
         ]
-        P = min((m[0] for m in matches), default=0)
-        return self.kv_cache.bucket_tokens(P), matches
+        P = self.kv_cache.bucket_tokens(min((m[0] for m in matches), default=0))
+        if self.state_layout():
+            # a join can start only where the tier holds the state: cut back
+            # to the last split point whose block carries a snapshot in every row
+            blk = self.kv_cache.block
+            while P and not all(self.kv_cache.snapshot(m[1][P // blk - 1]) for m in matches):
+                P = self.kv_cache.bucket_tokens(P - 1)
+        return P, matches
 
     # -- continuous-decode slot pool (serve/decode.py) -----------------------
     def kv_pool_scales(self):
@@ -1004,8 +1055,8 @@ class TextGenerator:
         L = ids.shape[1]
         T = -(-(L + max_new_tokens) // 64) * 64
         chunk = min(max_new_tokens, decode_step_bucket())
-        pool = self.alloc_kv_pool(b, T, cfg.dtype)
-        empty = ((),) * b
+        pool_k, pool_v = self.alloc_pool(b, T, cfg.dtype)
+        no_prefix = self.slot_prefix([()] * b, 0, None)
         pad = b - n
         with self._lock:
             prefill = self._slot_prefill_fn(b, T, b, L, 0)
@@ -1013,11 +1064,11 @@ class TextGenerator:
         rng0 = np.stack([np.asarray(jax.random.PRNGKey(seed))] * b)
         temps = jnp.full((b,), temperature, jnp.float32)
         pk, pv, tok, rngs, _ = retry_call(
-            "generator.dispatch", prefill, self.params, pool, jax.tree_util.tree_map(jnp.zeros_like, pool),
+            "generator.dispatch", prefill, self.params, pool_k, pool_v,
             # a pad row repeats row 0 (same slot, same ids): it writes the same values again
             jnp.asarray(np.r_[np.arange(n), np.zeros(pad)].astype(np.int32)),
             jnp.asarray(np.r_[ids, ids[:1].repeat(pad, 0)]), jnp.asarray(np.r_[n_lens, n_lens[:1].repeat(pad)]),
-            empty, empty, jnp.asarray(rng0), temps,
+            *no_prefix, jnp.asarray(rng0), temps,
         )
         # a blocking call by contract: each fetch below is booked
         record_fetch("generator_solo")

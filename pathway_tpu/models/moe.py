@@ -156,6 +156,8 @@ class MoeConfig:
     max_len = property(lambda self: self.max_position_embeddings)
     cache_depth = property(lambda self: self.num_hidden_layers)
     total_ut_steps = property(lambda self: 1)
+    n_experts = property(lambda self: self.moe_num_primary_experts)
+    experts_per_token = property(lambda self: self.moe_num_active_primary_experts)
 
     @property
     def period(self) -> int:
@@ -211,12 +213,13 @@ _EXPERT_LEAVES = ("wg", "wu", "wd")
 # ---------------------------------------------------------------------------
 
 
-def route(cfg: MoeConfig, a, router):
+def route(cfg, a, router):
     """The layer's experts for every token of ``a [N, D]`` (float32, the
-    normed input): ids ``[N, k]`` (ties broken as ``lax.top_k`` does: the
-    lower id first) and their weights, a softmax over the chosen logits."""
+    normed state the router reads): ids ``[N, k]`` (ties broken as
+    ``lax.top_k`` does: the lower id first) and their weights, a softmax over
+    the chosen logits.  ``cfg`` is any family's with routed experts."""
     r = jnp.dot(a, router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
-    top, ids = jax.lax.top_k(r, cfg.moe_num_active_primary_experts)
+    top, ids = jax.lax.top_k(r, cfg.experts_per_token)
     return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
@@ -249,28 +252,41 @@ def grouped_product(rows, w, sizes, kernel=None, interpret: bool = False):
     return out[:A] if pad else out
 
 
-def grouped_experts(cfg: MoeConfig, held, w, m, ids, gates):
+def grouped_experts(cfg, held, w, m, ids, gates, act=jax.nn.relu):
     """The expert layer as one grouped product: the ``N * k`` (token,
     expert) pairs sorted by expert, one grouped matrix product per weight
     (``grouped_product``) over one layer's experts ``w``: ``wg wu [E, D,
-    F]``, ``wd [E, F, D]``, the results weighted and summed back per token.
-    ``held`` (a bool per expert, or None) leaves the others' part of the
-    result out: what a chip that holds those experts computes.  Returns
-    ``[N, D]`` float32."""
+    F]``, ``wd [E, F, D]`` (gated by ``act``), the results weighted and
+    summed back per token.  ``held = (lo, hi)`` says that ``w`` holds only
+    the experts ``[lo, hi)`` of the ``cfg.n_experts`` the router chose among
+    (what one chip of an expert axis holds): pairs routed to the others sort
+    last, are computed by no one, and their part of the result is left out.
+    ``None``: every expert is here.  Returns ``[N, D]`` float32."""
     N, k = ids.shape
-    E = cfg.moe_num_primary_experts
+    n = cfg.n_experts if held is None else held[1] - held[0]
     flat = ids.reshape(-1)
-    sizes = jnp.zeros(E, jnp.int32).at[flat].add(1)
-    if held is not None:
-        gates = jnp.where(held[ids], gates, 0.0)
-    # whole row tiles for the kernel: pairs of no expert sort last, are computed by none and read by none
-    order = jnp.argsort(jnp.pad(flat, (0, -flat.shape[0] % _row_tile(flat.shape[0])), constant_values=E), stable=True)
+    if held is None:
+        sizes = jnp.zeros(n, jnp.int32).at[flat].add(1)
+    else:
+        mine = (ids >= held[0]) & (ids < held[1])
+        flat = jnp.where(mine, ids - held[0], n).reshape(-1)
+        sizes = jnp.zeros(n, jnp.int32).at[flat].add(1, mode="drop")
+    # whole row tiles for the kernel: pairs of no expert here sort last, are computed by none and read by none
+    order = jnp.argsort(jnp.pad(flat, (0, -flat.shape[0] % _row_tile(flat.shape[0])), constant_values=n), stable=True)
     x = m.astype(cfg.dtype)[jnp.minimum(order // k, N - 1)]
 
-    y = (jax.nn.relu(grouped_product(x, w["wg"], sizes)) * grouped_product(x, w["wu"], sizes)).astype(cfg.dtype)
+    y = (act(grouped_product(x, w["wg"], sizes)) * grouped_product(x, w["wu"], sizes)).astype(cfg.dtype)
     o = grouped_product(y, w["wd"], sizes)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))[: N * k]
-    return jnp.sum(o[back].reshape(N, k, -1) * gates[:, :, None], axis=1)
+    o = o[back].reshape(N, k, -1)
+    if held is not None:  # an absent expert's rows hold whatever the kernel found there
+        o = jnp.where(mine[:, :, None], o, 0.0)
+    return jnp.sum(o * gates[:, :, None], axis=1)
+
+
+def _expert_leaves(layers, l: int, held):
+    """Layer ``l``'s experts, by reference; a share's are cut from them (a copy: for a test, not for a cell)."""
+    return {n: layers[n][l] if held is None else layers[n][l][held[0] : held[1]] for n in _EXPERT_LEAVES}
 
 
 def expert_load(cfg: MoeConfig, ids, real):
@@ -416,7 +432,7 @@ def _stack(cfg: MoeConfig, params, ids, q_pos, real, carry, append, held=None, k
         loads, kvs = [], []
         for j in range(p):
             # the j-th layer of period i: one branch a period, each holding that layer's experts by reference
-            branches = [partial(grouped_experts, cfg, held, {n: layers[n][q * p + j] for n in _EXPERT_LEAVES}) for q in range(Ly // p)]
+            branches = [partial(grouped_experts, cfg, held, _expert_leaves(layers, q * p + j, held)) for q in range(Ly // p)]
             x, carry, load, kv = _block(
                 cfg, {n: a[j] for n, a in w.items()}, partial(jax.lax.switch, i, branches), i * p + j, j, x, q_pos, real, carry, append
             )
@@ -432,8 +448,8 @@ def _stack(cfg: MoeConfig, params, ids, q_pos, real, carry, append, held=None, k
 
 def forward(cfg: MoeConfig, params, ids, held=None, query_block: int = 0):
     """Full causal forward of ``ids [B, L]`` with no cache: logits ``[B, L,
-    V]`` (float32).  ``held`` computes only those experts' part of every
-    expert layer (and lets it feed the next layer: a share, not the model)."""
+    V]`` (float32).  ``held = (lo, hi)`` computes only those experts' part of
+    every expert layer (and lets it feed the next layer: a share, not the model)."""
     B, L = ids.shape
     pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None, :], (B, L))
     how = prompt_attention(cfg, L)
@@ -452,7 +468,7 @@ def expert_layer(cfg: MoeConfig, params, l: int, a, m, held=None):
     axis each compute, and what they must add up to."""
     layers = params["layers"]
     ids, gates = route(cfg, a, layers["router"][l])
-    return grouped_experts(cfg, held, {n: layers[n][l] for n in _EXPERT_LEAVES}, m, ids, gates)
+    return grouped_experts(cfg, held, _expert_leaves(layers, l, held), m, ids, gates)
 
 
 # ---------------------------------------------------------------------------

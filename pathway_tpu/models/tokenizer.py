@@ -39,9 +39,10 @@ def _width(longest: int, max_length: int, pad_to: int | None) -> int:
 def _width_table(max_length: int, pad_to: int | None) -> Tuple[np.ndarray, int]:
     """``_width`` by longest row, 0..max_length, and its largest entry: how
     the rule reaches the native batch call (no second copy of it in C++)."""
-    widths = np.array(
-        [_width(n, max_length, pad_to) for n in range(max_length + 1)], np.int64
-    )
+    # ``_width`` over 0..max_length at once: a generator's table has its whole context's 262,144 entries and a key
+    # for every answer budget, and a Python loop over them held the decode engine's thread 60-90 ms a new budget
+    n = np.arange(max_length + 1, dtype=np.int64)
+    widths = np.full_like(n, pad_to) if pad_to else np.minimum(max_length, (n + 15) // 16 * 16)
     widths.setflags(write=False)  # one table, handed to every caller
     return widths, int(widths.max())
 

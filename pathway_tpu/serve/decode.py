@@ -119,6 +119,8 @@ def decode_slots() -> int:
 _H_QUEUE_WAIT = observe.histogram("pathway_generator_queue_wait_seconds")
 _H_PREFILL = observe.histogram("pathway_generator_phase_seconds", phase="prefill")
 _H_STEP = observe.histogram("pathway_generator_phase_seconds", phase="step")
+# a join's round trip again, apart by where it started: behind a cached prefix (warm) or at token 0 (cold)
+_H_JOIN = {start: observe.histogram("pathway_generator_join_seconds", start=start) for start in ("warm", "cold")}
 # time-to-last-token per request, admission → completion at the waiter —
 # the series the SLO engine's decode_ttlt objective reads
 _H_TTLT = observe.histogram("pathway_generator_ttlt_seconds")
@@ -190,7 +192,7 @@ class _SlotState:
 
     __slots__ = (
         "req", "budget", "temperature", "seed", "eos", "tokens", "pos",
-        "left", "t_join_ns", "prompt_ids", "t_admit_ns", "t_first", "stats",
+        "left", "t_join_ns", "prompt_ids", "t_admit_ns", "t_first", "stats", "prefix",
     )
 
     def __init__(self, req, budget: int, temperature: float, seed: int, eos: int):
@@ -210,6 +212,7 @@ class _SlotState:
         self.t_admit_ns = 0  # when the join that took the request began
         self.t_first = 0.0
         self.stats: List[Tuple[np.ndarray, ...]] = []
+        self.prefix = 0  # prompt tokens its join took from the prefix tier
 
 
 def _spent_deadline() -> Deadline:
@@ -317,7 +320,12 @@ class ContinuousDecoder(_CoalescerBase):
         self._heads, self._head_dim = getattr(cfg, "n_kv_heads", cfg.n_heads), cfg.head_dim
         self._loop_steps = cfg.total_ut_steps
         # choices of expert one forwarded token makes (0: no routed experts)
-        self._expert_choices = cfg.n_layers * getattr(cfg, "moe_num_active_primary_experts", 0)
+        self._expert_choices = cfg.n_layers * getattr(cfg, "experts_per_token", 0)
+        # what a slot holds besides rows, whatever its length (models/hybrid.py: recurrent state)
+        self._state_layout = generator.state_layout()
+        self._state_bytes = {  # a slot's, by kind
+            kind: layers * int(np.prod(shape)) * np.dtype(dtype).itemsize for kind, layers, shape, dtype in self._state_layout
+        }
         if self._quant:
             # int8 pool + per-(layer, head, channel) stored scales — the
             # scales are derived from the generator's params off the
@@ -359,10 +367,17 @@ class ContinuousDecoder(_CoalescerBase):
             "join_tokens": 0,      # tokens they carried (rows x padded suffix)
             "join_tokens_kernel": 0,  # of those, tokens a flash kernel attended (generator.join_attention)
             "join_splits": 0,      # cohorts cut to JOIN_TOKEN_BUDGET
+            # recurrent state beside the rows (models/hybrid.py): prompt tokens a join
+            # skipped by starting from a restored snapshot, snapshots the prefix tier
+            # admitted from joins, and their bytes
+            "state_restored_tokens": 0,
+            "state_snapshots_admitted": 0,
+            "state_snapshot_bytes": 0,
             # routed experts (models/moe.py), by phase: (token, expert) choices
-            # made, experts that got a token summed over (program, layer), and
-            # the busiest expert's tokens summed likewise
-            **{f"{what}_{phase}": 0 for what in ("expert_tokens", "experts_touched", "expert_load_max")
+            # made, of those the pairs that fell to an expert held here (all of them,
+            # unless the family holds a range), experts that got a token summed over
+            # (program, layer), and the busiest expert's tokens summed likewise
+            **{f"{what}_{phase}": 0 for what in ("expert_tokens", "expert_pairs_held", "experts_touched", "expert_load_max")
                for phase in ("prefill", "decode")},
         }
         super().__init__(
@@ -383,8 +398,7 @@ class ContinuousDecoder(_CoalescerBase):
         )
 
     def _alloc_pool(self) -> None:
-        self._pk = self.generator.alloc_kv_pool(self.slots, self._T, self._pool_dtype)
-        self._pv = self.generator.alloc_kv_pool(self.slots, self._T, self._pool_dtype)
+        self._pk, self._pv = self.generator.alloc_pool(self.slots, self._T, self._pool_dtype)
 
     def _pool_buffers(self) -> List[Any]:
         import jax
@@ -404,6 +418,11 @@ class ContinuousDecoder(_CoalescerBase):
         (loop step, layer) row."""
         return 2 * self._depth * self._heads * self._head_dim * np.dtype(self._pool_dtype).itemsize
 
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a slot holds besides its rows, whatever its length: a
+        family's recurrent state, every layer that has one (0 without)."""
+        return sum(self._state_bytes.values())
+
     def hbm_bytes(self) -> int:
         """Device bytes of the persistent slot pool (K + V buffers +
         per-slot rng chains) — ``.nbytes`` metadata, never a sync."""
@@ -418,6 +437,9 @@ class ContinuousDecoder(_CoalescerBase):
         true footprint (int8 pool bytes + the tiny f32 scale arrays)
         next to the bf16 baseline's."""
         comp = {"kv_pool": self.hbm_bytes()}
+        if self._state_layout:  # of the pool's bytes, the part that is state and not rows
+            comp["state_pool"] = self.slots * self.state_bytes_per_slot()
+            comp["kv_pool"] -= comp["state_pool"]
         if self._quant:
             comp["kv_scales"] = sum(
                 int(getattr(s, "nbytes", 0))
@@ -656,10 +678,14 @@ class ContinuousDecoder(_CoalescerBase):
         while B < n_real:
             B *= 4
         attention = gen.join_attention(L_sfx)
+        # where this join's scan hands back its state for the prefix tier, and what it restores
+        snapshot_at = gen.snapshot_positions(P, L_sfx)
+        restored = P * n_real if self._state_layout else 0
         try:
             with observe.span(
                 "gen.prefill.dispatch", rows=n_real, batch=B,
-                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, attention=attention, **_S_PREFILL_DISPATCH,
+                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, attention=attention,
+                state_restored_tokens=restored, snapshots=len(snapshot_at) * n_real, **_S_PREFILL_DISPATCH,
             ):
                 t0 = time.perf_counter_ns()
                 suffix = np.zeros((B, L_sfx), np.int32)
@@ -685,10 +711,12 @@ class ContinuousDecoder(_CoalescerBase):
                 # the prompt's keys and values, where a family hands them to the
                 # prefix tier beside the pool: they stay on the device
                 prompt_kv = extra.pop("prompt_kv", None)
+                prompt_state = extra.pop("prompt_state", None)
                 extra = jax.device_get(extra)
             t_first = time.perf_counter()
             t1 = time.perf_counter_ns()
             _H_PREFILL.observe_ns(t1 - t0)
+            _H_JOIN["warm" if P else "cold"].observe_ns(t1 - t0)
             if n_live:
                 self._stalled_s += (t1 - t0) * 1e-9
         except Exception as exc:
@@ -720,6 +748,7 @@ class ContinuousDecoder(_CoalescerBase):
         if attention == "kernel":
             self.pool_stats["join_tokens_kernel"] += B * L_sfx
         self._note_expert_load("prefill", extra, sum(rec["n"] - P for rec in grp))
+        self.pool_stats["state_restored_tokens"] += restored
         for j, rec in enumerate(grp):
             req = rec["req"]
             slot = slots_real[j]
@@ -732,9 +761,13 @@ class ContinuousDecoder(_CoalescerBase):
                 matched, _blocks, chain = rec["match"]
                 if prompt_kv is not None:
                     # per row, the suffix's blocks as the program cut them:
-                    # block ``jb`` of the prompt is the suffix's ``jb - P / blk``-th
+                    # block ``jb`` of the prompt is the suffix's ``jb - P / blk``-th;
+                    # the block that ends where the join handed back its state carries it
                     def capture(jb, _j=j):
-                        return prompt_kv[0][_j][jb - P // blk], prompt_kv[1][_j][jb - P // blk]
+                        kv = prompt_kv[0][_j][jb - P // blk], prompt_kv[1][_j][jb - P // blk]
+                        if (jb + 1) * blk in snapshot_at:
+                            kv += prompt_state[_j][snapshot_at.index((jb + 1) * blk)]
+                        return kv
                 elif self._quant:
                     # int8 pool: captured blocks dequantize back to the
                     # cache's bf16 convention; a warm join re-quantizes
@@ -759,7 +792,11 @@ class ContinuousDecoder(_CoalescerBase):
                             pk_now[_s, :, jb * blk : (jb + 1) * blk],
                             pv_now[_s, :, jb * blk : (jb + 1) * blk],
                         )
-                gen.kv_cache.admit(chain, matched // blk, capture)
+                filed = dict(gen.kv_cache.stats_state)
+                with observe.span("gen.prefix.admit", blocks=len(chain) - matched // blk):
+                    gen.kv_cache.admit(chain, matched // blk, capture)
+                self.pool_stats["state_snapshots_admitted"] += gen.kv_cache.stats_state["snapshots"] - filed["snapshots"]
+                self.pool_stats["state_snapshot_bytes"] += gen.kv_cache.stats_state["bytes"] - filed["bytes"]
                 gen.kv_cache.note_prefill(reused=P, computed=rec["n"] - P)
             self.pool_stats["tokens_prefill"] += rec["n"] - P
             self.pool_stats["tokens_decode"] += 1
@@ -780,6 +817,7 @@ class ContinuousDecoder(_CoalescerBase):
             state.stats.append(tuple(extra[k][j : j + 1] for k in _STAT_KEYS))
             self._note_exit_mass(extra, (j,))
             state.pos = rec["n"]
+            state.prefix = P
             state.left = rec["steps"] - 1
             # host copy of the prompt ids: the draft miner's corpus
             state.prompt_ids = [int(t) for t in rec["ids"][0, : rec["n"]]]
@@ -820,7 +858,7 @@ class ContinuousDecoder(_CoalescerBase):
         blank = []  # a row of zero blocks: only ``warm`` has no real row to repeat
         if n_blk and not n_real:
             zero = jnp.zeros((self._depth, P // n_blk, self._heads, self._head_dim), gen.config.dtype)
-            blank = [(zero, zero)] * n_blk
+            blank = [(zero, zero)] * (n_blk - 1) + [(zero, zero, *gen.blank_snapshot())]
         prefix_k, prefix_v = gen.slot_prefix(
             fill(blocks, blank), n_blk, (self._depth, P, self._heads, self._head_dim)
         )
@@ -1016,6 +1054,8 @@ class ContinuousDecoder(_CoalescerBase):
         if touched is None:
             return
         self.pool_stats[f"expert_tokens_{phase}"] += tokens * self._expert_choices
+        held = extra.get("expert_pairs_held")  # a family that holds a range of the experts counts what fell to it
+        self.pool_stats[f"expert_pairs_held_{phase}"] += tokens * self._expert_choices if held is None else int(np.sum(held[:n_steps]))
         self.pool_stats[f"experts_touched_{phase}"] += int(np.sum(touched[:n_steps]))
         self.pool_stats[f"expert_load_max_{phase}"] += int(np.sum(extra["expert_load_max"][:n_steps]))
 
@@ -1277,7 +1317,7 @@ class ContinuousDecoder(_CoalescerBase):
     ) -> None:
         gen = self.generator
         meta: Dict[str, Any] = {
-            "tokens": len(st.tokens), "slot": slot,
+            "tokens": len(st.tokens), "slot": slot, "prefix_tokens": st.prefix,
             # what a caller may check or time (PERF.md): when the first token
             # reached the host (``time.perf_counter()``), the prompt's ids as
             # prefilled, the ids emitted, and per emitted token the float32
@@ -1444,10 +1484,15 @@ class ContinuousDecoder(_CoalescerBase):
         )
         for kind, depth, rows in self._layout:
             yield ("gauge", "pathway_generator_kv_rows", {**labels, "kind": kind}, depth * rows)
+        for kind, nbytes in (self._state_bytes or {"none": 0}).items():  # one `none` series of 0 without state
+            yield ("gauge", "pathway_generator_state_bytes", {**labels, "kind": kind}, nbytes)
+        for stat in ("state_restored_tokens", "state_snapshots_admitted", "state_snapshot_bytes"):
+            yield ("counter", f"pathway_generator_{stat}_total", labels, self.pool_stats[stat])
         yield ("counter", "pathway_generator_join_splits_total", labels, self.pool_stats["join_splits"])
         for phase in ("prefill", "decode"):
             for family, stat in (
                 ("pathway_generator_expert_tokens_total", "expert_tokens"),
+                ("pathway_generator_expert_pairs_held_total", "expert_pairs_held"),
                 ("pathway_generator_experts_touched_total", "experts_touched"),
                 ("pathway_generator_expert_load_max_total", "expert_load_max"),
             ):

@@ -154,3 +154,72 @@ def test_moe_prompt_attention_keeps_its_scores_on_the_chip(one_chip, monkeypatch
     compiled = jax.jit(lambda q, K, V: moe._attend_prompt(q, K, V, P, window, how)).lower(q, kv, kv).compile()
     assert how == "kernel" and "splash_mha_fwd" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+# ---------------------------------------------------------------------------
+# the hybrid family (models/hybrid.py) at Qwen3-Next-80B-A3B's widths, the cell's 8 layers and this chip's share (128
+# of 512 experts a layer, a quarter of the vocabulary; ISSUE 34): 7.3 GB of weights beside a pool of rows AND state.
+# What the compiler could have forced and these hold it to: the donated trees (full layers' rows; the delta rule's
+# float32 state and the convolution's rows) written in place, the held experts read where they lie, the chunked scan's
+# per-chunk operands (decay masks, the triangular system, its solution) and the float32 projections of 6,784 tokens
+# under a limit, the snapshots handed back without a copy of the scan's every state
+# ---------------------------------------------------------------------------
+
+HYBRID_ARCH = dict(
+    vocab_size=37984, hidden_size=2048, num_attention_heads=16, num_key_value_heads=2, head_dim=256, num_experts=512,
+    num_experts_per_tok=10, moe_intermediate_size=512, shared_expert_intermediate_size=512, full_attention_interval=4,
+    linear_conv_kernel_dim=4, linear_key_head_dim=128, linear_num_key_heads=16, linear_num_value_heads=32,
+    linear_value_head_dim=128, num_hidden_layers=8, partial_rotary_factor=0.25, rms_norm_eps=1e-6, rope_theta=1e7,
+    max_position_embeddings=262144, experts_held=[0, 128],
+)
+HYBRID_SLOTS, HYBRID_WIDTH = 12, 6784
+
+
+def _hybrid_shapes(one_chip, monkeypatch):
+    from pathway_tpu.models import hybrid, moe
+
+    monkeypatch.setattr(moe, "GROUPED_KERNEL", "gmm")  # what a TPU process chooses by its backend
+    monkeypatch.setattr(moe, "ATTENTION_KERNEL", "kernel")
+    cfg = hybrid.HybridConfig.from_architecture(HYBRID_ARCH)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: hybrid.init_params(cfg, 0)))
+    rows = sds((HYBRID_SLOTS, cfg.n_full, HYBRID_WIDTH, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    state = tuple(sds((layers, HYBRID_SLOTS) + shape, dtype) for _, layers, shape, dtype in cfg.state_layout())
+    assert rows.shape == (12, 2, 6784, 2, 256) and [s.shape for s in state] == [(6, 12, 32, 128, 128), (6, 12, 3, 8192)]
+    return hybrid, cfg, sds, params, ((rows, state[0]), (rows, state[1]))
+
+
+def _hybrid_fits(compiled, pools, temp_limit):
+    m = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(pools))  # 0.49 GB
+    assert m.alias_size_in_bytes >= pool_bytes, "the pools are not updated in place"
+    assert m.temp_size_in_bytes < temp_limit, f"{m.temp_size_in_bytes / 1e9:.2f} GB of temporaries"
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12.0e9  # beside a 3 GiB prefix tier
+
+
+def test_hybrid_step_chunk_fits_beside_weights_pool_and_tier(one_chip, monkeypatch):
+    hybrid, cfg, sds, params, pools = _hybrid_shapes(one_chip, monkeypatch)
+    S = HYBRID_SLOTS
+    args = (params, *pools, sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.bool_), sds((S,), jnp.int32),
+            sds((S, 2), jnp.uint32), sds((S,), jnp.float32), sds((S,), jnp.int32), sds((), jnp.int32))
+    compiled = hybrid.slot_step(cfg, S, HYBRID_WIDTH, 8).lower(*args).compile()
+    _hybrid_fits(compiled, pools, 0.5e9)
+    assert "%gmm" in compiled.as_text(), "the grouped expert product is not the Pallas kernel"
+
+
+# four rows and the cold join are most of a minute of the chip's compiler on every core each, beside timing tests: run them with -m slow
+@pytest.mark.parametrize("B,L,P,temporaries", [
+    (1, 512, 4096, 0.5e9), pytest.param(4, 2048, 4096, 3.0e9, marks=pytest.mark.slow), pytest.param(1, 6784, 0, 2.2e9, marks=pytest.mark.slow),
+], ids=["one-row-warm", "four-rows-warm", "one-row-cold"])
+def test_hybrid_join_fits_beside_weights_pool_and_tier(one_chip, monkeypatch, B, L, P, temporaries):
+    hybrid, cfg, sds, params, pools = _hybrid_shapes(one_chip, monkeypatch)
+    cached = sds((cfg.n_full, P, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    snapshot = tuple(sds((layers,) + shape, dtype) for _, layers, shape, dtype in cfg.state_layout())
+    prefix = tuple(tuple((cached, snapshot[i]) if P else (None, None) for _ in range(B)) for i in (0, 1))
+    args = (params, *pools, sds((B,), jnp.int32), sds((B, L), jnp.int32), sds((B,), jnp.int32), *prefix,
+            sds((B, 2), jnp.uint32), sds((B,), jnp.float32))
+    compiled = hybrid.slot_prefill(cfg, HYBRID_SLOTS, HYBRID_WIDTH, B, L, P, block=BLOCK).lower(*args).compile()
+    _hybrid_fits(compiled, pools, temporaries)  # 0.31 GB, 2.66 GB, 1.92 GB read
+    text = compiled.as_text()
+    assert "splash_mha_fwd" in text and "%gmm" in text, "the prompt's attention or the expert product is not its kernel"
+    assert len(hybrid.snapshot_positions(P, L, BLOCK)) == (0 if P else 7)

@@ -293,3 +293,19 @@ def test_submit_hands_the_compiled_function_the_bucket_padded_rows(
     assert ids.shape == (_bucket(n), want_ids.shape[1]) and ids.dtype == np.int32
     np.testing.assert_array_equal(ids, want_ids)
     np.testing.assert_array_equal(mask, want_mask)
+
+
+@pytest.mark.parametrize("max_length,pad_to", [(5, None), (128, None), (128, 64), (1000, 0), (262016, None)],
+                         ids=["tiny", "the-default", "a-fixed-width", "pad-to-zero-is-none", "a-generators-whole-context"])
+def test_the_width_table_is_the_width_rule_at_every_length(max_length, pad_to):
+    """The table the native batch call reads is built in one array expression
+    (a Python loop over a long-context generator's 262,144 entries held the
+    decode engine's thread 60-90 ms for every new answer budget: PERF.md
+    section 6, ISSUE 34); it is ``_width`` at every length all the same."""
+    from pathway_tpu.models import tokenizer as tok
+
+    tok._width_table.cache_clear()
+    widths, most = tok._width_table(max_length, pad_to)
+    at = np.unique(np.r_[np.arange(min(max_length, 100) + 1), np.linspace(0, max_length, 257).astype(np.int64)])
+    assert [int(widths[n]) for n in at] == [tok._width(int(n), max_length, pad_to) for n in at]
+    assert widths.shape == (max_length + 1,) and widths.dtype == np.int64 and most == int(widths.max()) and not widths.flags.writeable
