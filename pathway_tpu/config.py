@@ -166,7 +166,10 @@ def _knob(
 
 # serve tier
 _knob("serve.coalesce_us", "PATHWAY_SERVE_COALESCE_US", "float", 2000.0,
-      "scheduler coalescing window in µs (0 = no wait)",
+      "scheduler coalescing window in µs: the longest a batch is held, from "
+      "its oldest request, behind a full launch pipeline (two batches "
+      "launched, neither fetched yet); with room there a batch launches at "
+      "once (0 = never hold)",
       lo=0.0, hi=100_000.0, mutability=DYNAMIC)
 _knob("serve.max_batch", "PATHWAY_SERVE_MAX_BATCH", "int", 64,
       "cap on UNIQUE queries per coalesced device batch", lo=1, hi=4096)

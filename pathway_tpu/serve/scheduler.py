@@ -11,7 +11,7 @@ throughput is dominated by batch occupancy, not per-query FLOPs).
 One scheduler thread owns admission; the **future-handoff** contract
 splits the work so no thread ever blocks while holding the queue lock:
 
-    caller ──submit()──► admission queue ──window──► scheduler thread
+    caller ──submit()──► admission queue ───hold───► scheduler thread
                                                   │  sorted-unique pack,
                                                   │  ONE stage-1 dispatch
                                                   │  (batch N), then
@@ -19,11 +19,25 @@ splits the work so no thread ever blocks while holding the queue lock:
     caller ◄──ticket()─── per-request demux ◄─────┘
               (the WAITER performs the host fetch)
 
-- **Coalescing window**: ``PATHWAY_SERVE_COALESCE_US`` (default 2000)
-  anchored at the oldest queued request, capped by every queued
-  request's ``Deadline`` slack — the window never eats more than half
-  of any rider's remaining budget, and a request admitted with almost
-  no slack serves SOLO on its own thread instead of waiting at all.
+- **Launch when the pipeline has room, hold only behind a full one**: the
+  scheduler keeps the last two batches it launched (the double buffer
+  below).  While fewer than two of them are still unfetched it pops and
+  launches whatever is queued AT ONCE — a timer in front of an idle device
+  and a scheduler thread with room buys a denser batch nobody needs.  With
+  both places taken it HOLDS the queue, coalescing arrivals, until the
+  first of: a rider's fetch frees a place (``_Batch.result()`` notifies
+  the scheduler, once a batch), the **coalescing window** runs out
+  (``PATHWAY_SERVE_COALESCE_US``, default 2000, anchored at the oldest
+  queued request: the cap on a hold, not a wait), half of the budget any
+  queued request was admitted with does, or ``max_batch`` unique items
+  are queued.  Under load the queue forms behind the pipeline without any
+  timer, and the pipeline's own acknowledgements are the launch clock.
+  ``stats`` counts launches by what let them go (``launched_at_once``,
+  ``held_pipeline``, ``held_window``, ``held_full``; they sum to
+  ``batches``).  A request admitted with almost no slack serves SOLO on
+  its own thread instead of queueing at all.  An explicit ``window_us=``
+  pins the window and keeps the older meaning: every batch is held for it
+  from its oldest request, whatever is in flight.
 - **Double-buffered pipelining**: after dispatching batch N's stage 1
   the scheduler ``advance()``s batch N-1 (completing its stage-1 fetch
   and dispatching its stage-2 rerank), so stage 2 of N-1 overlaps
@@ -35,9 +49,9 @@ splits the work so no thread ever blocks while holding the queue lock:
   device batches (and therefore bit-identical results) regardless of
   thread arrival order.
 - **Tier-0 result cache** (``pathway_tpu/cache``): cross-WINDOW repeats
-  — the hot-head traffic in-window dedup cannot see — resolve before
+  — the hot-head traffic in-batch dedup cannot see — resolve before
   admission under ``(text, index generation, k)``: zero dispatches, no
-  window wait, generation-bump invalidation (see ``ServeScheduler``).
+  queueing, generation-bump invalidation (see ``ServeScheduler``).
 - **Degradation stays per-request**: a stage-1 failure inside a
   coalesced batch flags ``retrieval_failed`` on (and counts) each rider
   of that batch, and the next batch starts clean — one bad window never
@@ -92,8 +106,9 @@ __all__ = [
 
 def coalesce_window_s() -> float:
     """Coalescing window from ``serve.coalesce_us`` (default 2000 µs,
-    tuner-adjustable); 0 disables waiting (batches still form from
-    whatever is queued when the scheduler thread comes around)."""
+    tuner-adjustable): the longest a batch is held behind a full launch
+    pipeline, from its oldest request; 0 disables holding (batches still
+    form from whatever is queued when the scheduler thread comes around)."""
     return config.get("serve.coalesce_us") * 1e-6
 
 
@@ -119,8 +134,9 @@ _H_ADMISSION_WAIT = observe.histogram("pathway_serve_admission_wait_seconds")
 _H_TICKET_WAKE = observe.histogram("pathway_serve_ticket_wake_seconds")
 _LAUNCH = observe.serve_stage("launch")
 # the scheduler thread's wall time in seconds, by phase: nothing queued,
-# the deliberate coalescing wait, packing + launching a batch, advancing
-# the previous batch's pipeline
+# first request present → batch popped (a hold behind a full launch
+# pipeline; near zero where nothing is held), packing + launching a batch,
+# advancing the previous batch's pipeline
 _C_PHASE = {
     p: observe.counter("pathway_serve_dispatcher_seconds_total", phase=p)
     for p in ("idle", "window", "launch", "advance")
@@ -132,6 +148,15 @@ _C_SHED = {
     p: observe.counter("pathway_serve_shed_total", priority=p)
     for p in ("high", "normal", "low")
 }
+
+# the launch pipeline's depth: the batch whose stage 1 is on the device and
+# the one before it, which ``_run`` advances behind it
+_PIPELINE_DEPTH = 2
+# why a shared batch went (``stats`` keys; they sum to ``batches``): a place
+# in the pipeline was free / held behind a full pipeline until a fetch freed
+# one / until the window or half a rider's deadline ran out / ``max_batch``
+# unique items were queued
+_RELEASES = ("launched_at_once", "held_pipeline", "held_window", "held_full")
 
 
 def _shed_classes() -> frozenset:
@@ -146,8 +171,8 @@ class _Request:
     shared batch + this request's slot mapping into it."""
 
     __slots__ = (
-        "items", "k", "deadline", "t_enqueue_ns", "event", "batch", "slots",
-        "cache_store", "trace", "t_handoff_ns",
+        "items", "k", "deadline", "t_enqueue_ns", "hold_until_ns", "event",
+        "batch", "slots", "cache_store", "trace", "t_handoff_ns",
     )
 
     def __init__(self, items: Sequence[Any], k: Optional[int], deadline):
@@ -155,6 +180,12 @@ class _Request:
         self.k = k
         self.deadline = deadline
         self.t_enqueue_ns = time.perf_counter_ns()
+        # the latest a hold may keep this request queued: half of what its
+        # budget was at admission (None: no deadline)
+        self.hold_until_ns = (
+            None if deadline is None
+            else self.t_enqueue_ns + int(0.5e9 * deadline.remaining_s())
+        )
         # when the scheduler handed this request its batch (0: resolved
         # without a handoff, or its wake-up is already recorded)
         self.t_handoff_ns = 0
@@ -177,11 +208,11 @@ class _Batch:
     ever guards the once-only completion, never a queue."""
 
     __slots__ = ("_handle", "_n_items", "_n_requests", "_degrade_empty",
-                 "_lock", "_done", "_result", "_error", "_trace",
+                 "_lock", "_done", "_result", "_error", "_trace", "_fetched",
                  "t_launch_ns", "link")
 
     def __init__(self, handle, n_items: int, n_requests: int,
-                 degrade_empty: bool, trace_ctx=None):
+                 degrade_empty: bool, trace_ctx=None, fetched=None):
         self._handle = handle
         self._n_items = n_items
         self._n_requests = n_requests
@@ -195,6 +226,10 @@ class _Batch:
         # advance()/result() re-activate it because they run on other
         # threads (scheduler thread / whichever waiter fetches first)
         self._trace = trace_ctx
+        # the launching scheduler's condition (None: the batch holds no place
+        # in a launch pipeline): notified once, by the rider whose fetch
+        # completes the batch, so a launch held behind it can go
+        self._fetched = fetched
         # the launch bracket's start (0: none) and the riders' link-span
         # attrs: each rider records its own waits from them after it wakes
         self.t_launch_ns = 0
@@ -215,8 +250,10 @@ class _Batch:
             pass  # surfaces (once) at result() via the same handle
 
     def result(self) -> Any:
+        fetched = None
         with self._lock:
             if not self._done:
+                fetched = self._fetched
                 try:
                     with trace.use(self._trace):
                         self._result = self._handle()
@@ -248,6 +285,10 @@ class _Batch:
                     if self._error is not None:
                         flags = flags + ("error",)
                     trace.finish(self._trace, statuses=flags)
+        if fetched is not None:
+            # off the batch lock: the riders behind it go on to their demux
+            with fetched:
+                fetched.notify_all()
         if self._error is not None:
             raise self._error
         return self._result
@@ -320,9 +361,11 @@ class _CoalescerBase:
         autostart: bool = True,
     ):
         self.name = name or f"serve-{observe.next_id()}"
-        # window_us=None -> LIVE registry read per batch window: the
-        # online tuner (serve/tuner.py) adjusts ``serve.coalesce_us``
-        # while the batcher runs; an explicit window_us pins it
+        # window_us=None -> LIVE registry read per hold: the online tuner
+        # (serve/tuner.py) adjusts ``serve.coalesce_us`` while the batcher
+        # runs, and the window only bounds a hold behind a full launch
+        # pipeline; an explicit window_us pins it AND holds every batch for
+        # it from its oldest request, whatever is in flight
         self._window_pinned = window_us is not None
         self._window_s = (
             coalesce_window_s() if window_us is None else max(0.0, window_us) * 1e-6
@@ -334,12 +377,16 @@ class _CoalescerBase:
         self._queued_items = 0
         self._running = False
         self._thread: Optional[threading.Thread] = None
+        # the last batches this scheduler launched, newest last (scheduler
+        # thread only): those no rider has fetched yet take the places
+        self._pipeline: Deque[_Batch] = deque(maxlen=_PIPELINE_DEPTH)
         # plain-int stats; the flight recorder samples them at scrape
         # time through the provider registry (zero hot-path cost)
         self.stats: Dict[str, int] = {
             "requests": 0,       # admitted through the queue
             "solo": 0,           # deadline-preempted (or stopped) direct serves
-            "batches": 0,        # shared dispatches
+            "batches": 0,        # shared dispatches; by why they went:
+            **dict.fromkeys(_RELEASES, 0),
             "items": 0,          # queries/items admitted (pre-dedup)
             "items_dispatched": 0,  # unique items actually dispatched
             "dedup_hits": 0,     # duplicate items served from a shared slot
@@ -427,16 +474,16 @@ class _CoalescerBase:
 
     # -- scheduler thread ---------------------------------------------------
     def _run(self) -> None:
-        prev: Optional[_Batch] = None
         while True:
             reqs: Optional[List[_Request]] = None
             try:
-                reqs = self._collect()
-                if reqs is None:
+                collected = self._collect()
+                if collected is None:
                     return
+                reqs, release = collected
                 if reqs:
-                    batch = self._dispatch_batch(reqs)
-                    if prev is not None:
+                    batch = self._dispatch_batch(reqs, release=release)
+                    if self._pipeline:
                         # double buffering: stage-1 of the batch just
                         # dispatched is on the device queue; completing the
                         # PREVIOUS batch's stage 1 and dispatching its
@@ -444,12 +491,12 @@ class _CoalescerBase:
                         # where a waiter got there first, a wait where one
                         # is at it: an interval, not a profiler event)
                         t_advance = time.perf_counter_ns()
-                        prev.advance()
+                        self._pipeline[-1].advance()
                         observe.interval(
                             "sched.advance", t_advance, time.perf_counter_ns(),
                             counter=_C_PHASE["advance"], tree=None,
                         )
-                    prev = batch
+                    self._pipeline.append(batch)
             except Exception as exc:
                 # the scheduler thread must OUTLIVE any one bad batch:
                 # a dead thread would hang every queued and future ticket
@@ -478,11 +525,18 @@ class _CoalescerBase:
             # way this trace's outcome is known — keep it
             trace.finish(req.trace, statuses=("error",))
 
-    def _collect(self) -> Optional[List[_Request]]:
-        """Block until work arrives, hold the coalescing window open
-        (anchored at the oldest request, capped by every rider's
-        deadline slack and the batch query cap), then pop one batch.
-        Returns None when stopped and drained."""
+    def _collect(self) -> Optional[Tuple[List[_Request], str]]:
+        """Block until work arrives, then pop one batch and say why it went
+        (one of ``_RELEASES``).  With a place free in the launch pipeline
+        (fewer than ``_PIPELINE_DEPTH`` of the last launched batches still
+        unfetched) that is at once, with whatever is queued.  Behind a full
+        pipeline the batch is HELD, coalescing arrivals, until a rider's
+        fetch frees a place (it notifies ``_cond``), the coalescing window
+        anchored at the oldest request runs out, half of the budget a
+        queued request was admitted with does, or ``max_batch`` unique
+        items are queued.  A window pinned by the constructor holds from the
+        oldest request whatever is in flight.  Returns None when stopped
+        and drained."""
         with self._cond:
             if self._running and not self._queue:
                 with observe.span("sched.idle", counter=_C_PHASE["idle"]):
@@ -492,26 +546,42 @@ class _CoalescerBase:
                 return None  # stopped and drained
             with observe.span("sched.window", counter=_C_PHASE["window"]):
                 anchor_ns = self._queue[0].t_enqueue_ns
-                # the cap bounds UNIQUE items (the device batch shape), so
-                # the window stays open for hot duplicate-heavy traffic even
-                # when the raw queued count is past it — those riders dedup in
-                while (
-                    self._running
-                    and self._queued_unique_locked() < self._max_batch
-                ):
-                    now = time.perf_counter_ns()
+                release, held = "launched_at_once", False
+                while self._running:
+                    # the cap bounds UNIQUE items (the device batch shape), so
+                    # a hold stays open for hot duplicate-heavy traffic even
+                    # when the raw queued count is past it — those riders
+                    # dedup in (unique <= raw: most passes count nothing)
+                    if (
+                        self._queued_items >= self._max_batch
+                        and self._queued_unique_locked() >= self._max_batch
+                    ):
+                        release = "held_full"
+                        break
                     if not self._window_pinned:
                         self._window_s = coalesce_window_s()
-                    end_s = (anchor_ns - now) * 1e-9 + self._window_s
+                        if self._unfetched() < _PIPELINE_DEPTH:
+                            if held:
+                                release = "held_pipeline"
+                            break
+                    end_ns = anchor_ns + int(self._window_s * 1e9)
                     for r in self._queue:
-                        if r.deadline is not None:
-                            # the window never eats more than half of any
-                            # queued request's remaining budget
-                            end_s = min(end_s, 0.5 * r.deadline.remaining_s())
+                        if r.hold_until_ns is not None:
+                            # the hold never eats more than half of the
+                            # budget a queued request was admitted with
+                            end_ns = min(end_ns, r.hold_until_ns)
+                    end_s = (end_ns - time.perf_counter_ns()) * 1e-9
                     if end_s <= 0:
+                        release = "held_window"
                         break
+                    held = True
                     self._cond.wait(end_s)
-                return self._pop_batch_locked()
+                return self._pop_batch_locked(), release
+
+    def _unfetched(self) -> int:
+        """Places taken in the launch pipeline: the last launched batches
+        no rider has fetched yet (scheduler thread only)."""
+        return sum(1 for b in self._pipeline if not b._done)
 
     def _pop_batch(self) -> List[_Request]:
         with self._cond:
@@ -548,13 +618,17 @@ class _CoalescerBase:
         return take
 
     # -- dispatch -----------------------------------------------------------
-    def _dispatch_batch(self, reqs: List[_Request], solo: bool = False) -> _Batch:
+    def _dispatch_batch(
+        self, reqs: List[_Request], solo: bool = False,
+        release: str = "launched_at_once",
+    ) -> _Batch:
         """Pack one shared batch (sorted-unique items — deterministic
         composition regardless of arrival order), launch it, and hand
         the batch to every rider.  Every ticket resolves no matter what
         the launch does.  ``solo`` dispatches (deadline preemption,
         stopped scheduler) skip the coalescing counters — ``batches``
-        counts shared-window dispatches only."""
+        counts shared dispatches only, and ``release`` (what let this one
+        go, from ``_collect``) is counted beside it."""
         items: List[Any] = []
         total = sum(len(r.items) for r in reqs)
         error: Optional[BaseException] = None
@@ -605,11 +679,13 @@ class _CoalescerBase:
                 def handle(_exc: BaseException = error):
                     raise _exc
         batch = _Batch(
-            handle, len(items), len(reqs), self._degrade_empty, trace_ctx=bctx
+            handle, len(items), len(reqs), self._degrade_empty, trace_ctx=bctx,
+            fetched=None if solo else self._cond,
         )
         with self._qlock:
             if not solo:
                 self.stats["batches"] += 1
+                self.stats[release] += 1
             self.stats["items_dispatched"] += len(items)
             self.stats["dedup_hits"] += total - len(items)
         batch.t_launch_ns, t_now = launch.t0_ns, launch.t1_ns
@@ -655,6 +731,13 @@ class _CoalescerBase:
                 self.stats[mode],
             )
         yield ("counter", f"{self._metric_prefix}_batches_total", labels, self.stats["batches"])
+        for release in _RELEASES:
+            yield (
+                "counter",
+                f"{self._metric_prefix}_launches_total",
+                {**labels, "release": release},
+                self.stats[release],
+            )
         for kind, key in (
             ("admitted", "items"),
             ("dispatched", "items_dispatched"),

@@ -6,10 +6,11 @@ already *describes* the knob that would fix it; this module is the small
 controller that actually turns those knobs, bounded by the declarative
 registry (``pathway_tpu/config.py``):
 
-- ``serve.coalesce_us`` — from queue wait vs SLO headroom: a firing
+- ``serve.coalesce_us`` — from admission wait vs SLO headroom: a firing
   fast-burn window shrinks the coalescing window (latency pressure
   beats batching efficiency); ample headroom with the window binding
-  (mean wait ~= window) grows it.
+  (holds behind a full launch pipeline ended on it since the last tick,
+  and the mean wait for a launch ~= window) grows it.
 - ``decode.step_bucket`` — from decode-chunk occupancy: mostly-idle
   chunks halve the bucket, saturated chunks double it.
 - ``cache.{result,embed,kv}_bytes`` — from marginal hit rate: a tier
@@ -190,16 +191,29 @@ class Tuner:
         self._last[key] = current
         return current - prev
 
-    def _queue_wait_mean_s(self) -> Optional[float]:
-        """Mean serve queue wait over the last tick window (histogram
-        delta), or None when no requests landed."""
-        h = observe.histogram("pathway_serve_queue_wait_seconds")
+    def _admission_wait_mean_s(self) -> Optional[float]:
+        """Mean wait for the launch that took a request (enqueue → its
+        launch began: backlog + hold, WITHOUT the launch itself) over the
+        last tick window (histogram delta), or None when no requests
+        landed."""
+        h = observe.histogram("pathway_serve_admission_wait_seconds")
         _, sum_ns, n = h.snapshot()
-        d_sum = self._delta("qw_sum_ns", float(sum_ns))
-        d_n = self._delta("qw_n", float(n))
+        d_sum = self._delta("aw_sum_ns", float(sum_ns))
+        d_n = self._delta("aw_n", float(n))
         if d_n <= 0:
             return None
         return (d_sum / d_n) * 1e-9
+
+    def _held_window_launches(self) -> float:
+        """Shared batches, over every live scheduler, whose hold behind a
+        full launch pipeline ended on its cap (the coalescing window or
+        half a rider's budget): the only launches the window decides."""
+        return sum(
+            value
+            for name, value in observe.snapshot()["counters"].items()
+            if name.startswith("pathway_serve_queue_launches_total{")
+            and 'release="held_window"' in name
+        )
 
     def _slo_fast_burn(self) -> float:
         """Worst fast-window burn rate across latency SLOs (0 = all
@@ -234,7 +248,8 @@ class Tuner:
     # -- controllers ---------------------------------------------------------
     def _tune_coalesce(self) -> int:
         window_us = float(config.get("serve.coalesce_us"))
-        mean_wait = self._queue_wait_mean_s()
+        mean_wait = self._admission_wait_mean_s()
+        capped = self._delta("held_window", self._held_window_launches())
         burn = self._slo_fast_burn()
         if burn >= 1.0:
             # latency budget burning: the window is the one knob that
@@ -252,10 +267,12 @@ class Tuner:
             mean_wait is not None
             and burn < 0.5
             and window_us > 0
+            and capped > 0
             and mean_wait * 1e6 >= 0.5 * window_us
         ):
-            # headroom ample and the window itself is the binding wait:
-            # grow it for denser batches
+            # headroom ample and the window itself is the binding wait
+            # (where nothing is held on it, it decides no launch): grow it
+            # for denser batches
             return int(
                 self.propose(
                     "serve.coalesce_us", max(window_us * 1.3, 100.0), "up"

@@ -29,8 +29,9 @@ from pathway_tpu.ops import dispatch_counter
 from pathway_tpu.ops.knn import DeviceKnnIndex
 from pathway_tpu.ops.retrieve_rerank import RetrieveRerankPipeline
 from pathway_tpu.ops.serving import FusedEncodeSearch
-from pathway_tpu.robust import Deadline, RETRIEVAL_FAILED, inject
+from pathway_tpu.robust import Deadline, RETRIEVAL_FAILED, ServeResult, inject
 from pathway_tpu.serve import ServeScheduler, SharedBatcher
+from pathway_tpu.serve.scheduler import _RELEASES
 
 
 DOCS = {
@@ -497,6 +498,16 @@ def test_queue_metrics_reach_the_scrape_surface(stack):
         names = list(stats["counters"]) + list(stats["gauges"])
         joined = "\n".join(names)
         assert 'pathway_serve_queue_batches_total{scheduler="metrics-test"}' in names
+        # launches by what let them go: the pinned window released this one
+        launches = {
+            release: stats["counters"][
+                "pathway_serve_queue_launches_total"
+                f'{{release="{release}",scheduler="metrics-test"}}'
+            ]
+            for release in _RELEASES
+        }
+        assert launches == {**dict.fromkeys(_RELEASES, 0), "held_window": 1}
+        assert sum(launches.values()) == sched.stats["batches"] == 1
         assert "pathway_serve_queue_depth" in joined
         assert "pathway_serve_queue_requests_total" in joined
         assert "pathway_serve_queue_queries_total" in joined
@@ -568,3 +579,216 @@ def test_replica_submit_raise_releases_slot_exactly_once(stack):
         # the fleet still serves: the healthy replica answers
         clean = sched.serve([QUERIES[0]])
         assert clean and clean[0]
+
+
+# -- launch when the pipeline has room, hold only behind a full one (ISSUE 35)
+
+
+class _Gated:
+    """A serve target whose first ``hold_first`` batches cannot be fetched
+    until the test opens their gate: two of them fill the scheduler's launch
+    pipeline.  Over ``inner`` it forwards to a real pipeline; alone, a
+    batch's rows name its texts."""
+
+    def __init__(self, inner=None, hold_first=0):
+        self.inner = inner
+        self.hold_first = hold_first
+        self.batches = []  # each launched batch's texts, in launch order
+        self.launched_at = []
+        self.gates = []
+
+    def submit(self, texts, k=None, deadline=None):
+        gate = threading.Event()
+        if len(self.gates) >= self.hold_first:
+            gate.set()
+        self.launched_at.append(time.perf_counter())
+        self.batches.append(list(texts))
+        self.gates.append(gate)
+        handle = None if self.inner is None else self.inner.submit(
+            texts, k, deadline=deadline
+        )
+
+        def complete():
+            assert gate.wait(30), "the test never opened this batch's gate"
+            if handle is None:
+                return ServeResult([[(t, 1.0)] for t in texts])
+            return handle()
+
+        complete.advance = getattr(handle, "advance", lambda: None)
+        return complete
+
+    def open(self):
+        for gate in self.gates:
+            gate.set()
+
+
+def _wait_for(cond, what, timeout=10.0):
+    end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < end, f"timed out waiting for {what}"
+        time.sleep(0.001)
+
+
+@pytest.fixture
+def window_100ms():
+    """``serve.coalesce_us`` at its upper bound, live: an unpinned scheduler
+    that slept the window out would show it in every timing below."""
+    from pathway_tpu import config
+
+    config.set("serve.coalesce_us", 100_000)
+    yield 0.1
+    config.clear_override("serve.coalesce_us")
+
+
+def _fill_pipeline(sched, target):
+    """Two batches launched, neither fetchable: both places taken.  Returns
+    their tickets and an event set once the scheduler has SEEN the full
+    pipeline with a request queued (it checks right before it holds)."""
+    tickets = []
+    for i in range(2):
+        tickets.append(sched.submit([f"filler {i}"]))
+        # in its place once launched AND the batch before it advanced
+        _wait_for(lambda: len(sched._pipeline) == i + 1, f"launch {i + 1}")
+    assert sched.stats["launched_at_once"] == 2, sched.stats
+    seen_full = threading.Event()
+    unfetched = sched._unfetched
+
+    def spy():
+        n = unfetched()
+        if n >= 2:
+            seen_full.set()
+        return n
+
+    sched._unfetched = spy
+    return tickets, seen_full
+
+
+def test_lone_request_launches_without_waiting_out_the_window(window_100ms):
+    target = _Gated()
+    with ServeScheduler(target, result_cache=None) as sched:
+        t0 = time.perf_counter()
+        assert sched.serve(["alone"]) == [[("alone", 1.0)]]
+        waited = target.launched_at[0] - t0
+        assert sched.stats["launched_at_once"] == sched.stats["batches"] == 1
+    assert waited < 0.5 * window_100ms, waited
+
+
+def test_pinned_window_still_holds_a_lone_request():
+    """``window_us=`` keeps its meaning: a hold from the oldest request,
+    whatever is in flight (here: nothing)."""
+    target = _Gated()
+    with ServeScheduler(target, window_us=80_000, result_cache=None) as sched:
+        t0 = time.perf_counter()
+        assert sched.serve(["alone"]) == [[("alone", 1.0)]]
+        waited = target.launched_at[0] - t0
+        assert sched.stats["held_window"] == sched.stats["batches"] == 1
+        assert sched.stats["launched_at_once"] == 0
+    assert waited >= 0.07, waited
+
+
+@pytest.mark.parametrize("released_by", ["fetch", "window", "deadline", "full"])
+def test_full_pipeline_holds_until(window_100ms, released_by):
+    """Two batches unfetched: a third request is HELD, and goes when a rider
+    fetches one of them, when the window (the cap) or half the budget it was
+    admitted with runs out, or when ``max_batch`` unique items are queued."""
+    target = _Gated(hold_first=2)
+    with ServeScheduler(target, result_cache=None, max_batch=4) as sched:
+        fillers, seen_full = _fill_pipeline(sched, target)
+        deadline = None
+        if released_by == "deadline":
+            # a budget of 60 ms passes the solo rung only against a stale
+            # small window (the tuner grew it since): half of it, 30 ms,
+            # then ends the hold long before the 100 ms window would
+            sched._window_s = 0.001
+            deadline = Deadline.after_ms(60)
+        t0 = time.perf_counter()
+        held = [sched.submit(["held"], deadline=deadline)]
+        assert seen_full.wait(10), "the scheduler never looked at its pipeline"
+        assert len(target.batches) == 2  # held: not launched
+        if released_by == "fetch":
+            target.gates[0].set()
+            assert fillers[0]() == [[("filler 0", 1.0)]]
+        elif released_by == "full":
+            held += [sched.submit([f"more {i}"]) for i in range(3)]
+        _wait_for(lambda: len(target.batches) == 3, "the held launch")
+        waited = target.launched_at[2] - t0
+        target.open()
+        for t in fillers + held:
+            assert t()[0]
+        want = {
+            "fetch": "held_pipeline", "window": "held_window",
+            "deadline": "held_window", "full": "held_full",
+        }[released_by]
+        assert sched.stats[want] == 1, sched.stats
+        assert sched.stats["launched_at_once"] == 2, sched.stats
+        assert sum(sched.stats[r] for r in _RELEASES) == sched.stats["batches"] == 3
+    if released_by == "window":
+        assert waited >= 0.9 * window_100ms, waited
+    elif released_by == "deadline":
+        assert 0.025 <= waited < 0.9 * window_100ms, waited
+    elif released_by == "full":
+        assert target.batches[2] == ["held", "more 0", "more 1", "more 2"]
+
+
+def test_riders_arriving_during_a_hold_share_the_held_batch(stack, window_100ms):
+    """The held batch is composed as every batch is: the sorted unique texts
+    of its riders, so each rider's rows are bit-identical to a sequential
+    serve of that batch."""
+    pipe = _pipeline(stack)
+    riders = QUERIES[2:] + [QUERIES[3]]  # one duplicate
+    reference = pipe(sorted(set(riders)), k=5)
+    pipe(QUERIES[:1])  # the fillers' shape
+    target = _Gated(pipe, hold_first=2)
+    with ServeScheduler(target, result_cache=None) as sched:
+        fillers, seen_full = _fill_pipeline(sched, target)
+        held = [sched.submit([q], k=5) for q in riders]
+        assert seen_full.wait(10)
+        assert len(target.batches) == 2
+        target.open()
+        for t in fillers:
+            assert t()[0]
+        results = [t() for t in held]
+        assert target.batches[2] == sorted(set(riders))
+        assert sched.stats["held_pipeline"] == 1, sched.stats
+        assert sched.stats["dedup_hits"] == 1, sched.stats
+    order = sorted(set(riders))
+    for q, got in zip(riders, results):
+        assert got[0] == reference[order.index(q)]  # floats: bit-equal
+
+
+def test_launch_pipeline_under_contention():
+    """More riders than cores through an unpinned scheduler at a short switch
+    interval: every ticket gets its own row, no hold outlives its release
+    (the fetch's notify is never lost), and the launch counters add up."""
+    import sys
+
+    target = _Gated()
+    errors, n_threads, per_thread = [], 24, 40
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ServeScheduler(target, result_cache=None, max_batch=8) as sched:
+
+            def rider(i):
+                try:
+                    for j in range(per_thread):
+                        text = f"rider {i} request {j}"
+                        assert sched.serve([text]) == [[(text, 1.0)]]
+                except Exception as exc:
+                    errors.append(exc)
+
+            threads = [
+                threading.Thread(target=rider, args=(i,)) for i in range(n_threads)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads), "a rider hung"
+            assert not errors, errors
+            stats = dict(sched.stats)
+    finally:
+        sys.setswitchinterval(interval)
+    assert stats["requests"] == n_threads * per_thread
+    assert sum(stats[r] for r in _RELEASES) == stats["batches"] == len(target.batches)
+    assert sum(len(b) for b in target.batches) == n_threads * per_thread

@@ -163,16 +163,44 @@ def test_coalesce_shrinks_under_slo_burn(monkeypatch):
     assert config.get("serve.coalesce_us") < 2000.0
 
 
-def test_coalesce_grows_when_window_binds(monkeypatch):
+@pytest.mark.parametrize(
+    "series, holds_capped, grows",
+    [
+        # the wait for a launch ~= the full window, holds ended on it, no
+        # burn: the window binds
+        ("pathway_serve_admission_wait_seconds", 1, True),
+        # the same wait where no hold ended on the window (the queue formed
+        # behind a busy scheduler thread): the window decided no launch
+        ("pathway_serve_admission_wait_seconds", 0, False),
+        # queue_wait CONTAINS the launch: a 1.9 ms launch is not a window
+        ("pathway_serve_queue_wait_seconds", 1, False),
+    ],
+)
+def test_coalesce_grows_only_when_the_window_binds(
+    monkeypatch, series, holds_capped, grows
+):
+    class _Scheduler:
+        """The launch counters a scheduler's provider exports."""
+
+        held_window = 0
+
+        def observe_metrics(self):
+            yield (
+                "counter", "pathway_serve_queue_launches_total",
+                {"scheduler": "s", "release": "held_window"}, self.held_window,
+            )
+
+    monkeypatch.setattr(Tuner, "_slo_fast_burn", lambda self: 0.0)
+    sched = _Scheduler()
+    observe.register_provider(sched)
     t = Tuner(interval_s=0.01)
-    t.tick()  # baseline histogram snapshot
-    # mean queue wait ~= the full window with no burn: window binds
-    h = observe.histogram("pathway_serve_queue_wait_seconds")
+    t.tick()  # baseline snapshots
+    sched.held_window += holds_capped
+    h = observe.histogram(series)
     for _ in range(10):
         h.observe_s(0.0019)
-    monkeypatch.setattr(Tuner, "_slo_fast_burn", lambda self: 0.0)
     t.tick()
-    assert config.get("serve.coalesce_us") > 2000.0
+    assert (config.get("serve.coalesce_us") > 2000.0) == grows
 
 
 def test_profile_sample_backs_off_under_overhead(monkeypatch):
