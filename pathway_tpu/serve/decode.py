@@ -131,23 +131,48 @@ _H_TTLT = observe.histogram("pathway_generator_ttlt_seconds")
 _H_DRAFT_ACCEPT = observe.histogram(
     "pathway_generator_draft_accepted_tokens"
 )
-# the engine thread's host work, one span each (observe/spans.py), with the
-# thread-CPU twin: a join's tokenising and prefix walk, the prefill's and the
-# step chunk's call path up to the enqueue, and the fetch that waits for the
-# device
+# The engine thread's life, as a chain of sibling spans (observe/spans.py):
+# each starts where the one before it ended (``after=self._prev``; the glue
+# between two brackets, a lock's wait included, is the later one's), so the
+# phases of ``pathway_generator_engine_seconds_total`` sum to the thread's
+# wall time and every gap of the device trace lies under a label that names
+# one thing.  ``G{x}`` = ``pathway_generator_stage_seconds{stage=x}`` is a
+# bracket's wall series.  Siblings, not children, wherever a gap has to be
+# labelled: the trace reducer gives a gap to the event that overlaps it most,
+# so a child never beats its parent.
 _STAGE = "pathway_generator_stage_seconds"
-_STAGE_CPU = "pathway_generator_stage_cpu_seconds"
-_S_JOIN, _S_PREFILL_DISPATCH, _S_PREFILL_FETCH, _S_STEP_DISPATCH, _S_STEP_FETCH = (
-    {
-        "hist": observe.histogram(_STAGE, stage=stage),
-        "cpu_hist": observe.histogram(_STAGE_CPU, stage=stage),
-    }
-    for stage in ("join", "prefill_dispatch", "prefill_fetch", "step_dispatch", "step_fetch")
-)
-# a rider's waits, as the serve scheduler defines them: enqueue -> the join
-# that took it began; ticket resolved -> the rider is back from its wait
-_H_ADMISSION_WAIT = observe.histogram("pathway_generator_admission_wait_seconds")
-_H_TICKET_WAKE = observe.histogram("pathway_generator_ticket_wake_seconds")
+_ENGINE = "pathway_generator_engine_seconds_total"
+_E = {
+    phase: {"counter": observe.counter(_ENGINE, phase=phase), "hist": observe.histogram(_STAGE, stage=phase)}
+    for phase in (
+        "prefill_operands",  # slots popped, host arrays, pad rows, rng rows
+        "prefill_prefix",    # the cached blocks joined into the prefix operands
+        "prefill_call",      # compiled lookup, transfers, the jit call, the rng scatter
+        "prefill_fetch",     # first tokens and their statistics on the host
+        "prefill_settle",    # pool rebind, prefix capture, slot states
+        "step_operands",     # the chunk's host arrays and their transfers
+        "step_dispatch",     # the jit call
+        "step_fetch",        # the chunk's tokens on the host: the wait for the device
+        "step_replay",       # the per-slot replay, every leave and its resolve
+    )
+}
+# a request's tokenising and prefix walk (its series kept the name it had), and
+# the wait on ``_cond`` with nothing live and nothing queued (no series)
+_E["join_host"] = {"counter": observe.counter(_ENGINE, phase="join_host"), "hist": observe.histogram(_STAGE, stage="join")}
+_E["idle"] = {"counter": observe.counter(_ENGINE, phase="idle")}
+# brackets outside the chain: the parent of a join's operands / prefix / call
+# (it keeps the label and the series the ledger has history of; its three
+# children sum to it) and the prefix tier's admission inside ``settle``
+_S_PREFILL_DISPATCH = {"hist": observe.histogram(_STAGE, stage="prefill_dispatch")}
+_S_PREFIX_ADMIT = {"hist": observe.histogram(_STAGE, stage="prefix_admit")}
+# a request's life by phase, one observation each as it leaves the pool; the
+# six sum to its ``pathway_generator_ttlt_seconds`` observation by construction:
+# enqueue -> the join that took it began / -> its lane went live / live -> leave,
+# split into the engine's time in OTHER requests' joins (what chunked prefill
+# could give back), in step chunks, and the rest (replay, leaves, collect) /
+# resolved -> the rider is back from its wait
+_PHASES = ("slot_wait", "join", "stalled", "stepping", "host", "wake")
+_H_REQUEST = {phase: observe.histogram("pathway_generator_request_seconds", phase=phase) for phase in _PHASES}
 # what a request's meta carries of every emitted token (models/looped.py
 # ``token_stats``): its logit, the log-sum-exp, the top ids and logits
 _STAT_KEYS = ("logit", "lse", "top_ids", "top_logits")
@@ -192,7 +217,8 @@ class _SlotState:
 
     __slots__ = (
         "req", "budget", "temperature", "seed", "eos", "tokens", "pos",
-        "left", "t_join_ns", "prompt_ids", "t_admit_ns", "t_first", "stats", "prefix",
+        "left", "t_join_ns", "prompt_ids", "t_admit_ns", "stats", "prefix",
+        "t_live_ns", "join_ns0", "step_ns0",
     )
 
     def __init__(self, req, budget: int, temperature: float, seed: int, eos: int):
@@ -204,15 +230,22 @@ class _SlotState:
         self.tokens: List[int] = []
         self.pos = 0     # next K/V write position (= current length)
         self.left = 0    # decode-step tokens still allowed
-        self.t_join_ns = time.perf_counter_ns()
         # prompt token ids (host copy) — the n-gram draft mining corpus
         self.prompt_ids: List[int] = []
-        # when the first token reached the host (time.perf_counter()) and,
-        # per fetch, the emitted tokens' stats (``_STAT_KEYS`` arrays)
-        self.t_admit_ns = 0  # when the join that took the request began
-        self.t_first = 0.0
+        # when the join that took the request began, when its first token
+        # reached the host (both ``perf_counter_ns``) and, per fetch, the
+        # emitted tokens' stats (``_STAT_KEYS`` arrays)
+        self.t_admit_ns = 0
+        self.t_join_ns = 0
         self.stats: List[Tuple[np.ndarray, ...]] = []
         self.prefix = 0  # prompt tokens its join took from the prefix tier
+        # when its lane went live (its join's ``settle`` ended; 0: it left
+        # inside it, or the recorder is off) and the engine's running totals
+        # of join and step-chunk time at that instant: what ``_resolve``
+        # subtracts to split the request's life
+        self.t_live_ns = 0
+        self.join_ns0 = 0
+        self.step_ns0 = 0
 
 
 def _spent_deadline() -> Deadline:
@@ -338,6 +371,15 @@ class ContinuousDecoder(_CoalescerBase):
         self._pool_dtype = pool_dtype
         self._alloc_pool()
         self._rngs = jnp.zeros((self.slots, 2), jnp.uint32)
+        # the last span of the engine thread's chain (module comment at ``_E``)
+        self._prev: Any = None
+        # running totals from the chain's clock reads, in nanoseconds: the
+        # engine's time in joins (a request's host prep up to its group's
+        # ``settle``) and in step chunks (operands up to the fetch), and where
+        # the join now running is booked from
+        self._join_ns = 0
+        self._step_ns = 0
+        self._join_mark_ns = 0
         # seconds a prefill ran while decode lanes were live and waiting,
         # and the exit gate's mass per loop step over emitted tokens
         self._stalled_s = 0.0
@@ -525,9 +567,11 @@ class ContinuousDecoder(_CoalescerBase):
         so the step loop keeps advancing.  Returns None when stopped
         AND fully drained (queue empty, pool empty)."""
         with self._cond:
-            if not self._active:
-                while self._running and not self._queue:
-                    self._cond.wait(0.1)
+            if not self._active and self._running and not self._queue:
+                # under the lock it waits on: lock, then span
+                with observe.span("gen.idle", after=self._prev, **_E["idle"]) as self._prev:
+                    while self._running and not self._queue:
+                        self._cond.wait(0.1)
             if not self._queue and not self._active and not self._running:
                 return None
             free = len(self._free)
@@ -552,7 +596,9 @@ class ContinuousDecoder(_CoalescerBase):
         gen = self.generator
         cfg = gen.config
         ready: List[dict] = []
-        t_join_ns = time.perf_counter_ns()
+        # when this cohort's join began: a rider's slot wait ends here and the
+        # engine's join time is booked from here (``_prefill_group``)
+        t_join_ns = self._join_mark_ns = time.perf_counter_ns()
         for req in reqs:
             text, steps, temp, seed, eos = req.items[0]
             _H_QUEUE_WAIT.observe_ns(
@@ -580,7 +626,7 @@ class ContinuousDecoder(_CoalescerBase):
                         f"max_new_tokens={steps} leaves no prompt budget "
                         f"(max_len={cfg.max_len})"
                     )
-                with observe.span("gen.join", **_S_JOIN):
+                with observe.span("gen.join", after=self._prev, **_E["join_host"]) as self._prev:
                     ids, mask = gen.tokenizer.encode_batch(
                         [text], max_length=L_budget
                     )
@@ -664,7 +710,6 @@ class ContinuousDecoder(_CoalescerBase):
 
     def _prefill_group(self, grp: List[dict], L_sfx: int, P: int) -> None:
         import jax
-        import jax.numpy as jnp
 
         gen = self.generator
         cfg = gen.config
@@ -682,28 +727,22 @@ class ContinuousDecoder(_CoalescerBase):
         snapshot_at = gen.snapshot_positions(P, L_sfx)
         restored = P * n_real if self._state_layout else 0
         try:
+            # the parent of ``operands`` / ``prefix`` / ``call`` (``_dispatch_prefill``)
             with observe.span(
                 "gen.prefill.dispatch", rows=n_real, batch=B,
                 suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, attention=attention,
                 state_restored_tokens=restored, snapshots=len(snapshot_at) * n_real, **_S_PREFILL_DISPATCH,
-            ):
-                t0 = time.perf_counter_ns()
-                suffix = np.zeros((B, L_sfx), np.int32)
-                n_len = np.zeros(B, np.int32)
-                temps = np.zeros(B, np.float32)
-                seeds: List[int] = []
-                for j, rec in enumerate(grp):
-                    row = rec["ids"][0, P:]
-                    suffix[j, : row.shape[0]] = row
-                    n_len[j] = rec["n"]
-                    temps[j] = rec["temp"]
-                    seeds.append(rec["seed"])
-                blocks = [rec["match"][1] if P else [] for rec in grp]
+            ) as dispatch:
+                # the round trip runs from this bracket's start to the fetch's
+                # end, on the spans' own reads; with the recorder off the
+                # engine reads the clock itself, for ``_stalled_s`` and
+                # ``meta["t_first_token"]``
+                t0 = dispatch.t0_ns or time.perf_counter_ns()
                 pk, pv, toks, rngs_all, extra = self._dispatch_prefill(
-                    B, L_sfx, P, slots_real, suffix, n_len, temps, seeds, blocks,
+                    B, L_sfx, P, slots_real, grp,
                     self._batch_deadline([rec["req"] for rec in grp]),
                 )
-            with observe.span("gen.prefill.fetch", **_S_PREFILL_FETCH):
+            with observe.span("gen.prefill.fetch", after=self._prev, **_E["prefill_fetch"]) as self._prev:
                 # the prefill JOIN's one deliberate host fetch: first tokens
                 # (and what the program read off their logits) must reach the
                 # riders' tickets before the step loop takes over
@@ -713,8 +752,8 @@ class ContinuousDecoder(_CoalescerBase):
                 prompt_kv = extra.pop("prompt_kv", None)
                 prompt_state = extra.pop("prompt_state", None)
                 extra = jax.device_get(extra)
-            t_first = time.perf_counter()
-            t1 = time.perf_counter_ns()
+            fetch = self._prev
+            t1 = fetch.t1_ns or time.perf_counter_ns()
             _H_PREFILL.observe_ns(t1 - t0)
             _H_JOIN["warm" if P else "cold"].observe_ns(t1 - t0)
             if n_live:
@@ -741,146 +780,169 @@ class ContinuousDecoder(_CoalescerBase):
                     ),
                 )
             return
-        self._pk, self._pv, self._rngs = pk, pv, rngs_all
-        pk_now, pv_now = self._pk, self._pv
-        self.pool_stats["joins"] += 1
-        self.pool_stats["join_tokens"] += B * L_sfx
-        if attention == "kernel":
-            self.pool_stats["join_tokens_kernel"] += B * L_sfx
-        self._note_expert_load("prefill", extra, sum(rec["n"] - P for rec in grp))
-        self.pool_stats["state_restored_tokens"] += restored
-        for j, rec in enumerate(grp):
-            req = rec["req"]
-            slot = slots_real[j]
-            first = int(firsts[j])
-            # prefix capture: admit the prompt's uncached full blocks as
-            # async device slices of THIS pool version (functional
-            # arrays — later steps never mutate them)
-            if gen.kv_cache is not None:
-                blk = gen.kv_cache.block
-                matched, _blocks, chain = rec["match"]
-                if prompt_kv is not None:
-                    # per row, the suffix's blocks as the program cut them:
-                    # block ``jb`` of the prompt is the suffix's ``jb - P / blk``-th;
-                    # the block that ends where the join handed back its state carries it
-                    def capture(jb, _j=j):
-                        kv = prompt_kv[0][_j][jb - P // blk], prompt_kv[1][_j][jb - P // blk]
-                        if (jb + 1) * blk in snapshot_at:
-                            kv += prompt_state[_j][snapshot_at.index((jb + 1) * blk)]
-                        return kv
-                elif self._quant:
-                    # int8 pool: captured blocks dequantize back to the
-                    # cache's bf16 convention; a warm join re-quantizes
-                    # them — idempotent (ops/kv_quant.py), so warm pool
-                    # bytes match cold ones bit-for-bit
-                    from ..ops.kv_quant import dequantize_kv
+        joined: List[_SlotState] = []
+        with observe.span("gen.prefill.settle", after=fetch, **_E["prefill_settle"]) as self._prev:
+            self._pk, self._pv, self._rngs = pk, pv, rngs_all
+            pk_now, pv_now = self._pk, self._pv
+            self.pool_stats["joins"] += 1
+            self.pool_stats["join_tokens"] += B * L_sfx
+            if attention == "kernel":
+                self.pool_stats["join_tokens_kernel"] += B * L_sfx
+            self._note_expert_load("prefill", extra, sum(rec["n"] - P for rec in grp))
+            self.pool_stats["state_restored_tokens"] += restored
+            for j, rec in enumerate(grp):
+                req = rec["req"]
+                slot = slots_real[j]
+                first = int(firsts[j])
+                # prefix capture: admit the prompt's uncached full blocks as
+                # async device slices of THIS pool version (functional
+                # arrays — later steps never mutate them)
+                if gen.kv_cache is not None:
+                    blk = gen.kv_cache.block
+                    matched, _blocks, chain = rec["match"]
+                    if prompt_kv is not None:
+                        # per row, the suffix's blocks as the program cut them:
+                        # block ``jb`` of the prompt is the suffix's ``jb - P / blk``-th;
+                        # the block that ends where the join handed back its state carries it
+                        def capture(jb, _j=j):
+                            kv = prompt_kv[0][_j][jb - P // blk], prompt_kv[1][_j][jb - P // blk]
+                            if (jb + 1) * blk in snapshot_at:
+                                kv += prompt_state[_j][snapshot_at.index((jb + 1) * blk)]
+                            return kv
+                    elif self._quant:
+                        # int8 pool: captured blocks dequantize back to the
+                        # cache's bf16 convention; a warm join re-quantizes
+                        # them — idempotent (ops/kv_quant.py), so warm pool
+                        # bytes match cold ones bit-for-bit
+                        from ..ops.kv_quant import dequantize_kv
 
-                    def capture(jb, _s=slot):
-                        return (
-                            dequantize_kv(
+                        def capture(jb, _s=slot):
+                            return (
+                                dequantize_kv(
+                                    pk_now[_s, :, jb * blk : (jb + 1) * blk],
+                                    self._kscale, cfg.dtype,
+                                ),
+                                dequantize_kv(
+                                    pv_now[_s, :, jb * blk : (jb + 1) * blk],
+                                    self._vscale, cfg.dtype,
+                                ),
+                            )
+                    else:
+                        def capture(jb, _s=slot):
+                            return (
                                 pk_now[_s, :, jb * blk : (jb + 1) * blk],
-                                self._kscale, cfg.dtype,
-                            ),
-                            dequantize_kv(
                                 pv_now[_s, :, jb * blk : (jb + 1) * blk],
-                                self._vscale, cfg.dtype,
-                            ),
-                        )
-                else:
-                    def capture(jb, _s=slot):
-                        return (
-                            pk_now[_s, :, jb * blk : (jb + 1) * blk],
-                            pv_now[_s, :, jb * blk : (jb + 1) * blk],
-                        )
-                filed = dict(gen.kv_cache.stats_state)
-                with observe.span("gen.prefix.admit", blocks=len(chain) - matched // blk):
-                    gen.kv_cache.admit(chain, matched // blk, capture)
-                self.pool_stats["state_snapshots_admitted"] += gen.kv_cache.stats_state["snapshots"] - filed["snapshots"]
-                self.pool_stats["state_snapshot_bytes"] += gen.kv_cache.stats_state["bytes"] - filed["bytes"]
-                gen.kv_cache.note_prefill(reused=P, computed=rec["n"] - P)
-            self.pool_stats["tokens_prefill"] += rec["n"] - P
-            self.pool_stats["tokens_decode"] += 1
-            self.pool_stats["tokens_forwarded"] += rec["n"] - P
-            self.pool_stats["loop_passes"] += (rec["n"] - P) * self._loop_steps
-            if req.trace is not None:
-                req.trace.add_span(
-                    "decode.prefill", t0, t1,
-                    slot=slot, prefix_tokens=P, suffix_tokens=L_sfx,
-                    join_batch=n_real,
+                            )
+                    filed = dict(gen.kv_cache.stats_state)
+                    with observe.span("gen.prefix.admit", blocks=len(chain) - matched // blk, **_S_PREFIX_ADMIT):
+                        gen.kv_cache.admit(chain, matched // blk, capture)
+                    self.pool_stats["state_snapshots_admitted"] += gen.kv_cache.stats_state["snapshots"] - filed["snapshots"]
+                    self.pool_stats["state_snapshot_bytes"] += gen.kv_cache.stats_state["bytes"] - filed["bytes"]
+                    gen.kv_cache.note_prefill(reused=P, computed=rec["n"] - P)
+                self.pool_stats["tokens_prefill"] += rec["n"] - P
+                self.pool_stats["tokens_decode"] += 1
+                self.pool_stats["tokens_forwarded"] += rec["n"] - P
+                self.pool_stats["loop_passes"] += (rec["n"] - P) * self._loop_steps
+                if req.trace is not None:
+                    req.trace.add_span(
+                        "decode.prefill", t0, t1,
+                        slot=slot, prefix_tokens=P, suffix_tokens=L_sfx,
+                        join_batch=n_real,
+                    )
+                state = _SlotState(
+                    req, rec["steps"], rec["temp"], rec["seed"], rec["eos"]
                 )
-            state = _SlotState(
-                req, rec["steps"], rec["temp"], rec["seed"], rec["eos"]
-            )
-            state.tokens = [first]
-            state.t_first = t_first
-            state.t_admit_ns = rec["t_join_ns"]
-            state.stats.append(tuple(extra[k][j : j + 1] for k in _STAT_KEYS))
-            self._note_exit_mass(extra, (j,))
-            state.pos = rec["n"]
-            state.prefix = P
-            state.left = rec["steps"] - 1
-            # host copy of the prompt ids: the draft miner's corpus
-            state.prompt_ids = [int(t) for t in rec["ids"][0, : rec["n"]]]
-            self._active[slot] = state
-            if (rec["eos"] >= 0 and first == rec["eos"]) or state.left <= 0:
-                self._leave(slot, state)
+                state.tokens = [first]
+                state.t_join_ns = t1
+                state.t_admit_ns = rec["t_join_ns"]
+                state.stats.append(tuple(extra[k][j : j + 1] for k in _STAT_KEYS))
+                self._note_exit_mass(extra, (j,))
+                state.pos = rec["n"]
+                state.prefix = P
+                state.left = rec["steps"] - 1
+                # host copy of the prompt ids: the draft miner's corpus
+                state.prompt_ids = [int(t) for t in rec["ids"][0, : rec["n"]]]
+                self._active[slot] = state
+                joined.append(state)
+                if (rec["eos"] >= 0 and first == rec["eos"]) or state.left <= 0:
+                    self._leave(slot, state)
+        t_live = self._prev.t1_ns
+        if t_live:
+            # the group's lanes are live from here; what the engine spends in
+            # joins after this instant is what each of them is stalled by
+            self._join_ns += t_live - self._join_mark_ns
+            self._join_mark_ns = t_live
+            for state in joined:
+                state.t_live_ns, state.join_ns0, state.step_ns0 = t_live, self._join_ns, self._step_ns
 
-    def _dispatch_prefill(
-        self, B, L_sfx, P, slots_real, suffix, n_len, temps, seeds, blocks, deadline
-    ):
+    def _dispatch_prefill(self, B, L_sfx, P, slots_real, grp, deadline):
         """One prefill dispatch of ``B`` rows, the first ``len(slots_real)``
-        of them real: the compiled-fn lookup, the pad rows, the cached prefix
-        operands, the call.  Returns the new pools and rng chains
-        (the caller rebinds them once the fetch has succeeded), the first
-        tokens and what was read off their logits.  Every operand's shape
-        follows from ``(B, L_sfx, P)`` alone, so ``warm`` covers what a join
-        will run."""
+        of them real (``grp``, their records): the host arrays and pad rows,
+        the cached prefix operands, the compiled-fn lookup, the call; three
+        chained spans.  Returns the new pools and rng chains (the caller
+        rebinds them once the fetch has succeeded), the first tokens and what
+        was read off their logits.  Every operand's shape follows from
+        ``(B, L_sfx, P)`` alone, so ``warm`` covers what a join will run."""
         import jax
         import jax.numpy as jnp
 
         gen = self.generator
-        with gen._lock:
+        n_real = len(slots_real)
+        with observe.span("gen.prefill.operands", after=self._prev, **_E["prefill_operands"]) as self._prev:
+            suffix = np.zeros((B, L_sfx), np.int32)
+            n_len = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            for j, rec in enumerate(grp):
+                row = rec["ids"][0, P:]
+                suffix[j, : row.shape[0]] = row
+                n_len[j] = rec["n"]
+                temps[j] = rec["temp"]
+            # real rows first; a pad row repeats the first row (its slot, its
+            # ids, its prefix, its seed), so it writes the same values again and
+            # no slot index is ever out of bounds.  With no real row at all
+            # (``warm``) every row names slot 0 of the idle pool: a join rewrites
+            # every position it will attend before it attends it
+            fill = lambda rows, blank: list(rows) + [rows[0] if n_real else blank] * (B - n_real)  # noqa: E731
+            slot_rows = np.asarray(fill(slots_real, 0), np.int32)
+            if n_real:
+                suffix[n_real:], n_len[n_real:], temps[n_real:] = suffix[0], n_len[0], temps[0]
+            rng_rows = np.stack(fill([np.asarray(jax.random.PRNGKey(rec["seed"])) for rec in grp], np.zeros(2, np.uint32)))
+            n_blk = P // gen.kv_cache.block if P else 0
+            blank = []  # a row of zero blocks: only ``warm`` has no real row to repeat
+            if n_blk and not n_real:
+                zero = jnp.zeros((self._depth, P // n_blk, self._heads, self._head_dim), gen.config.dtype)
+                blank = [(zero, zero)] * (n_blk - 1) + [(zero, zero, *gen.blank_snapshot())]
+            blocks = fill([rec["match"][1] if P else [] for rec in grp], blank)
+        with observe.span("gen.prefill.prefix", after=self._prev, **_E["prefill_prefix"]) as self._prev:
+            prefix_k, prefix_v = gen.slot_prefix(
+                blocks, n_blk, (self._depth, P, self._heads, self._head_dim)
+            )
+        with gen._lock:  # between two brackets: no span encloses a lock
             fn = gen._slot_prefill_fn(
                 self.slots, self._T, B, L_sfx, P, self._quant
             )
-        # real rows first; a pad row repeats the first row (its slot, its
-        # ids, its prefix, its seed), so it writes the same values again and
-        # no slot index is ever out of bounds.  With no real row at all
-        # (``warm``) every row names slot 0 of the idle pool: a join rewrites
-        # every position it will attend before it attends it
-        n_real = len(slots_real)
-        fill = lambda rows, blank: list(rows) + [rows[0] if n_real else blank] * (B - n_real)  # noqa: E731
-        slot_arr = jnp.asarray(np.asarray(fill(slots_real, 0), np.int32))
-        if n_real:
-            suffix[n_real:], n_len[n_real:], temps[n_real:] = suffix[0], n_len[0], temps[0]
-        rng_rows = fill([np.asarray(jax.random.PRNGKey(seed)) for seed in seeds], np.zeros(2, np.uint32))
-        n_blk = P // gen.kv_cache.block if P else 0
-        blank = []  # a row of zero blocks: only ``warm`` has no real row to repeat
-        if n_blk and not n_real:
-            zero = jnp.zeros((self._depth, P // n_blk, self._heads, self._head_dim), gen.config.dtype)
-            blank = [(zero, zero)] * (n_blk - 1) + [(zero, zero, *gen.blank_snapshot())]
-        prefix_k, prefix_v = gen.slot_prefix(
-            fill(blocks, blank), n_blk, (self._depth, P, self._heads, self._head_dim)
-        )
-        sc = (self._kscale, self._vscale) if self._quant else ()
-        # pathway: allow(recompile-hazard): prefill shapes are bucketed upstream — the tokenizer pads suffix length to /16 multiples, the prefix split is a power-of-two block multiple (PrefixKVCache.bucket_tokens) and the join batch is a power-of-two bucket; the census test bounds the signature set
-        pk, pv, toks, rngs_out, extra = retry_call(
-            "generator.prefill",
-            fn,
-            gen.params,
-            self._pk,
-            self._pv,
-            slot_arr,
-            jnp.asarray(suffix),
-            jnp.asarray(n_len),
-            prefix_k,
-            prefix_v,
-            jnp.asarray(np.stack(rng_rows)),
-            jnp.asarray(temps),
-            *sc,
-            deadline=deadline,
-        )
-        return pk, pv, toks, self._rngs.at[slot_arr].set(rngs_out), extra
+        with observe.span("gen.prefill.call", after=self._prev, **_E["prefill_call"]) as self._prev:
+            slot_arr = jnp.asarray(slot_rows)
+            sc = (self._kscale, self._vscale) if self._quant else ()
+            # pathway: allow(recompile-hazard): prefill shapes are bucketed upstream — the tokenizer pads suffix length to /16 multiples, the prefix split is a power-of-two block multiple (PrefixKVCache.bucket_tokens) and the join batch is a power-of-two bucket; the census test bounds the signature set
+            pk, pv, toks, rngs_out, extra = retry_call(
+                "generator.prefill",
+                fn,
+                gen.params,
+                self._pk,
+                self._pv,
+                slot_arr,
+                jnp.asarray(suffix),
+                jnp.asarray(n_len),
+                prefix_k,
+                prefix_v,
+                jnp.asarray(rng_rows),
+                jnp.asarray(temps),
+                *sc,
+                deadline=deadline,
+            )
+            rngs = self._rngs.at[slot_arr].set(rngs_out)
+        return pk, pv, toks, rngs, extra
 
     def warm(self, prompt_tokens: Tuple[int, int], prefix_tokens: Sequence[int] = (0,)) -> int:
         """Compile and run once every program that joins and steps of prompts
@@ -907,13 +969,13 @@ class ContinuousDecoder(_CoalescerBase):
                 L_sfx = min(L_pad, self._T - P)
                 shapes.update((B, L_sfx, P) for B in sizes if B <= self._join_rows(L_sfx))
         for B, L_sfx, P in sorted(shapes):
-            pk, pv, toks, rngs, _ = self._dispatch_prefill(
-                B, L_sfx, P, [], np.zeros((B, L_sfx), np.int32), np.zeros(B, np.int32),
-                np.zeros(B, np.float32), [], [], None,
-            )
+            pk, pv, toks, rngs, _ = self._dispatch_prefill(B, L_sfx, P, [], [], None)
             np.asarray(toks)  # start-up, before traffic: wait for each program to have run
             self._pk, self._pv, self._rngs = pk, pv, rngs
         self._step_chunk()
+        # these brackets ran on the caller's thread beside the engine's idle
+        # one: the engine's chain does not go on from them
+        self._prev = None
         return len(shapes) + 1
 
     # -- decode step chunk ---------------------------------------------------
@@ -923,62 +985,63 @@ class ContinuousDecoder(_CoalescerBase):
 
         gen = self.generator
         S = self.slots
-        tok = np.zeros(S, np.int32)
-        pos = np.zeros(S, np.int32)
-        act = np.zeros(S, bool)
-        left = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        eos = np.full(S, -1, np.int32)
-        for s, st in self._active.items():
-            tok[s] = st.tokens[-1]
-            pos[s] = st.pos
-            act[s] = True
-            left[s] = st.left
-            temps[s] = st.temperature
-            eos[s] = st.eos
-        with gen._lock:
+        with gen._lock:  # ahead of the chunk's brackets: no span encloses a lock
             fn = gen._slot_step_fn(S, self._T, self.chunk, self._quant)
-        sc = (self._kscale, self._vscale) if self._quant else ()
-        n_steps = self.chunk
-        if gen.family is not None:
-            # a decoder family's step program takes the number of steps to
-            # run: no further than the nearest budget's end, so a lane leaves
-            # (and the next request joins) at the step it finishes
-            n_steps = min([self.chunk] + [st.left for st in self._active.values()])
-            sc = (jnp.int32(max(n_steps, 1)),)
-        deadline = self._batch_deadline(
-            [st.req for st in self._active.values()]
-        )
-        riders = [
-            st for st in self._active.values() if st.req.trace is not None
-        ]
         bctx = None
-        if riders:
-            # ONE batch trace per step chunk, linked from every traced
-            # rider — the decode-loop analog of the coalescing
-            # scheduler's batch/link-span pattern
-            bctx = trace.start_trace(
-                "decode.batch", deadline=deadline, kind="batch", sample=False
-            )
-            if bctx is not None:
-                bctx.annotate(
-                    engine=self.name, slots=len(self._active),
-                    chunk=self.chunk,
-                )
-        t0 = time.perf_counter_ns()
         try:
-            args = (
-                gen.params, self._pk, self._pv, jnp.asarray(tok),
-                jnp.asarray(pos), jnp.asarray(act), jnp.asarray(left),
-                self._rngs, jnp.asarray(temps), jnp.asarray(eos), *sc,
-            )
+            with observe.span("gen.step.operands", after=self._prev, **_E["step_operands"]) as self._prev:
+                tok = np.zeros(S, np.int32)
+                pos = np.zeros(S, np.int32)
+                act = np.zeros(S, bool)
+                left = np.zeros(S, np.int32)
+                temps = np.zeros(S, np.float32)
+                eos = np.full(S, -1, np.int32)
+                for s, st in self._active.items():
+                    tok[s] = st.tokens[-1]
+                    pos[s] = st.pos
+                    act[s] = True
+                    left[s] = st.left
+                    temps[s] = st.temperature
+                    eos[s] = st.eos
+                sc = (self._kscale, self._vscale) if self._quant else ()
+                n_steps = self.chunk
+                if gen.family is not None:
+                    # a decoder family's step program takes the number of steps to
+                    # run: no further than the nearest budget's end, so a lane leaves
+                    # (and the next request joins) at the step it finishes
+                    n_steps = min([self.chunk] + [st.left for st in self._active.values()])
+                    sc = (jnp.int32(max(n_steps, 1)),)
+                deadline = self._batch_deadline(
+                    [st.req for st in self._active.values()]
+                )
+                riders = [
+                    st for st in self._active.values() if st.req.trace is not None
+                ]
+                if riders:
+                    # ONE batch trace per step chunk, linked from every traced
+                    # rider — the decode-loop analog of the coalescing
+                    # scheduler's batch/link-span pattern
+                    bctx = trace.start_trace(
+                        "decode.batch", deadline=deadline, kind="batch", sample=False
+                    )
+                    if bctx is not None:
+                        bctx.annotate(
+                            engine=self.name, slots=len(self._active),
+                            chunk=self.chunk,
+                        )
+                args = (
+                    gen.params, self._pk, self._pv, jnp.asarray(tok),
+                    jnp.asarray(pos), jnp.asarray(act), jnp.asarray(left),
+                    self._rngs, jnp.asarray(temps), jnp.asarray(eos), *sc,
+                )
+            operands = self._prev
             with trace.use(bctx), observe.span(
-                "gen.step.dispatch", slots=len(self._active), **_S_STEP_DISPATCH
-            ):
+                "gen.step.dispatch", after=operands, slots=len(self._active), **_E["step_dispatch"]
+            ) as self._prev:
                 pk, pv, rngs, em, extra = retry_call(
                     "generator.step", fn, *args, deadline=deadline
                 )
-            with observe.span("gen.step.fetch", **_S_STEP_FETCH):
+            with observe.span("gen.step.fetch", after=self._prev, **_E["step_fetch"]) as self._prev:
                 em = np.asarray(em)  # [chunk, S]: the per-chunk host fetch  # pathway: allow(value-flow): THE decode-loop fetch — one deliberate sync per step chunk delivers every slot's tokens to its rider
                 extra = jax.device_get(extra)  # pathway: allow(value-flow): the same fetch's second half — what was read off those tokens' logits, outputs of the same program
         except Exception as exc:
@@ -993,48 +1056,52 @@ class ContinuousDecoder(_CoalescerBase):
             self._evict_all(exc)
             self._pool_lost()
             return
-        t1 = time.perf_counter_ns()
+        # the chunk's round trip, operands to fetched, on the spans' own reads
+        # (the recorder off: no read, and nothing that wants one)
+        t0, t1 = operands.t0_ns, self._prev.t1_ns
         _H_STEP.observe_ns(t1 - t0)
-        self._pk, self._pv, self._rngs = pk, pv, rngs
-        self.pool_stats["chunks"] += 1
-        self.pool_stats["steps"] += n_steps
-        self.pool_stats["occupancy_sum"] += len(self._active)
-        self._note_expert_load("decode", extra, n_steps * len(self._active), n_steps)
-        if bctx is not None:
-            trace.finish(bctx)
-            for st in riders:
-                rt = st.req.trace
-                rt.add_link(bctx.trace_id)
-                rt.add_span(
-                    "decode.step", t0, t1,
-                    linked_trace=bctx.trace_id, slots=len(self._active),
-                )
-        # replay the chunk per slot — the EXACT mask rules the kernel
-        # applied: a lane emits until EOS or budget, then freezes
-        leaves: List[Tuple[int, _SlotState, Tuple[str, ...]]] = []
-        for s, st in list(self._active.items()):
-            flags: Tuple[str, ...] = ()
-            finished = False
-            took = 0
-            for i in range(n_steps):
-                t = int(em[i, s])  # pathway: allow(value-flow): `em` was rebound to its HOST copy at the fetch above — the rule's name-level residency tracking cannot see the rebind; no device touch happens here
-                st.tokens.append(t)
-                took += 1
-                if (st.eos >= 0 and t == st.eos) or st.left - took <= 0:
+        self._step_ns += t1 - t0
+        with observe.span("gen.step.replay", after=self._prev, **_E["step_replay"]) as self._prev:
+            self._pk, self._pv, self._rngs = pk, pv, rngs
+            self.pool_stats["chunks"] += 1
+            self.pool_stats["steps"] += n_steps
+            self.pool_stats["occupancy_sum"] += len(self._active)
+            self._note_expert_load("decode", extra, n_steps * len(self._active), n_steps)
+            if bctx is not None:
+                trace.finish(bctx)
+                for st in riders:
+                    rt = st.req.trace
+                    rt.add_link(bctx.trace_id)
+                    rt.add_span(
+                        "decode.step", t0, t1,
+                        linked_trace=bctx.trace_id, slots=len(self._active),
+                    )
+            # replay the chunk per slot — the EXACT mask rules the kernel
+            # applied: a lane emits until EOS or budget, then freezes
+            leaves: List[Tuple[int, _SlotState, Tuple[str, ...]]] = []
+            for s, st in list(self._active.items()):
+                flags: Tuple[str, ...] = ()
+                finished = False
+                took = 0
+                for i in range(n_steps):
+                    t = int(em[i, s])  # pathway: allow(value-flow): `em` was rebound to its HOST copy at the fetch above — the rule's name-level residency tracking cannot see the rebind; no device touch happens here
+                    st.tokens.append(t)
+                    took += 1
+                    if (st.eos >= 0 and t == st.eos) or st.left - took <= 0:
+                        finished = True
+                        break
+                self._took(st, s, took, extra)
+                if not finished and (
+                    st.req.deadline is not None and st.req.deadline.expired()
+                ):
+                    # mid-decode deadline: the request leaves with its
+                    # tokens so far, flagged — its slot frees for the queue
                     finished = True
-                    break
-            self._took(st, s, took, extra)
-            if not finished and (
-                st.req.deadline is not None and st.req.deadline.expired()
-            ):
-                # mid-decode deadline: the request leaves with its
-                # tokens so far, flagged — its slot frees for the queue
-                finished = True
-                flags = (EXTRACTIVE_ANSWER,)
-            if finished:
-                leaves.append((s, st, flags))
-        for s, st, flags in leaves:
-            self._leave(s, st, flags=flags)
+                    flags = (EXTRACTIVE_ANSWER,)
+                if finished:
+                    leaves.append((s, st, flags))
+            for s, st, flags in leaves:
+                self._leave(s, st, flags=flags)
 
     def _took(self, st: _SlotState, lane: int, n: int, extra) -> None:
         """Book the first ``n`` tokens of a fetched chunk to a lane."""
@@ -1158,90 +1225,98 @@ class ContinuousDecoder(_CoalescerBase):
         gen = self.generator
         S = self.slots
         k = self.spec_k
-        toks = np.zeros((S, k), np.int32)
-        pos = np.zeros(S, np.int32)
-        act = np.zeros(S, bool)
-        left = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        eos = np.full(S, -1, np.int32)
-        src_of: Dict[int, str] = {}
-        need_trunk: List[int] = []
-        for s, st in self._active.items():
-            toks[s, 0] = st.tokens[-1]
-            pos[s] = st.pos
-            act[s] = True
-            left[s] = st.left
-            temps[s] = st.temperature
-            eos[s] = st.eos
-            mined: List[int] = []
-            if self.draft_source in ("auto", "ngram"):
-                hist = st.prompt_ids + st.tokens
-                mined = self._mine_ngram(hist, k - 1)
-                pooled = self._mine_corpus(hist, k - 1)
-                if len(pooled) > len(mined):
-                    mined = pooled
-            if mined:
-                toks[s, 1 : 1 + len(mined)] = mined
-                src_of[s] = "ngram"
-            elif self.draft_source in ("auto", "trunk"):
-                need_trunk.append(s)
-                src_of[s] = "trunk"
-            else:
-                src_of[s] = "none"
-        sc = (self._kscale, self._vscale) if self._quant else ()
-        with gen._lock:
+        with gen._lock:  # ahead of the round's brackets: no span encloses a lock
             vfn = gen._slot_verify_fn(S, self._T, k, self._quant)
             dfn = gen._slot_draft_fn(
                 S, self._T, k - 1, self._draft_layers, self._quant
             )
-        deadline = self._batch_deadline(
-            [st.req for st in self._active.values()]
-        )
-        riders = [
-            st for st in self._active.values() if st.req.trace is not None
-        ]
         bctx = None
-        if riders:
-            bctx = trace.start_trace(
-                "decode.batch", deadline=deadline, kind="batch", sample=False
-            )
-            if bctx is not None:
-                bctx.annotate(
-                    engine=self.name, slots=len(self._active),
-                    spec_k=k, spec=True,
-                )
-        t0 = time.perf_counter_ns()
+        # the step chunk's four brackets: the drafts mined and the operands
+        # built / draft and verify dispatched (a trunk draft's fetch, which
+        # seeds the verify, inside) / the accepted tokens fetched / replayed
         try:
-            # draft phase: ONE reduced-trunk dispatch covers every lane
-            # that needs it; pure-ngram rounds still fire the chaos site
-            # so a faulted draft path degrades ALL speculation uniformly
-            if need_trunk:
-                # pathway: allow(recompile-hazard): every operand shape is static per engine — [S] / [S, k] with S = the pool size and k = spec_k, fixed at construction; the census test pins the signature count
-                dr = retry_call(
-                    "generator.draft",
-                    dfn,
-                    gen.params, self._pk, self._pv,
-                    jnp.asarray(toks[:, 0]), jnp.asarray(pos),
-                    jnp.asarray(act), *sc,
-                    deadline=deadline,
+            with observe.span("gen.step.operands", after=self._prev, **_E["step_operands"]) as self._prev:
+                toks = np.zeros((S, k), np.int32)
+                pos = np.zeros(S, np.int32)
+                act = np.zeros(S, bool)
+                left = np.zeros(S, np.int32)
+                temps = np.zeros(S, np.float32)
+                eos = np.full(S, -1, np.int32)
+                src_of: Dict[int, str] = {}
+                need_trunk: List[int] = []
+                for s, st in self._active.items():
+                    toks[s, 0] = st.tokens[-1]
+                    pos[s] = st.pos
+                    act[s] = True
+                    left[s] = st.left
+                    temps[s] = st.temperature
+                    eos[s] = st.eos
+                    mined: List[int] = []
+                    if self.draft_source in ("auto", "ngram"):
+                        hist = st.prompt_ids + st.tokens
+                        mined = self._mine_ngram(hist, k - 1)
+                        pooled = self._mine_corpus(hist, k - 1)
+                        if len(pooled) > len(mined):
+                            mined = pooled
+                    if mined:
+                        toks[s, 1 : 1 + len(mined)] = mined
+                        src_of[s] = "ngram"
+                    elif self.draft_source in ("auto", "trunk"):
+                        need_trunk.append(s)
+                        src_of[s] = "trunk"
+                    else:
+                        src_of[s] = "none"
+                sc = (self._kscale, self._vscale) if self._quant else ()
+                deadline = self._batch_deadline(
+                    [st.req for st in self._active.values()]
                 )
-                dr = np.asarray(dr)  # pathway: allow(value-flow): the draft fetch — proposals are host state (they seed the verify's token operand), one deliberate sync per speculative round
-                for s in need_trunk:
-                    toks[s, 1:] = dr[s]
-            else:
-                inject.fire("generator.draft", deadline=deadline)
-            # verify phase: ONE batched dispatch scores all k positions
-            args = (
-                gen.params, self._pk, self._pv, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(act), jnp.asarray(left),
-                self._rngs, jnp.asarray(temps), jnp.asarray(eos), *sc,
-            )
-            with trace.use(bctx):
-                pk, pv, rngs, em, extra = retry_call(
-                    "generator.verify", vfn, *args, deadline=deadline
+                riders = [
+                    st for st in self._active.values() if st.req.trace is not None
+                ]
+                if riders:
+                    bctx = trace.start_trace(
+                        "decode.batch", deadline=deadline, kind="batch", sample=False
+                    )
+                    if bctx is not None:
+                        bctx.annotate(
+                            engine=self.name, slots=len(self._active),
+                            spec_k=k, spec=True,
+                        )
+            operands = self._prev
+            with observe.span(
+                "gen.step.dispatch", after=operands, slots=len(self._active), **_E["step_dispatch"]
+            ) as self._prev:
+                # draft phase: ONE reduced-trunk dispatch covers every lane
+                # that needs it; pure-ngram rounds still fire the chaos site
+                # so a faulted draft path degrades ALL speculation uniformly
+                if need_trunk:
+                    # pathway: allow(recompile-hazard): every operand shape is static per engine — [S] / [S, k] with S = the pool size and k = spec_k, fixed at construction; the census test pins the signature count
+                    dr = retry_call(
+                        "generator.draft",
+                        dfn,
+                        gen.params, self._pk, self._pv,
+                        jnp.asarray(toks[:, 0]), jnp.asarray(pos),
+                        jnp.asarray(act), *sc,
+                        deadline=deadline,
+                    )
+                    dr = np.asarray(dr)  # pathway: allow(value-flow): the draft fetch — proposals are host state (they seed the verify's token operand), one deliberate sync per speculative round
+                    for s in need_trunk:
+                        toks[s, 1:] = dr[s]
+                else:
+                    inject.fire("generator.draft", deadline=deadline)
+                # verify phase: ONE batched dispatch scores all k positions
+                args = (
+                    gen.params, self._pk, self._pv, jnp.asarray(toks),
+                    jnp.asarray(pos), jnp.asarray(act), jnp.asarray(left),
+                    self._rngs, jnp.asarray(temps), jnp.asarray(eos), *sc,
                 )
-            em = np.asarray(em)  # [k, S]  # pathway: allow(value-flow): THE decode-loop fetch (speculative flavor) — one deliberate sync per round delivers every slot's accepted tokens to its rider
-            extra = jax.device_get(extra)  # pathway: allow(value-flow): the same fetch's second half — what was read off those tokens' logits, outputs of the same program
+                with trace.use(bctx):
+                    pk, pv, rngs, em, extra = retry_call(
+                        "generator.verify", vfn, *args, deadline=deadline
+                    )
+            with observe.span("gen.step.fetch", after=self._prev, **_E["step_fetch"]) as self._prev:
+                em = np.asarray(em)  # [k, S]  # pathway: allow(value-flow): THE decode-loop fetch (speculative flavor) — one deliberate sync per round delivers every slot's accepted tokens to its rider
+                extra = jax.device_get(extra)  # pathway: allow(value-flow): the same fetch's second half — what was read off those tokens' logits, outputs of the same program
         except Exception as exc:
             if bctx is not None:
                 trace.finish(bctx, statuses=("speculation_disabled",))
@@ -1260,56 +1335,58 @@ class ContinuousDecoder(_CoalescerBase):
             self._spec_hold = 8
             self._step_chunk()
             return
-        t1 = time.perf_counter_ns()
+        t0, t1 = operands.t0_ns, self._prev.t1_ns
         _H_STEP.observe_ns(t1 - t0)
-        self._pk, self._pv, self._rngs = pk, pv, rngs
-        self.pool_stats["chunks"] += 1
-        self.pool_stats["steps"] += k
-        self.pool_stats["spec_rounds"] += 1
-        self.pool_stats["occupancy_sum"] += len(self._active)
-        if bctx is not None:
-            trace.finish(bctx)
-            for st in riders:
-                rt = st.req.trace
-                rt.add_link(bctx.trace_id)
-                rt.add_span(
-                    "decode.step", t0, t1,
-                    linked_trace=bctx.trace_id, slots=len(self._active),
-                    spec_k=k,
-                )
-        # replay: commit each lane's accepted prefix — ``-1`` marks the
-        # first rejected position (acceptance is a PREFIX by
-        # construction); EOS inside the accepted prefix truncates it
-        # there and frees the slot THIS round, exactly like a plain
-        # chunk whose lane hits EOS mid-chunk
-        leaves: List[Tuple[int, _SlotState, Tuple[str, ...]]] = []
-        for s, st in list(self._active.items()):
-            emitted = 0
-            flags: Tuple[str, ...] = ()
-            finished = False
-            for i in range(k):
-                t = int(em[i, s])  # pathway: allow(value-flow): `em` was rebound to its HOST copy at the fetch above — no device touch here
-                if t < 0:
-                    break
-                st.tokens.append(t)
-                emitted += 1
-                if (st.eos >= 0 and t == st.eos) or st.left - emitted <= 0:
+        self._step_ns += t1 - t0
+        with observe.span("gen.step.replay", after=self._prev, **_E["step_replay"]) as self._prev:
+            self._pk, self._pv, self._rngs = pk, pv, rngs
+            self.pool_stats["chunks"] += 1
+            self.pool_stats["steps"] += k
+            self.pool_stats["spec_rounds"] += 1
+            self.pool_stats["occupancy_sum"] += len(self._active)
+            if bctx is not None:
+                trace.finish(bctx)
+                for st in riders:
+                    rt = st.req.trace
+                    rt.add_link(bctx.trace_id)
+                    rt.add_span(
+                        "decode.step", t0, t1,
+                        linked_trace=bctx.trace_id, slots=len(self._active),
+                        spec_k=k,
+                    )
+            # replay: commit each lane's accepted prefix — ``-1`` marks the
+            # first rejected position (acceptance is a PREFIX by
+            # construction); EOS inside the accepted prefix truncates it
+            # there and frees the slot THIS round, exactly like a plain
+            # chunk whose lane hits EOS mid-chunk
+            leaves: List[Tuple[int, _SlotState, Tuple[str, ...]]] = []
+            for s, st in list(self._active.items()):
+                emitted = 0
+                flags: Tuple[str, ...] = ()
+                finished = False
+                for i in range(k):
+                    t = int(em[i, s])  # pathway: allow(value-flow): `em` was rebound to its HOST copy at the fetch above — no device touch here
+                    if t < 0:
+                        break
+                    st.tokens.append(t)
+                    emitted += 1
+                    if (st.eos >= 0 and t == st.eos) or st.left - emitted <= 0:
+                        finished = True
+                        break
+                self._took(st, s, emitted, extra)
+                _H_DRAFT_ACCEPT.observe_s(float(emitted))
+                self.pool_stats["draft_offered"] += k - 1
+                self.pool_stats["draft_accepted"] += max(0, emitted - 1)
+                self._draft_sources[src_of.get(s, "none")] += 1
+                if not finished and (
+                    st.req.deadline is not None and st.req.deadline.expired()
+                ):
                     finished = True
-                    break
-            self._took(st, s, emitted, extra)
-            _H_DRAFT_ACCEPT.observe_s(float(emitted))
-            self.pool_stats["draft_offered"] += k - 1
-            self.pool_stats["draft_accepted"] += max(0, emitted - 1)
-            self._draft_sources[src_of.get(s, "none")] += 1
-            if not finished and (
-                st.req.deadline is not None and st.req.deadline.expired()
-            ):
-                finished = True
-                flags = (EXTRACTIVE_ANSWER,)
-            if finished:
-                leaves.append((s, st, flags))
-        for s, st, flags in leaves:
-            self._leave(s, st, flags=flags)
+                    flags = (EXTRACTIVE_ANSWER,)
+                if finished:
+                    leaves.append((s, st, flags))
+            for s, st, flags in leaves:
+                self._leave(s, st, flags=flags)
 
     # -- leave / resolve -----------------------------------------------------
     def _leave(
@@ -1323,7 +1400,7 @@ class ContinuousDecoder(_CoalescerBase):
             # prefilled, the ids emitted, and per emitted token the float32
             # logit of the token chosen, the log-sum-exp and the top ids and
             # logits it was chosen from
-            "t_first_token": st.t_first,
+            "t_first_token": st.t_join_ns * 1e-9,
             "prompt_ids": list(st.prompt_ids),
             "token_ids": list(st.tokens),
             "logprobs": {
@@ -1340,11 +1417,6 @@ class ContinuousDecoder(_CoalescerBase):
             self.pool_stats["finished"] += 1
             if self.spec_k >= 2:
                 self._remember(st)
-        if st.req.trace is not None:
-            st.req.trace.add_span(
-                "decode", st.t_join_ns, time.perf_counter_ns(),
-                tokens=len(st.tokens), slot=slot,
-            )
         # free BEFORE resolving: the waiter may act on the result the
         # instant the ticket fires, and the slot hand-off (including its
         # chaos site) must already be settled by then
@@ -1355,7 +1427,7 @@ class ContinuousDecoder(_CoalescerBase):
             DecodeResult(
                 gen.render_tokens(st.tokens), degraded=flags, meta=meta
             ),
-            t_join_ns=st.t_admit_ns,
+            left=(slot, st),
         )
 
     def _evict_all(self, exc: BaseException) -> None:
@@ -1401,17 +1473,32 @@ class ContinuousDecoder(_CoalescerBase):
         with self._pool_lock:
             self._free.append(slot)
 
-    def _resolve(self, req, result: DecodeResult, t_join_ns: int = 0) -> None:
+    def _resolve(self, req, result: DecodeResult, left: Optional[Tuple[int, _SlotState]] = None) -> None:
+        """Hand ``req`` its result.  ``left`` = (slot, state) of a request
+        that left the pool (``_leave``): its rider then records its waits and
+        its life by phase from what is noted here (``_demux``), and ends its
+        trace, as the serve scheduler's riders do."""
         req.slots = [0]
-        req.batch = _Batch(
+        batch = req.batch = _Batch(
             lambda _r=result: [_r], 1, 1, self._degrade_empty
         )
-        # the rider records its own waits from these two reads (``_demux``)
-        req.batch.t_launch_ns = t_join_ns
-        req.batch.link["t_resolved_ns"] = time.perf_counter_ns()
+        t_resolved = batch.link["t_resolved_ns"] = time.perf_counter_ns()
+        if left is not None and observe.enabled():
+            slot, st = left
+            batch.t_launch_ns = st.t_admit_ns
+            # live -> resolved, split by the engine's running totals; a lane
+            # that left inside its own join's ``settle`` was never live
+            t_live, stalled, stepping = t_resolved, 0, 0
+            if st.t_live_ns:
+                t_live, stalled, stepping = st.t_live_ns, self._join_ns - st.join_ns0, self._step_ns - st.step_ns0
+            batch.link["life"] = (
+                st.t_join_ns, {"tokens": len(st.tokens), "slot": slot},
+                {
+                    "join": t_live - st.t_admit_ns, "stalled": stalled, "stepping": stepping,
+                    "host": t_resolved - t_live - stalled - stepping,
+                },
+            )
         req.event.set()
-        if req.trace is not None:
-            trace.finish(req.trace, statuses=tuple(result.degraded))
 
     # -- solo fallback (deadline preemption, stop-drain, quarantine) ---------
     def _launch(self, items: List[Any], reqs: List[Any]):
@@ -1437,10 +1524,6 @@ class ContinuousDecoder(_CoalescerBase):
         # completion is the client-visible "last token")
         t_woke = time.perf_counter_ns()
         _H_TTLT.observe_ns(t_woke - req.t_enqueue_ns)
-        t_join, t_resolved = req.batch.t_launch_ns, req.batch.link.get("t_resolved_ns", 0)
-        if t_join:  # joined the pool: enqueue -> its join began; resolved -> woke
-            observe.interval("admission_wait", req.t_enqueue_ns, t_join, hist=_H_ADMISSION_WAIT, tree=req.trace)
-            observe.interval("ticket_wake", t_resolved, t_woke, hist=_H_TICKET_WAKE, tree=req.trace)
         out = []
         for slot in req.slots:
             if 0 <= slot < len(batch_result):
@@ -1450,14 +1533,31 @@ class ContinuousDecoder(_CoalescerBase):
                     DecodeResult("", degraded=(EXTRACTIVE_ANSWER,))
                 )
         result = out[0]
-        if req.trace is not None:
-            # solo-path requests (deadline preemption, stop-drain,
-            # quarantine/kv_width fallback) resolve through here without
-            # passing _resolve — finish their trace so tail sampling
-            # sees them (idempotent for pool-path requests)
-            trace.finish(
-                req.trace, statuses=tuple(getattr(result, "degraded", ()))
-            )
+        rt = req.trace
+        life = req.batch.link.pop("life", None)
+        if life is not None:
+            # it left the pool with the recorder on: its life by phase, on the
+            # engine's clock reads and the two waits the rider itself sees;
+            # the six sum to the observation above
+            t_admit, t_resolved = req.batch.t_launch_ns, req.batch.link["t_resolved_ns"]
+            t_first, attrs, engine_ns = life
+            phases = {"slot_wait": t_admit - req.t_enqueue_ns, **engine_ns, "wake": t_woke - t_resolved}
+            for phase, ns in phases.items():
+                _H_REQUEST[phase].observe_ns(ns)
+            result.meta["phases_ms"] = {phase: ns * 1e-6 for phase, ns in phases.items()}
+            if rt is not None:
+                observe.interval("admission_wait", req.t_enqueue_ns, t_admit, tree=rt)
+                observe.interval(  # first token on the host -> leave, with the request's split
+                    "decode", t_first, t_resolved, tree=rt, **attrs,
+                    **{f"{phase}_ms": ms for phase, ms in result.meta["phases_ms"].items()},
+                )
+                observe.interval("ticket_wake", t_resolved, t_woke, tree=rt)
+        if rt is not None:
+            # the rider's trace ends with the rider, pool and solo paths alike
+            # (deadline preemption, stop-drain, quarantine/kv_width fallback):
+            # the root span is the request's latency and tail sampling runs
+            # once its outcome is known (idempotent)
+            trace.finish(rt, statuses=tuple(getattr(result, "degraded", ())))
         return result
 
     # -- flight-recorder provider -------------------------------------------
@@ -1469,7 +1569,6 @@ class ContinuousDecoder(_CoalescerBase):
             "gauge", "pathway_generator_slots_active", labels,
             len(self._active),
         )
-        yield ("gauge", "pathway_generator_slots_live", labels, len(self._active))
         yield (
             "gauge", "pathway_generator_slots_quarantined", labels,
             self.pool_stats["quarantined"],
