@@ -472,19 +472,22 @@ def _stack(cfg: HybridConfig, params, ids, q_pos, real, carry, delta, attend, ke
     p, Ly = cfg.period, cfg.num_hidden_layers
     B, L = ids.shape
     layers = params["layers"]
-    every = {n: a.reshape((Ly // p, p) + a.shape[1:]) for n, a in layers["every"].items()}
-    linear = {n: a.reshape((Ly // p, p - 1) + a.shape[1:]) for n, a in layers["linear"].items()}
     x = params["embed"][ids].astype(jnp.float32)
 
-    def one_period(c, xs):
-        (we, wl, wf), i = xs
+    def take(group, l):
+        # each layer indexes the flat stack itself, so its slice has one consumer and fuses into its product: a
+        # period's slab scanned out for several layers is copied whole through HBM before they read it (ISSUE 37)
+        return {n: jax.lax.dynamic_index_in_dim(a, l, keepdims=False) for n, a in layers[group].items()}
+
+    def one_period(c, i):
         x, carry = c
         loads, kept = [], []
         for j in range(p):
-            w = {n: a[j] for n, a in we.items()}
+            w = take("every", i * p + j)
             a = _rms0(x, w["in_norm"], cfg.rms_norm_eps)
             if j < p - 1:
-                carry, out, extra = delta(carry, i * (p - 1) + j, {n: a_[j] for n, a_ in wl.items()}, a)
+                d = i * (p - 1) + j
+                carry, out, extra = delta(carry, d, take("linear", d), a)
                 kept.append(extra)
             else:
                 def attend_here(q, k, v):
@@ -492,7 +495,7 @@ def _stack(cfg: HybridConfig, params, ids, q_pos, real, carry, delta, attend, ke
                     carry, o = attend(carry, i, q, k, v)
                     return o
 
-                out, kv = _full_mixer(cfg, wf, a, q_pos, attend_here)
+                out, kv = _full_mixer(cfg, take("full", i), a, q_pos, attend_here)
             x = x + out
             m = _rms0(x, w["post_norm"], cfg.rms_norm_eps).reshape(B * L, -1)
             # the j-th layer of period i: one branch a period, each holding that layer's experts by reference
@@ -505,7 +508,7 @@ def _stack(cfg: HybridConfig, params, ids, q_pos, real, carry, delta, attend, ke
             ys += (jax.tree_util.tree_map(lambda *a: jnp.stack(a), *kept), kv)
         return (x, carry), ys
 
-    (x, carry), ys = jax.lax.scan(one_period, (x, carry), ((every, linear, layers["full"]), jnp.arange(Ly // p)))
+    (x, carry), ys = jax.lax.scan(one_period, (x, carry), jnp.arange(Ly // p))
     flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731 - [periods, layers of a period, ...] -> [layers, ...]
     loads = tuple(flat(a) for a in ys[:3])
     return _rms0(x, params["final_norm"], cfg.rms_norm_eps), carry, loads, (jax.tree_util.tree_map(flat, ys[3]), ys[4]) if keep else ()
