@@ -158,7 +158,10 @@ class TextGenerator:
         # which this chip holds a range, a shared expert), routed experts
         # (``moe_num_primary_experts``; models/moe.py: grouped-query
         # heads, per-layer rotary and window layouts, a router before
-        # attention) or else the looped family (models/looped.py: RMSNorm
+        # attention; or the parallel block, ``use_parallel_block`` /
+        # ``model_type`` "cohere2_moe": one LayerNorm feeding attention and
+        # sigmoid-routed experts side by side, shared experts, a held range)
+        # or else the looped family (models/looped.py: RMSNorm
         # sandwich, rotary, gated SiLU, ``total_ut_steps`` passes over one
         # stack).  ``params`` hands its weights in.  Without it this is the
         # 4 x dimension LayerNorm/GELU trunk, as ever (``family`` None).
@@ -232,7 +235,8 @@ class TextGenerator:
     def _family_of(architecture: Mapping[str, Any]):
         if "linear_num_value_heads" in architecture or "full_attention_interval" in architecture:
             return hybrid
-        return moe if "moe_num_primary_experts" in architecture else looped
+        parallel = "use_parallel_block" in architecture or architecture.get("model_type") == "cohere2_moe"
+        return moe if parallel or "moe_num_primary_experts" in architecture else looped
 
     def _family_params(self, params, seed: int):
         """A decoder family's weights: handed in (their tree is checked
@@ -292,13 +296,25 @@ class TextGenerator:
                 f"bf16 cache (decode.kv_quant bf16); asked for spec_k={spec_k}, kv_quant={kv_quant}"
             )
 
-    def join_attention(self, L_sfx: int) -> str:
-        """What attends the prompt in a join of ``L_sfx`` suffix tokens:
-        "kernel" where a family's flash kernel takes it, "blocks" where its
-        query blocks do (models/moe.py ``prompt_attention``), "dense" where
-        the scores are one array."""
+    def join_attention(self, L_sfx: int, rows: int = 1, prefix: int = 0) -> str:
+        """What attends the prompt in a join of ``rows`` rows of ``L_sfx``
+        suffix tokens behind ``prefix`` cached ones: "kernel" where a family's
+        flash kernel takes it, "blocks" where its query blocks do
+        (models/moe.py ``prompt_attention``), "dense" where the scores are one array."""
         choose = getattr(self.family, "prompt_attention", None)
-        return choose(self.config, L_sfx) if choose is not None else "dense"
+        return choose(self.config, L_sfx, rows, prefix + L_sfx) if choose is not None else "dense"
+
+    def join_rows(self, prefix: int):
+        """The most rows one join behind ``prefix`` cached tokens may carry
+        (models/moe.py ``join_rows``), or None: no limit of the family's."""
+        most = getattr(self.family, "join_rows", None)
+        return most(self.config, prefix) if most is not None else None
+
+    def join_ffn_blocks(self, tokens: int) -> int:
+        """Blocks of tokens the expert branch of a join of ``tokens`` tokens
+        runs its shared experts over (models/moe.py ``ffn_blocks``; 1 elsewhere)."""
+        blocks = getattr(self.family, "ffn_blocks", None)
+        return blocks(self.config, tokens) if blocks is not None else 1
 
     def kv_pool_layout(self, T: int):
         """The slot pool's kinds of rows at width ``T`` as ``(kind, cache

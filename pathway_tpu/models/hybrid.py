@@ -63,9 +63,9 @@ block that ends there.
 
 What is shared with the other families: ``looped._rope``, ``_mm``,
 ``_mm_t``, ``_head``, ``_sample``, ``token_stats``;
-``moe.route``, ``grouped_experts`` (the sort, ``gmm`` and un-sort, here with
-SiLU and the held range), ``_attend_prompt`` (the flash kernel on a TPU) and
-``_attend_rows``.
+``moe.route``, ``expert_load``, ``grouped_experts`` (the sort, ``gmm`` and
+un-sort, here with SiLU and the held range's passes), ``prompt_attention``
+and ``_attend_prompt`` (the flash kernel on a TPU), and ``_attend_rows``.
 
 Precision: bfloat16 weights and matrix-product inputs with float32
 accumulation; the residual stream, norms, rotary angles, softmax, the gates'
@@ -86,7 +86,7 @@ import jax
 import jax.numpy as jnp
 
 from .looped import _head, _mm, _mm_t, _rope, _sample, token_stats
-from .moe import _attend_prompt, _attend_rows, grouped_experts, prompt_attention, route
+from .moe import _attend_prompt, _attend_rows, _load_stats, expert_load, grouped_experts, prompt_attention, route
 
 __all__ = ["HybridConfig", "delta_scan", "forward", "init_params", "slot_prefill", "slot_step", "snapshot_positions"]
 
@@ -437,23 +437,15 @@ def _delta_token(cfg: HybridConfig, w, a, live, conv, S):
     return _gated_out(cfg, w, o[:, None], z), jnp.where(keep, rows[:, 1:], conv), jnp.where(keep[..., None], S1, S)
 
 
-def held_load(cfg: HybridConfig, ids, real):
-    """Of one layer's choices ``ids [N, k]``, counting the tokens ``real
-    [N]`` only: how many of the held experts got a token, the busiest one's
-    tokens, and the (token, expert) pairs that fell to a held expert."""
-    lo, hi = cfg.experts_held
-    local = jnp.where(real[:, None] & (ids >= lo) & (ids < hi), ids - lo, hi - lo).reshape(-1)
-    sizes = jnp.zeros(hi - lo + 1, jnp.int32).at[local].add(1)[:-1]
-    return jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes), jnp.sum(sizes)
-
-
 def _experts(cfg: HybridConfig, w, routed, m):
     """The expert branch on the normed state ``m [N, D]``: the held routed
     experts' part (``routed(m, ids, gates)``) and the shared expert, which
-    every chip of a layer computes alike.  Returns it and the choices."""
+    every chip of a layer computes alike.  Returns it, the choices and the
+    rows the routed part carried."""
     ids, gates = route(cfg, m, w["router"])
     shared = _mm((jax.nn.silu(_mm(m, w["shared_wg"])) * _mm(m, w["shared_wu"])).astype(cfg.dtype), w["shared_wd"])
-    return routed(m, ids, gates) + jax.nn.sigmoid(_mm(m, w["shared_gate"]))[:, None] * shared, ids
+    r, carried = routed(m, ids, gates)
+    return r + jax.nn.sigmoid(_mm(m, w["shared_gate"]))[:, None] * shared, ids, carried
 
 
 def _held_experts(cfg: HybridConfig, w, m, ids, gates):
@@ -465,8 +457,9 @@ def _stack(cfg: HybridConfig, params, ids, q_pos, real, carry, delta, attend, ke
     runs the ``d``-th delta layer's mixer and ``attend(carry, d, q, k, v)``
     files the ``d``-th full layer's keys and values and attends; both return
     the carry first.  Returns the normed state ``[B, L, D]``, the carry, per
-    layer the held experts that got a real token, the busiest one's tokens
-    and the pairs that fell to held experts (``[layers]`` each) and, asked
+    layer the held experts that got a real token, the busiest one's tokens,
+    the pairs that fell to held experts and the rows the expert layer
+    carried (``[layers]`` each) and, asked
     for, what ``delta`` returned beside its output, stacked ``[n_linear,
     ...]``, and the full layers' keys and values ``[n_full, B, L, Hkv, hd]``."""
     p, Ly = cfg.period, cfg.num_hidden_layers
@@ -500,18 +493,18 @@ def _stack(cfg: HybridConfig, params, ids, q_pos, real, carry, delta, attend, ke
             m = _rms0(x, w["post_norm"], cfg.rms_norm_eps).reshape(B * L, -1)
             # the j-th layer of period i: one branch a period, each holding that layer's experts by reference
             branches = [partial(_held_experts, cfg, {n: layers[n][q * p + j] for n in _EXPERT_LEAVES}) for q in range(Ly // p)]
-            f, chosen = _experts(cfg, w, partial(jax.lax.switch, i, branches), m)
+            f, chosen, carried = _experts(cfg, w, partial(jax.lax.switch, i, branches), m)
             x = x + f.reshape(B, L, -1)
-            loads.append(held_load(cfg, chosen, real.reshape(-1)))
-        ys = tuple(jnp.stack([load[n] for load in loads]) for n in range(3))
+            loads.append(expert_load(cfg, chosen, real.reshape(-1), cfg.experts_held) + (carried,))
+        ys = tuple(jnp.stack([load[n] for load in loads]) for n in range(4))
         if keep:
             ys += (jax.tree_util.tree_map(lambda *a: jnp.stack(a), *kept), kv)
         return (x, carry), ys
 
     (x, carry), ys = jax.lax.scan(one_period, (x, carry), jnp.arange(Ly // p))
     flat = lambda a: a.reshape((-1,) + a.shape[2:])  # noqa: E731 - [periods, layers of a period, ...] -> [layers, ...]
-    loads = tuple(flat(a) for a in ys[:3])
-    return _rms0(x, params["final_norm"], cfg.rms_norm_eps), carry, loads, (jax.tree_util.tree_map(flat, ys[3]), ys[4]) if keep else ()
+    loads = tuple(flat(a) for a in ys[:4])
+    return _rms0(x, params["final_norm"], cfg.rms_norm_eps), carry, loads, (jax.tree_util.tree_map(flat, ys[4]), ys[5]) if keep else ()
 
 
 def _zero_state(cfg: HybridConfig, B: int):
@@ -523,7 +516,7 @@ def forward(cfg: HybridConfig, params, ids, query_block: int = 0):
     V]`` (float32), the delta layers through the chunked scan from zeros."""
     B, L = ids.shape
     pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None, :], (B, L))
-    how = prompt_attention(cfg, L)
+    how = prompt_attention(cfg, L, B)
     real = jnp.ones((B, L), bool)
     S0, conv0 = _zero_state(cfg, B)
 
@@ -585,7 +578,7 @@ def slot_prefill(cfg: HybridConfig, S: int, T: int, B: int, L_sfx: int, P: int, 
     ``snapshot_positions`` the pair (delta state ``[n_linear, Hv, dk, dv]``,
     carried rows ``[n_linear, taps - 1, channels]``) at that position, which
     stay on the device.  The pools are donated and updated in place."""
-    how = prompt_attention(cfg, L_sfx)
+    how = prompt_attention(cfg, L_sfx, B, P + L_sfx)
     positions = snapshot_positions(P, L_sfx, block)
     marks = tuple(p - P for p in positions)
 
@@ -621,13 +614,13 @@ def slot_prefill(cfg: HybridConfig, S: int, T: int, B: int, L_sfx: int, P: int, 
             rows_v = rows_v.at[rows, d, : P + L_sfx].set(v[twice], mode="promise_in_bounds")
             return ((rows_k, state), (rows_v, conv)), _attend_prompt(q, k, v, P, 0, how)
 
-        x, (pool_k, pool_v), (touched, busiest, pairs), kept = _stack(
+        x, (pool_k, pool_v), loads, kept = _stack(
             cfg, params, suffix_ids, pos, real, (pool_k, pool_v), delta, attend, keep=bool(block)
         )
         at = jnp.maximum(n_len - 1 - P, 0)
         logits = _head(params, jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0])
         rngs, tok = _sample(logits, rngs, temps)
-        extra = {**token_stats(logits, tok), "experts_touched": touched, "expert_load_max": busiest, "expert_pairs_held": pairs}
+        extra = {**token_stats(logits, tok), **_load_stats(loads)}
         if block:
             (states, convs), kv = kept  # per mark [n_linear, B, ...]; keys and values [n_full, B, L, Hkv, hd]
             extra["prompt_kv"] = tuple(
@@ -677,12 +670,12 @@ def slot_step(cfg: HybridConfig, S: int, T: int, chunk: int) -> Callable:
                 K, V = (jax.lax.dynamic_index_in_dim(x, d, axis=1, keepdims=False) for x in (rows_k, rows_v))
                 return ((rows_k, state), (rows_v, conv)), _attend_rows(q.astype(K.dtype), K, V, seen)
 
-            x, (pool_k, pool_v), (touched, busiest, pairs), _ = _stack(
+            x, (pool_k, pool_v), loads, _ = _stack(
                 cfg, params, tok[:, None], pos[:, None], live[:, None], (pool_k, pool_v), delta, attend
             )
             logits = _head(params, x[:, 0])
             rngs2, nxt = _sample(logits, rngs, temps)
-            stats = {**token_stats(logits, nxt), "experts_touched": touched, "expert_load_max": busiest, "expert_pairs_held": pairs}
+            stats = {**token_stats(logits, nxt), **_load_stats(loads)}
             carry = (
                 pool_k, pool_v, jnp.where(live, nxt, tok), jnp.where(live, pos + 1, pos),
                 live & (nxt != eos), jnp.where(live, left - 1, left),

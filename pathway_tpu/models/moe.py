@@ -38,7 +38,34 @@ copy; folding the layer into the group axis of one stack made the chip's
 grouped kernel walk every layer's experts for each layer's product: a step
 of 113 ms): each layer's experts are arrays of their own, and the product
 is reached through a ``lax.switch`` on the period's index whose branches
-each hold one layer's, by reference.
+each hold one layer's, by reference.  The other weights are no scanned
+operand either: each layer takes its own by one dynamic index of the flat
+``[layers, ...]`` stack, as the hybrid family's.
+
+**The parallel block** (``use_parallel_block``, Command A+'s
+``cohere2_moe`` keys; ``MoeConfig.from_architecture`` reads either set).
+One LayerNorm (mean removed, a weight, no bias) feeds attention, the router
+and the experts alike, and both branches are added to the stream: ``x = x +
+Attn(a) + F(a)``.  The router's ``sigmoid`` scores choose the
+``num_experts_per_tok`` largest, renormalised among themselves; the experts
+are gated SiLU; ``num_shared_experts`` shared ones of the same width every
+token takes (one gated MLP of their summed width: their sum) are averaged in,
+``F = (routed + sum_i S_i / n) / 2``.  Window layers rotate queries and keys
+GPT-J's way (the pairs ``(2i, 2i + 1)``), full layers carry no positions;
+the head is the embedding's table, scaled by ``logit_scale``.  The choices
+are static in the configuration: without them a SmallThinker program
+computes what it did.
+
+**What this chip holds** (``experts_held = (lo, hi)``, as the hybrid
+family's): the leaves ``[hi - lo, ...]`` of the routed experts ``[lo, hi)``;
+the router keeps every output and its choices, and pairs routed to an absent
+expert are computed by no one.  Such a layer sorts its pairs, absent ones
+last, and carries the held ones through the gather and the grouped products
+in passes of a bounded number of rows (``_held_pass_rows``: twice what even
+routing sends the range, at most ``HELD_BYTES`` of temporaries), as many
+passes as the held pairs fill; the shared experts run over blocks of
+``FFN_BLOCK`` tokens.  So a 12,544-token join's expert branch never holds a
+row for every (token, expert) pair.
 
 A prompt's attention (the join's and the full forward's) goes through one
 seam, ``_attend_prompt``: on a TPU a banded flash kernel (the Pallas splash
@@ -63,14 +90,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Mapping, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .looped import _head, _mm, _mm_t, _prefix_rows, _rms, _rope, _sample, token_stats
 
-__all__ = ["MoeConfig", "forward", "init_params", "slot_prefill", "slot_step"]
+__all__ = ["MoeConfig", "ffn_blocks", "forward", "init_params", "join_rows", "slot_prefill", "slot_step"]
 
 FAMILY = "sparse-expert"
 QUERY_BLOCK = 512  # queries the block path attends at a time: its scores are [B, heads, QUERY_BLOCK, keys], never [.., L, L]
@@ -82,6 +109,10 @@ KERNEL_BLOCK = 512  # the kernel's tile, queries and keys alike: chosen on the c
 # the grouped product's kernel: None chooses by the backend (the Pallas grouped matmul on a TPU, ``ragged_dot``
 # elsewhere); a test that compiles for a chip it does not have names it
 GROUPED_KERNEL = None
+HELD_BYTES = 1 << 29  # a held range's pass: its gathered rows and its products' temporaries stay under this
+FFN_BLOCK = 2048  # tokens the shared experts take at a time: [FFN_BLOCK, summed width] products, never [N, ...]
+ATTEND_BYTES = 256 << 20  # a prompt's float32 queries made and attended at a time: whole key/value groups under this
+JOIN_PREFIX_BYTES = 256 << 20  # the cached keys and values one join gathers behind its rows' prefixes, all rows together
 
 
 def _refuse(what: str) -> ValueError:
@@ -105,15 +136,23 @@ class MoeConfig:
     rope_layout: Tuple[int, ...]
     sliding_window_layout: Tuple[int, ...]
     sliding_window_size: int
-    rms_norm_eps: float = 1e-6
+    rms_norm_eps: float = 1e-6  # the norms' epsilon (the parallel block's LayerNorms': ``layer_norm_eps``)
     rope_theta: float = 10000.0
     max_position_embeddings: int = 2048
     dtype: Any = jnp.bfloat16
+    # the parallel block (module docstring: its LayerNorm, sigmoid router, SiLU experts, GPT-J rotary and tied head
+    # come together); off, SmallThinker's block
+    parallel_block: bool = False
+    num_shared_experts: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    logit_scale: float = 1.0
 
     @classmethod
     def from_architecture(cls, arch: Mapping[str, Any], dtype=jnp.bfloat16) -> "MoeConfig":
         """Read a published ``config.json``; what this family cannot run is
         refused here, by name, and never approximated."""
+        if "use_parallel_block" in arch or arch.get("model_type") == "cohere2_moe":
+            return cls._from_parallel(arch, dtype)
         if arch.get("rope_scaling") is not None:
             raise _refuse(f"rope_scaling={arch['rope_scaling']!r}: scaled rotary positions are not implemented")
         if arch.get("tie_word_embeddings"):
@@ -149,6 +188,59 @@ class MoeConfig:
             max_position_embeddings=int(arch.get("max_position_embeddings", 2048)), dtype=dtype, **layouts,
         )
 
+    @classmethod
+    def _from_parallel(cls, arch: Mapping[str, Any], dtype) -> "MoeConfig":
+        """The parallel block's keys (``cohere2_moe``), plus ``experts_held``."""
+        rope = arch.get("rope_parameters") or {}
+        refused = {
+            "use_parallel_block": (not arch.get("use_parallel_block"), "false: attention and experts read one normed input side by side here"),
+            "first_k_dense_replace": (int(arch.get("first_k_dense_replace") or 0) > 0, "> 0: a dense layer among the expert layers is not implemented"),
+            "use_qk_norm": (bool(arch.get("use_qk_norm")), "true: query/key norms are not implemented in this family"),
+            "attention_bias": (bool(arch.get("attention_bias")), "true: the projections have no bias"),
+            "rope_scaling": (arch.get("rope_scaling") is not None or rope.get("rope_type", "default") != "default",
+                             "set: scaled rotary positions are not implemented"),
+            "position_embedding_type": (arch.get("position_embedding_type", "rope_gptj") != "rope_gptj", "not rope_gptj: window layers rotate GPT-J's pairs"),
+            "rotary_pct": (float(arch.get("rotary_pct", 1)) != 1, "below 1: every dim of a head is rotated"),
+            "hidden_act": (arch.get("hidden_act", "silu") != "silu" or not arch.get("use_gated_activation", True), "the experts are gated SiLU"),
+            "expert_selection_fn": (arch.get("expert_selection_fn", "sigmoid") != "sigmoid", "the router's scores are sigmoids"),
+            "norm_topk_prob": (not arch.get("norm_topk_prob", True), "false: the chosen experts' scores are renormalised among themselves"),
+            "shared_expert_combination_strategy": (arch.get("shared_expert_combination_strategy", "average") != "average",
+                                                   "not average: the shared experts are averaged in"),
+            "tie_word_embeddings": (not arch.get("tie_word_embeddings", True), "false: the head is the embedding's table"),
+            "use_parallel_embedding": (bool(arch.get("use_parallel_embedding")), "true: one embedding table"),
+        }
+        for key, (bad, why) in refused.items():
+            if bad:
+                raise _refuse(f"{key}={arch.get(key)!r} {why}")
+        layers = int(arch["num_hidden_layers"])
+        types = list(arch.get("layer_types") or ())
+        if len(types) != layers or set(types) - {"sliding_attention", "full_attention"}:
+            raise _refuse(f"layer_types must hold num_hidden_layers={layers} entries of sliding_attention or full_attention, got {types}")
+        heads, kv_heads = int(arch["num_attention_heads"]), int(arch["num_key_value_heads"])
+        if heads % kv_heads:
+            raise _refuse(f"num_attention_heads={heads} is not a multiple of num_key_value_heads={kv_heads}")
+        experts, active = int(arch["num_experts"]), int(arch["num_experts_per_tok"])
+        if not 1 <= active <= experts:
+            raise _refuse(f"num_experts_per_tok={active} of num_experts={experts}")
+        lo, hi = (int(x) for x in arch.get("experts_held") or (0, experts))
+        if not 0 <= lo < hi <= experts:
+            raise _refuse(f"experts_held=[{lo}, {hi}) of num_experts={experts}")
+        window = tuple(int(t == "sliding_attention") for t in types)
+        if any(window) and int(arch.get("sliding_window") or 0) < 1:
+            raise _refuse(f"sliding_window={arch.get('sliding_window')} with sliding_attention layers")
+        hidden = int(arch["hidden_size"])
+        return cls(
+            vocab_size=int(arch["vocab_size"]), hidden_size=hidden, num_attention_heads=heads, num_key_value_heads=kv_heads,
+            head_dim=int(arch.get("head_dim") or hidden // heads), moe_ffn_hidden_size=int(arch["intermediate_size"]),
+            moe_num_primary_experts=experts, moe_num_active_primary_experts=active, num_hidden_layers=layers,
+            rope_layout=window, sliding_window_layout=window, sliding_window_size=int(arch.get("sliding_window") or 0),
+            rms_norm_eps=float(arch.get("layer_norm_eps", 1e-5)),
+            rope_theta=float(arch.get("rope_theta") or rope.get("rope_theta", 10000.0)),
+            max_position_embeddings=int(arch.get("max_position_embeddings", 2048)), dtype=dtype, parallel_block=True,
+            num_shared_experts=int(arch.get("num_shared_experts") or 0), experts_held=(lo, hi),
+            logit_scale=float(arch.get("logit_scale", 1.0)),
+        )
+
     d_model = property(lambda self: self.hidden_size)
     n_heads = property(lambda self: self.num_attention_heads)
     n_kv_heads = property(lambda self: self.num_key_value_heads)
@@ -180,9 +272,13 @@ def init_params(cfg: MoeConfig, seed: int, scale: float = 0.02) -> Dict[str, Any
     ``scale``) in ``cfg.dtype`` (the projections kept ``[heads * head_dim,
     hidden]`` as the looped family's, stacked over the layers; the experts
     ``wg wu [E, D, F]`` and ``wd [E, F, D]`` one array a layer, in a tuple),
-    norm weights 1 + normal(0, 0.1) in float32."""
+    norm weights 1 + normal(0, 0.1) in float32.  The parallel block has no
+    ``post_norm`` and no ``head`` (it is the embedding's table); shared
+    experts are ``shared_wg shared_wu [Ly, D, n * F]``, ``shared_wd [Ly, n *
+    F, D]``; a held range's experts are ``[hi - lo, ...]``."""
     D, F, V, E, Ly = cfg.hidden_size, cfg.moe_ffn_hidden_size, cfg.vocab_size, cfg.moe_num_primary_experts, cfg.num_hidden_layers
     A, Akv = cfg.num_attention_heads * cfg.head_dim, cfg.num_key_value_heads * cfg.head_dim
+    here = E if cfg.experts_held is None else cfg.experts_held[1] - cfg.experts_held[0]
     keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16 + 3 * Ly))
 
     def mat(*shape):
@@ -191,18 +287,24 @@ def init_params(cfg: MoeConfig, seed: int, scale: float = 0.02) -> Dict[str, Any
     def norm(*shape):
         return 1.0 + 0.1 * jax.random.normal(next(keys), shape, jnp.float32)
 
-    return {
+    params = {
         "embed": mat(V, D),
         "head": mat(D, V),
         "final_norm": norm(D),
         "layers": {
             "wq": mat(Ly, A, D), "wk": mat(Ly, Akv, D), "wv": mat(Ly, Akv, D), "wo": mat(Ly, A, D),
             "router": mat(Ly, D, E),
-            "wg": tuple(mat(E, D, F) for _ in range(Ly)), "wu": tuple(mat(E, D, F) for _ in range(Ly)),
-            "wd": tuple(mat(E, F, D) for _ in range(Ly)),
+            "wg": tuple(mat(here, D, F) for _ in range(Ly)), "wu": tuple(mat(here, D, F) for _ in range(Ly)),
+            "wd": tuple(mat(here, F, D) for _ in range(Ly)),
             "in_norm": norm(Ly, D), "post_norm": norm(Ly, D),
         },
     }
+    if cfg.parallel_block:
+        del params["head"], params["layers"]["post_norm"]
+    if cfg.num_shared_experts:
+        Fs = cfg.num_shared_experts * F
+        params["layers"].update(shared_wg=mat(Ly, D, Fs), shared_wu=mat(Ly, D, Fs), shared_wd=mat(Ly, Fs, D))
+    return params
 
 
 _EXPERT_LEAVES = ("wg", "wu", "wd")
@@ -213,12 +315,66 @@ _EXPERT_LEAVES = ("wg", "wu", "wd")
 # ---------------------------------------------------------------------------
 
 
+def _layer_norm(x, w, eps):
+    """LayerNorm with the mean removed, a weight and no bias."""
+    c = x - jnp.mean(x, axis=-1, keepdims=True)
+    return c * jax.lax.rsqrt(jnp.mean(c * c, axis=-1, keepdims=True) + eps) * w
+
+
+def _norm(cfg: MoeConfig, x, w):
+    return (_layer_norm if cfg.parallel_block else _rms)(x, w, cfg.rms_norm_eps)
+
+
+def _pairs_first(x):
+    """``x [B, L, H, hd]`` with each head's dims reordered evens first, then
+    odds: GPT-J's rotary pairs ``(2i, 2i + 1)`` become rotate-half's ``(i, i
+    + hd/2)``.  Queries and keys are reordered alike, so ``q . k`` is what it
+    was, and the rotation is rotate-half's (``_rope``): the same pairs turned
+    by the same angles, with no ``[..., hd / 2, 2]`` view left for the chip's
+    tiles to pad."""
+    B, L, H, hd = x.shape
+    return x.reshape(B, L, H, hd // 2, 2).swapaxes(-1, -2).reshape(B, L, H, hd)
+
+
+def _rotate(cfg: MoeConfig, x, pos):
+    """A window layer's rotary positions: rotate-half, or in the parallel
+    block GPT-J's pairs (reordered first: the cache keeps such a layer's keys
+    so, one order).  The reorder is of the projection's output: behind a
+    barrier, or the compiler moves it onto the weights and copies them on
+    every call."""
+    if cfg.parallel_block:
+        x = _pairs_first(jax.lax.optimization_barrier(x))
+    return _rope(x, pos, cfg.rope_theta)
+
+
+def _head_chunks(cfg: MoeConfig, tokens: int) -> int:
+    """Chunks of whole key/value groups a layer's queries are made and
+    attended in, for ``tokens`` tokens: the fewest that keep a chunk's float32
+    queries under ``ATTEND_BYTES`` (1 for a step, or a prompt that small)."""
+    Hkv = cfg.num_key_value_heads
+    q_bytes = 4 * tokens * cfg.num_attention_heads * cfg.head_dim
+    return next((n for n in range(1, Hkv + 1) if Hkv % n == 0 and q_bytes <= n * ATTEND_BYTES), Hkv)
+
+
+def _logits(cfg: MoeConfig, params, x):
+    """The head: untied, or the embedding's table ``[V, D]`` contracted on its
+    second axis as it lies (no transposed copy of it a call) and scaled by ``logit_scale``."""
+    if not cfg.parallel_block:
+        return _head(params, x)
+    table = params["embed"]
+    return jnp.einsum("...d,vd->...v", x.astype(table.dtype), table, preferred_element_type=jnp.float32) * cfg.logit_scale
+
+
 def route(cfg, a, router):
     """The layer's experts for every token of ``a [N, D]`` (float32, the
     normed state the router reads): ids ``[N, k]`` (ties broken as
     ``lax.top_k`` does: the lower id first) and their weights, a softmax over
-    the chosen logits.  ``cfg`` is any family's with routed experts."""
+    the chosen logits, or, in the parallel block, the chosen sigmoid scores
+    over their sum.  ``cfg`` is any family's with routed experts."""
     r = jnp.dot(a, router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    if getattr(cfg, "parallel_block", False):
+        top, ids = jax.lax.top_k(jax.nn.sigmoid(r), cfg.experts_per_token)
+        return ids.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True)
     top, ids = jax.lax.top_k(r, cfg.experts_per_token)
     return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
@@ -252,6 +408,16 @@ def grouped_product(rows, w, sizes, kernel=None, interpret: bool = False):
     return out[:A] if pad else out
 
 
+def _held_pass_rows(pairs: int, share: float, D: int, F: int) -> int:
+    """Rows one pass of a held range's grouped product carries, of ``pairs``
+    routed pairs of which even routing sends ``share`` to the range: twice
+    that, no more than the pairs, nor than ``HELD_BYTES`` of a pass's
+    temporaries (a row's gathered input, its two products, their gated
+    product and its output), in whole row tiles."""
+    want = min(pairs, max(1, round(2 * share * pairs)), max(512, HELD_BYTES // (6 * D + 10 * F)))
+    return -(-want // _row_tile(want)) * _row_tile(want)
+
+
 def grouped_experts(cfg, held, w, m, ids, gates, act=jax.nn.relu):
     """The expert layer as one grouped product: the ``N * k`` (token,
     expert) pairs sorted by expert, one grouped matrix product per weight
@@ -260,28 +426,55 @@ def grouped_experts(cfg, held, w, m, ids, gates, act=jax.nn.relu):
     summed back per token.  ``held = (lo, hi)`` says that ``w`` holds only
     the experts ``[lo, hi)`` of the ``cfg.n_experts`` the router chose among
     (what one chip of an expert axis holds): pairs routed to the others sort
-    last, are computed by no one, and their part of the result is left out.
-    ``None``: every expert is here.  Returns ``[N, D]`` float32."""
+    last and are computed, gathered and carried by no one; the held ones go
+    through in passes (``_held_pass_rows``), each the next stretch of the
+    sorted pairs with its experts' groups cut to it.  ``None``: every expert
+    is here, every pair in one pass.  Returns ``[N, D]`` float32 and the rows
+    carried (every pass's, whole)."""
     N, k = ids.shape
-    n = cfg.n_experts if held is None else held[1] - held[0]
-    flat = ids.reshape(-1)
     if held is None:
-        sizes = jnp.zeros(n, jnp.int32).at[flat].add(1)
-    else:
-        mine = (ids >= held[0]) & (ids < held[1])
-        flat = jnp.where(mine, ids - held[0], n).reshape(-1)
-        sizes = jnp.zeros(n, jnp.int32).at[flat].add(1, mode="drop")
-    # whole row tiles for the kernel: pairs of no expert here sort last, are computed by none and read by none
-    order = jnp.argsort(jnp.pad(flat, (0, -flat.shape[0] % _row_tile(flat.shape[0])), constant_values=n), stable=True)
-    x = m.astype(cfg.dtype)[jnp.minimum(order // k, N - 1)]
+        flat = ids.reshape(-1)
+        sizes = jnp.zeros(cfg.n_experts, jnp.int32).at[flat].add(1)
+        # whole row tiles for the kernel: the padding sorts last, is computed by none and read by none
+        order = jnp.argsort(jnp.pad(flat, (0, -flat.shape[0] % _row_tile(flat.shape[0])), constant_values=cfg.n_experts), stable=True)
+        x = m.astype(cfg.dtype)[jnp.minimum(order // k, N - 1)]
 
-    y = (act(grouped_product(x, w["wg"], sizes)) * grouped_product(x, w["wu"], sizes)).astype(cfg.dtype)
-    o = grouped_product(y, w["wd"], sizes)
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))[: N * k]
-    o = o[back].reshape(N, k, -1)
-    if held is not None:  # an absent expert's rows hold whatever the kernel found there
-        o = jnp.where(mine[:, :, None], o, 0.0)
-    return jnp.sum(o * gates[:, :, None], axis=1)
+        y = (act(grouped_product(x, w["wg"], sizes)) * grouped_product(x, w["wu"], sizes)).astype(cfg.dtype)
+        o = grouped_product(y, w["wd"], sizes)
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))[: N * k]
+        return jnp.sum(o[back].reshape(N, k, -1) * gates[:, :, None], axis=1), jnp.int32(order.shape[0])
+
+    n = held[1] - held[0]
+    _, D, F = w["wg"].shape
+    rows = _held_pass_rows(N * k, n / cfg.n_experts, D, F)
+    mine = (ids >= held[0]) & (ids < held[1])
+    local = jnp.where(mine, ids - held[0], n).reshape(-1)
+    sizes = jnp.zeros(n, jnp.int32).at[local].add(1, mode="drop")
+    starts = jnp.cumsum(sizes) - sizes
+    total = jnp.sum(sizes)
+    order = jnp.argsort(local, stable=True)  # held pairs first, by expert
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k, dtype=order.dtype)).reshape(N, k)  # a pair's place in that order
+    order = jnp.pad(order, (0, rows))  # a pass may run past the last pair: those rows are in no group
+    x_all = m.astype(cfg.dtype)
+
+    def one_pass(c):
+        s, out = c
+        x = x_all[jax.lax.dynamic_slice_in_dim(order, s, rows) // k]
+        cut = jnp.clip(starts + sizes, s, s + rows) - jnp.clip(starts, s, s + rows)  # each group's rows in this pass
+        y = (act(grouped_product(x, w["wg"], cut)) * grouped_product(x, w["wu"], cut)).astype(cfg.dtype)
+        o = grouped_product(y, w["wd"], cut)
+        at = back - s
+        inside = mine & (at >= 0) & (at < rows)
+
+        def choice(j, out):  # this pass's rows back to their tokens, weighted (an absent expert's pair reads none)
+            take = partial(jax.lax.dynamic_index_in_dim, index=j, axis=1, keepdims=False)
+            return out + jnp.where(take(inside)[:, None], o[jnp.clip(take(at), 0, rows - 1)], 0.0) * take(gates)[:, None]
+
+        # a loop, not k terms side by side: one [N, D] term lives at a time
+        return s + rows, jax.lax.fori_loop(0, k, choice, out)
+
+    _, out = jax.lax.while_loop(lambda c: c[0] < total, one_pass, (jnp.int32(0), jnp.zeros((N, m.shape[1]), jnp.float32)))
+    return out, (-(-total // rows) * rows).astype(jnp.int32)
 
 
 def _expert_leaves(layers, l: int, held):
@@ -289,12 +482,59 @@ def _expert_leaves(layers, l: int, held):
     return {n: layers[n][l] if held is None else layers[n][l][held[0] : held[1]] for n in _EXPERT_LEAVES}
 
 
-def expert_load(cfg: MoeConfig, ids, real):
+def expert_load(cfg, ids, real, held=None):
     """Of one layer's choices ``ids [N, k]``, counting the tokens ``real
-    [N]`` only: how many experts got a token, and the busiest one's tokens."""
-    flat = jnp.where(real[:, None], ids, cfg.moe_num_primary_experts).reshape(-1)
-    sizes = jnp.zeros(cfg.moe_num_primary_experts + 1, jnp.int32).at[flat].add(1)[:-1]
-    return jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes)
+    [N]`` only: how many of the experts held here (``held = (lo, hi)``, or
+    all of them) got a token, the busiest one's tokens, and the pairs that
+    fell to them.  ``cfg`` is any family's with routed experts."""
+    lo, hi = held or (0, cfg.n_experts)
+    local = jnp.where(real[:, None] & (ids >= lo) & (ids < hi), ids - lo, hi - lo).reshape(-1)
+    sizes = jnp.zeros(hi - lo + 1, jnp.int32).at[local].add(1)[:-1]
+    return jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes), jnp.sum(sizes)
+
+
+def join_rows(cfg: MoeConfig, prefix: int) -> Optional[int]:
+    """The most rows one join behind ``prefix`` cached tokens carries (None:
+    no limit without a prefix): each row gathers its prefix's keys and
+    values, every layer's, beside its queries; rows that would pass
+    ``JOIN_PREFIX_BYTES`` of them join apart (one row always goes)."""
+    per_row = 2 * cfg.num_hidden_layers * cfg.num_key_value_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize * prefix
+    return max(1, JOIN_PREFIX_BYTES // per_row) if prefix else None
+
+
+def ffn_blocks(cfg: MoeConfig, tokens: int) -> int:
+    """Blocks of ``FFN_BLOCK`` tokens the shared experts of ``tokens`` take (1 without shared experts)."""
+    return -(-tokens // FFN_BLOCK) if cfg.num_shared_experts else 1
+
+
+def _shared(cfg: MoeConfig, w, m):
+    """The shared experts as one gated SiLU MLP of their summed width on ``m
+    [N, D]``: ``sum_i S_i``, over ``ffn_blocks`` blocks of tokens."""
+    def mlp(x):
+        return _mm((jax.nn.silu(_mm(x, w["shared_wg"])) * _mm(x, w["shared_wu"])).astype(cfg.dtype), w["shared_wd"])
+
+    N, n = m.shape[0], ffn_blocks(cfg, m.shape[0])
+    size = -(-N // n)
+    size += -size % 8
+    # unrolled, not a loop (a loop's body would take each layer's shared weights as operands, copied out of the
+    # stack), and one block after another: the next block's input waits on the last one's output
+    out = []
+    for a in range(0, N, size):
+        x = m[a : a + size]
+        if out:
+            x, out[-1] = jax.lax.optimization_barrier((x, out[-1]))
+        out.append(mlp(x))
+    return jnp.concatenate(out, axis=0)
+
+
+def _ffn(cfg: MoeConfig, w, routed, m, ids, gates):
+    """The expert branch on the normed state ``m [N, D]``: the routed part
+    (``routed(m, ids, gates)``) and, where the layer has shared experts, their
+    average beside it, ``(routed + sum_i S_i / n) / 2``.  Returns it and the rows carried."""
+    r, carried = routed(m, ids, gates)
+    if cfg.num_shared_experts:
+        r = 0.5 * (r + _shared(cfg, w, m) / cfg.num_shared_experts)
+    return r, carried
 
 
 def _grouped(q, n_kv: int):
@@ -311,12 +551,16 @@ def _attend_rows(q, K, V, seen):
     return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(V.dtype), V, preferred_element_type=jnp.float32).reshape(S, 1, H, hd)
 
 
-def prompt_attention(cfg: "MoeConfig", L: int) -> str:
-    """What attends a prompt's ``L`` tokens: "kernel" on a TPU where the
-    heads fill the kernel's lanes and the prompt at least one of its tiles,
-    else "blocks" (the tests' tiny models, a short prompt, any other backend)."""
+def prompt_attention(cfg: "MoeConfig", L: int, rows: int = 1, keys: int = 0) -> str:
+    """What attends ``rows`` prompts of ``L`` tokens over at most ``keys``
+    keys each (``L`` where not given): "kernel" on a TPU where the heads fill
+    the kernel's lanes and either the prompt fills one of its tiles or the
+    block path's float32 scores of one query block would pass
+    ``ATTEND_BYTES`` (128 heads over a long cached prefix), else "blocks"
+    (the tests' tiny models, a short prompt, any other backend)."""
     how = ATTENTION_KERNEL or ("kernel" if jax.default_backend() == "tpu" else "blocks")
-    return how if cfg.head_dim % 128 == 0 and L >= KERNEL_BLOCK else "blocks"
+    scores = 4 * rows * cfg.num_attention_heads * min(L, QUERY_BLOCK) * max(keys, L)
+    return how if cfg.head_dim % 128 == 0 and (L >= KERNEL_BLOCK or scores > ATTEND_BYTES) else "blocks"
 
 
 def _attend_prompt(q, K, V, first_pos: int, window: int, how: str, block: int = 0):
@@ -391,59 +635,92 @@ def _attend_blocks(q, K, V, first_pos: int, window: int, block: int):
     return jnp.moveaxis(out, 0, 1).reshape(B, n_blocks * block, H, hd)[:, :L]
 
 
-def _block(cfg: MoeConfig, w, experts, l, j: int, x, q_pos, real, carry, append):
+def _block(cfg: MoeConfig, w, experts, l, j: int, x, q_pos, real, carry, append, held=None):
     """Layer ``l`` (the ``j``-th of its period: its kind is static) applied
     to the residual stream ``x [B, L, D]`` (float32).  ``append(carry, l, j,
-    q, k, v)`` files this layer's keys and values and returns the attention's
-    result (``q`` float32: the attention rounds it); ``experts(m, ids,
-    gates)`` is the layer's expert branch; ``real [B, L]`` says which tokens
-    count in the expert load.  Returns the new state, the carry, the load and
-    the layer's keys and values."""
+    k, v)`` files this layer's keys and values and returns the carry and
+    ``attend(q, groups)``: the attention's result for queries ``q`` (float32:
+    the attention rounds it) of the key/value heads ``groups`` (a slice).  A
+    prompt's queries are made and attended ``_head_chunks`` whole groups at
+    a time, each chunk's output projected and summed; ``experts(m, ids,
+    gates)`` is the layer's expert branch (it returns the rows it carried
+    too); ``real [B, L]`` says which tokens count in the expert load; ``held``
+    the experts held here.  Returns the new state, the carry, the load and
+    the layer's keys and values.  In the parallel block the router, the
+    attention and the experts read one normed input and both branches are
+    added at once; else the experts read the post-attention normed state."""
     B, L, D = x.shape
-    H, Hkv, hd, eps = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.rms_norm_eps
-    a = _rms(x, w["in_norm"], eps)
+    H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    a = _norm(cfg, x, w["in_norm"])
     ids, gates = route(cfg, a.reshape(B * L, D), w["router"])
-    q = _mm_t(a, w["wq"]).reshape(B, L, H, hd)
     k = _mm_t(a, w["wk"]).reshape(B, L, Hkv, hd)
     if cfg.rope_layout[j]:
-        q, k = _rope(q, q_pos, cfg.rope_theta), _rope(k, q_pos, cfg.rope_theta)
+        k = _rotate(cfg, k, q_pos)
     v = _mm_t(a, w["wv"]).reshape(B, L, Hkv, hd)
     k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
-    carry, o = append(carry, l, j, q, k, v)
-    x = x + _mm(o.reshape(B, L, H * hd), w["wo"])
-    m = _rms(x, w["post_norm"], eps)
-    f = experts(m.reshape(B * L, D), ids, gates)
-    return x + f.reshape(B, L, D), carry, expert_load(cfg, ids, real.reshape(-1)), (k, v)
+    carry, attend = append(carry, l, j, k, v)
+    n = _head_chunks(cfg, B * L)
+    rows, groups = H * hd // n, Hkv // n
+    attn = None
+    for c in range(n):  # a chunk of whole key/value groups' query heads at a time
+        if c:  # one after another: the next chunk's queries are not made before the last one's output is summed
+            a, attn = jax.lax.optimization_barrier((a, attn))
+        # the barrier keeps the heads' reshape off the weights: moved onto them it copies every layer's wq a step
+        q = jax.lax.optimization_barrier(_mm_t(a, w["wq"][c * rows : (c + 1) * rows])).reshape(B, L, H // n, hd)
+        if cfg.rope_layout[j]:
+            q = _rotate(cfg, q, q_pos)
+        o = _mm(attend(q, slice(c * groups, (c + 1) * groups)).reshape(B, L, rows), w["wo"][c * rows : (c + 1) * rows])
+        attn = o if attn is None else attn + o
+    if cfg.parallel_block:
+        f, carried = _ffn(cfg, w, experts, a.reshape(B * L, D), ids, gates)
+        x = x + attn + f.reshape(B, L, D)
+    else:
+        x = x + attn
+        f, carried = _ffn(cfg, w, experts, _norm(cfg, x, w["post_norm"]).reshape(B * L, D), ids, gates)
+        x = x + f.reshape(B, L, D)
+    return x, carry, expert_load(cfg, ids, real.reshape(-1), held) + (carried,), (k, v)
 
 
 def _stack(cfg: MoeConfig, params, ids, q_pos, real, carry, append, held=None, keep_kv: bool = False):
     """Embedding, then every period of layers.  Returns the normed state
-    ``[B, L, D]``, the cache carry, per layer the experts that got a real
-    token and the busiest one's tokens (``[layers]`` each) and, asked for,
-    every layer's keys and values ``[layers, B, L, Hkv, hd]``."""
+    ``[B, L, D]``, the cache carry, per layer the held experts that got a
+    real token, the busiest one's tokens, the pairs that fell to held
+    experts and the rows the expert layer carried (``[layers]`` each) and,
+    asked for, every layer's keys and values ``[layers, B, L, Hkv, hd]``.
+    ``held`` cuts a share's experts from weights that hold them all (a test's)."""
     p, Ly = cfg.period, cfg.num_hidden_layers
     layers = params["layers"]
-    rest = {n: a.reshape((Ly // p, p) + a.shape[1:]) for n, a in layers.items() if n not in _EXPERT_LEAVES}
     x = params["embed"][ids].astype(jnp.float32)
+    here = held or cfg.experts_held
+    act = jax.nn.silu if cfg.parallel_block else jax.nn.relu
 
-    def one_period(c, xs):
-        w, i = xs
+    def take(l):
+        # each layer indexes the flat stack itself, so its slice has one consumer and fuses into its product: a
+        # period's slab scanned out for several layers is copied through HBM before they read it
+        return {n: jax.lax.dynamic_index_in_dim(a, l, keepdims=False) for n, a in layers.items() if n not in _EXPERT_LEAVES}
+
+    def one_period(c, i):
         x, carry = c
         loads, kvs = [], []
         for j in range(p):
             # the j-th layer of period i: one branch a period, each holding that layer's experts by reference
-            branches = [partial(grouped_experts, cfg, held, _expert_leaves(layers, q * p + j, held)) for q in range(Ly // p)]
+            branches = [partial(grouped_experts, cfg, here, _expert_leaves(layers, q * p + j, held), act=act) for q in range(Ly // p)]
             x, carry, load, kv = _block(
-                cfg, {n: a[j] for n, a in w.items()}, partial(jax.lax.switch, i, branches), i * p + j, j, x, q_pos, real, carry, append
+                cfg, take(i * p + j), partial(jax.lax.switch, i, branches), i * p + j, j, x, q_pos, real, carry, append, here,
             )
             loads.append(load)
             kvs.append(kv)
-        ys = (jnp.stack([t for t, _ in loads]), jnp.stack([b for _, b in loads]))
+        ys = tuple(jnp.stack([load[n] for load in loads]) for n in range(4))
         return (x, carry), ys + ((jnp.stack([k for k, _ in kvs]), jnp.stack([v for _, v in kvs])) if keep_kv else ())
 
-    (x, carry), ys = jax.lax.scan(one_period, (x, carry), (rest, jnp.arange(Ly // p)))
-    kept = tuple(a.reshape((Ly,) + a.shape[2:]) for a in ys[2:])
-    return _rms(x, params["final_norm"], cfg.rms_norm_eps), carry, (ys[0].reshape(Ly), ys[1].reshape(Ly)), kept
+    (x, carry), ys = jax.lax.scan(one_period, (x, carry), jnp.arange(Ly // p))
+    kept = tuple(a.reshape((Ly,) + a.shape[2:]) for a in ys[4:])
+    return _norm(cfg, x, params["final_norm"]), carry, tuple(a.reshape(Ly) for a in ys[:4]), kept
+
+
+def _load_stats(loads) -> Dict[str, Any]:
+    """A program's per-layer expert load, under the names the decode engine books."""
+    return dict(zip(("experts_touched", "expert_load_max", "expert_pairs_held", "expert_pairs_carried"), loads))
 
 
 def forward(cfg: MoeConfig, params, ids, held=None, query_block: int = 0):
@@ -452,23 +729,28 @@ def forward(cfg: MoeConfig, params, ids, held=None, query_block: int = 0):
     every expert layer (and lets it feed the next layer: a share, not the model)."""
     B, L = ids.shape
     pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None, :], (B, L))
-    how = prompt_attention(cfg, L)
+    how = prompt_attention(cfg, L, B)
 
-    def append(carry, l, j, q, k, v):
+    def append(carry, l, j, k, v):
         window = cfg.sliding_window_size if cfg.sliding_window_layout[j] else 0
-        return carry, _attend_prompt(q, k, v, 0, window, how, query_block)
+        return carry, lambda q, g: _attend_prompt(q, k[:, :, g], v[:, :, g], 0, window, how, query_block)
 
     x = _stack(cfg, params, ids, pos, jnp.ones((B, L), bool), (), append, held)[0]
-    return _head(params, x)
+    return _logits(cfg, params, x)
 
 
 def expert_layer(cfg: MoeConfig, params, l: int, a, m, held=None):
     """One layer's expert branch alone, ``a`` / ``m [N, D]`` the normed
-    states the router and the experts read: what the shares of an expert
-    axis each compute, and what they must add up to."""
+    states the router and the experts read (the parallel block's are one):
+    what the shares of an expert axis each compute, and what they must add
+    up to once what each computes alike (the shared experts) is counted once.
+    ``held`` cuts a share from weights that hold every expert."""
     layers = params["layers"]
-    ids, gates = route(cfg, a, layers["router"][l])
-    return grouped_experts(cfg, held, _expert_leaves(layers, l, held), m, ids, gates)
+    w = {n: x[l] for n, x in layers.items() if n not in _EXPERT_LEAVES}
+    ids, gates = route(cfg, a, w["router"])
+    act = jax.nn.silu if cfg.parallel_block else jax.nn.relu
+    routed = partial(grouped_experts, cfg, held or cfg.experts_held, _expert_leaves(layers, l, held), act=act)
+    return _ffn(cfg, w, routed, m, ids, gates)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -496,14 +778,14 @@ def slot_prefill(cfg: MoeConfig, S: int, T: int, B: int, L_sfx: int, P: int, blo
     suffix attends through ``_attend_prompt``, window layers only the band of
     keys that reaches a query.  ``extra`` carries, beside the first token's
     stats, the per-layer expert load over real tokens (a pad row counts as
-    the row it repeats) and, where the prefix tier's ``block`` is given,
-    ``prompt_kv``: the suffix's keys and values cut into the tier's blocks,
+    the row it repeats), the rows the expert layers carried and, where the
+    prefix tier's ``block`` is given, ``prompt_kv``: the suffix's keys and values cut into the tier's blocks,
     per row and block ``[layers, block, Hkv, hd]``, which stay on the device
     (a ring no longer holds a long prompt's first blocks, and a slice a
     block after the join cost the engine 0.45 s of dispatches a join).  The
     pools are donated and updated in place."""
     ring = cfg.pool_layout(T)[1][2]
-    how = prompt_attention(cfg, L_sfx)
+    how = prompt_attention(cfg, L_sfx, B, P + L_sfx)
 
     def run(params, pool_k, pool_v, slots, suffix_ids, n_len, prefix_k, prefix_v, rngs, temps):
         pos = jnp.broadcast_to((P + jnp.arange(L_sfx, dtype=jnp.int32))[None, :], (B, L_sfx))
@@ -514,7 +796,7 @@ def slot_prefill(cfg: MoeConfig, S: int, T: int, B: int, L_sfx: int, P: int, blo
         r = jnp.arange(ring, dtype=jnp.int32)[None, :]
         src = jnp.clip(last - jnp.mod(last - r, ring), 0, P + L_sfx - 1)[twice]  # [B, ring]: the position row r is to hold
 
-        def append(carry, l, j, q, k, v):
+        def append(carry, l, j, k, v):
             (full_k, ring_k), (full_v, ring_v) = carry
             if P:
                 k = jnp.concatenate([_prefix_rows(prefix_k, l, k.dtype), k], axis=1)
@@ -529,16 +811,16 @@ def slot_prefill(cfg: MoeConfig, S: int, T: int, B: int, L_sfx: int, P: int, blo
                 full_k = full_k.at[rows, d, : P + L_sfx].set(k[twice], mode="promise_in_bounds")
                 full_v = full_v.at[rows, d, : P + L_sfx].set(v[twice], mode="promise_in_bounds")
                 window = 0
-            return ((full_k, ring_k), (full_v, ring_v)), _attend_prompt(q, k, v, P, window, how)
+            return ((full_k, ring_k), (full_v, ring_v)), lambda q, g: _attend_prompt(q, k[:, :, g], v[:, :, g], P, window, how)
 
         real = pos < n_len[:, None]
-        x, (pool_k, pool_v), (touched, busiest), kept = _stack(
+        x, (pool_k, pool_v), loads, kept = _stack(
             cfg, params, suffix_ids, pos, real, (pool_k, pool_v), append, keep_kv=bool(block)
         )
         at = jnp.maximum(n_len - 1 - P, 0)
-        logits = _head(params, jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0])
+        logits = _logits(cfg, params, jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0])
         rngs, tok = _sample(logits, rngs, temps)
-        extra = {**token_stats(logits, tok), "experts_touched": touched, "expert_load_max": busiest}
+        extra = {**token_stats(logits, tok), **_load_stats(loads)}
         if block:
             extra["prompt_kv"] = tuple(
                 tuple(tuple(a[:, b, i * block : (i + 1) * block] for i in range(L_sfx // block)) for b in range(B)) for a in kept
@@ -570,7 +852,7 @@ def slot_step(cfg: MoeConfig, S: int, T: int, chunk: int) -> Callable:
             seen_full = jnp.arange(T)[None, :] <= pos[:, None]
             seen_ring = pos[:, None] - jnp.mod(pos[:, None] - jnp.arange(ring)[None, :], ring) >= 0
 
-            def append(c, l, j, q, k, v):
+            def append(c, l, j, k, v):
                 (full_k, ring_k), (full_v, ring_v) = c
                 d = _kind_index(cfg, l, j)
                 if cfg.sliding_window_layout[j]:
@@ -582,14 +864,14 @@ def slot_step(cfg: MoeConfig, S: int, T: int, chunk: int) -> Callable:
                     full_v = full_v.at[lanes, d, at_full].set(v[:, 0], mode="promise_in_bounds")
                     K, V, seen = full_k, full_v, seen_full
                 K, V = (jax.lax.dynamic_index_in_dim(x, d, axis=1, keepdims=False) for x in (K, V))
-                return ((full_k, ring_k), (full_v, ring_v)), _attend_rows(q.astype(K.dtype), K, V, seen)
+                return ((full_k, ring_k), (full_v, ring_v)), lambda q, g: _attend_rows(q.astype(K.dtype), K[:, :, g], V[:, :, g], seen)
 
-            x, (pool_k, pool_v), (touched, busiest), _ = _stack(
+            x, (pool_k, pool_v), loads, _ = _stack(
                 cfg, params, tok[:, None], pos[:, None], live[:, None], (pool_k, pool_v), append
             )
-            logits = _head(params, x[:, 0])
+            logits = _logits(cfg, params, x[:, 0])
             rngs2, nxt = _sample(logits, rngs, temps)
-            stats = {**token_stats(logits, nxt), "experts_touched": touched, "expert_load_max": busiest}
+            stats = {**token_stats(logits, nxt), **_load_stats(loads)}
             carry = (
                 pool_k, pool_v, jnp.where(live, nxt, tok), jnp.where(live, pos + 1, pos),
                 live & (nxt != eos), jnp.where(live, left - 1, left),

@@ -418,8 +418,11 @@ class ContinuousDecoder(_CoalescerBase):
             # routed experts (models/moe.py), by phase: (token, expert) choices
             # made, of those the pairs that fell to an expert held here (all of them,
             # unless the family holds a range), experts that got a token summed over
-            # (program, layer), and the busiest expert's tokens summed likewise
-            **{f"{what}_{phase}": 0 for what in ("expert_tokens", "expert_pairs_held", "experts_touched", "expert_load_max")
+            # (program, layer), the busiest expert's tokens summed likewise, and the
+            # rows the expert layers carried through sort, gather and products (pad
+            # tokens' and idle lanes' pairs, whole tiles and passes, included)
+            **{f"{what}_{phase}": 0 for what in ("expert_tokens", "expert_pairs_held", "experts_touched", "expert_load_max",
+                                                 "expert_pairs_carried")
                for phase in ("prefill", "decode")},
         }
         super().__init__(
@@ -693,7 +696,7 @@ class ContinuousDecoder(_CoalescerBase):
             L_sfx = min(L_pad, self._T - P)
             # the cohort, cut to the token budget: joins of as many rows as
             # the widest batch bucket under it holds, one after another
-            rows = self._join_rows(L_sfx)
+            rows = self._join_rows_behind(L_sfx, P)
             if len(grp) > rows:
                 self.pool_stats["join_splits"] += 1
             for a in range(0, len(grp), rows):
@@ -706,6 +709,15 @@ class ContinuousDecoder(_CoalescerBase):
         rows = 1
         while rows * 4 * L_sfx <= JOIN_TOKEN_BUDGET:
             rows *= 4
+        return rows
+
+    def _join_rows_behind(self, L_sfx: int, P: int) -> int:
+        """``_join_rows``, and no wider than the family lets a join behind
+        ``P`` cached tokens be (``TextGenerator.join_rows``: a row gathers its
+        prefix's keys and values for every layer)."""
+        rows, most = self._join_rows(L_sfx), self.generator.join_rows(P)
+        while rows > 1 and most is not None and rows > most:
+            rows //= 4
         return rows
 
     def _prefill_group(self, grp: List[dict], L_sfx: int, P: int) -> None:
@@ -722,7 +734,8 @@ class ContinuousDecoder(_CoalescerBase):
         B = 1
         while B < n_real:
             B *= 4
-        attention = gen.join_attention(L_sfx)
+        attention = gen.join_attention(L_sfx, B, P)
+        ffn_blocks = gen.join_ffn_blocks(B * L_sfx)
         # where this join's scan hands back its state for the prefix tier, and what it restores
         snapshot_at = gen.snapshot_positions(P, L_sfx)
         restored = P * n_real if self._state_layout else 0
@@ -730,7 +743,7 @@ class ContinuousDecoder(_CoalescerBase):
             # the parent of ``operands`` / ``prefix`` / ``call`` (``_dispatch_prefill``)
             with observe.span(
                 "gen.prefill.dispatch", rows=n_real, batch=B,
-                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, attention=attention,
+                suffix_tokens=L_sfx, prefix_tokens=P, join_tokens=B * L_sfx, attention=attention, ffn_blocks=ffn_blocks,
                 state_restored_tokens=restored, snapshots=len(snapshot_at) * n_real, **_S_PREFILL_DISPATCH,
             ) as dispatch:
                 # the round trip runs from this bracket's start to the fetch's
@@ -967,7 +980,7 @@ class ContinuousDecoder(_CoalescerBase):
                 while L_pad < width - P:
                     L_pad *= 2
                 L_sfx = min(L_pad, self._T - P)
-                shapes.update((B, L_sfx, P) for B in sizes if B <= self._join_rows(L_sfx))
+                shapes.update((B, L_sfx, P) for B in sizes if B <= self._join_rows_behind(L_sfx, P))
         for B, L_sfx, P in sorted(shapes):
             pk, pv, toks, rngs, _ = self._dispatch_prefill(B, L_sfx, P, [], [], None)
             np.asarray(toks)  # start-up, before traffic: wait for each program to have run
@@ -1116,15 +1129,13 @@ class ContinuousDecoder(_CoalescerBase):
     def _note_expert_load(self, phase: str, extra, tokens: int, n_steps: Optional[int] = None) -> None:
         """Book what a program with routed experts read off its routers, per
         layer (and per step, of which the first ``n_steps`` ran): the experts
-        that got a token and the busiest one's tokens."""
-        touched = extra.get("experts_touched")
-        if touched is None:
+        that got a token, the busiest one's tokens, the pairs that fell to an
+        expert held here and the rows the expert layers carried."""
+        if extra.get("experts_touched") is None:
             return
         self.pool_stats[f"expert_tokens_{phase}"] += tokens * self._expert_choices
-        held = extra.get("expert_pairs_held")  # a family that holds a range of the experts counts what fell to it
-        self.pool_stats[f"expert_pairs_held_{phase}"] += tokens * self._expert_choices if held is None else int(np.sum(held[:n_steps]))
-        self.pool_stats[f"experts_touched_{phase}"] += int(np.sum(touched[:n_steps]))
-        self.pool_stats[f"expert_load_max_{phase}"] += int(np.sum(extra["expert_load_max"][:n_steps]))
+        for stat in ("experts_touched", "expert_load_max", "expert_pairs_held", "expert_pairs_carried"):
+            self.pool_stats[f"{stat}_{phase}"] += int(np.sum(extra[stat][:n_steps]))
 
     def _note_exit_mass(self, extra, at) -> None:
         mass = extra.get("exit_mass")  # [..., loop steps]: the looped family's gate
@@ -1594,6 +1605,7 @@ class ContinuousDecoder(_CoalescerBase):
                 ("pathway_generator_expert_pairs_held_total", "expert_pairs_held"),
                 ("pathway_generator_experts_touched_total", "experts_touched"),
                 ("pathway_generator_expert_load_max_total", "expert_load_max"),
+                ("pathway_generator_expert_pairs_carried_total", "expert_pairs_carried"),
             ):
                 yield ("counter", family, {**labels, "phase": phase}, self.pool_stats[f"{stat}_{phase}"])
         yield (

@@ -267,3 +267,75 @@ def test_hybrid_reads_each_layers_weights_where_they_lie(one_chip, monkeypatch, 
     which fuses into its product; ``scan(unroll=True)`` keeps the copy."""
     _, params, _, compiled = _hybrid_step(one_chip, monkeypatch) if program == "step" else _hybrid_join(one_chip, monkeypatch, 1, 512, 4096)
     assert _weight_slabs_in_hbm(compiled.as_text(), params) == []
+
+
+# ---------------------------------------------------------------------------
+# the sparse-expert family's parallel block at Command A+'s widths (4 of 32 layers, 16 of 128 experts a layer held, an
+# eighth of the vocabulary: 9.5 GB of weights beside a 1.2 GB pool and a 2 GiB prefix tier, 80% of the chip before a
+# program's temporaries).  What the compiler could have forced and these hold it to: a layer's query projection
+# copied out of the stack every step (the heads' reshape, or GPT-J's pair reorder, moved onto the weights: 4 x 128 MB a
+# step), and a 12,544-token join's 128 heads of float32 queries, shared experts of 16,384 and held pairs all live at once
+# ---------------------------------------------------------------------------
+
+CMDA_ARCH = dict(
+    model_type="cohere2_moe", vocab_size=32768, hidden_size=4096, num_attention_heads=128, num_key_value_heads=8, head_dim=128,
+    intermediate_size=4096, num_experts=128, num_experts_per_tok=8, num_shared_experts=4, num_hidden_layers=4,
+    layer_types=["sliding_attention"] * 3 + ["full_attention"], sliding_window=4096, rope_theta=50000, layer_norm_eps=1e-5,
+    max_position_embeddings=200000, use_parallel_block=True, tie_word_embeddings=True, experts_held=[0, 16],
+)
+CMDA_SLOTS, CMDA_WIDTH = 12, 12544
+
+
+def _cmda_shapes(one_chip, monkeypatch):
+    from pathway_tpu.models import moe
+
+    monkeypatch.setattr(moe, "GROUPED_KERNEL", "gmm")
+    monkeypatch.setattr(moe, "ATTENTION_KERNEL", "kernel")
+    cfg = moe.MoeConfig.from_architecture(CMDA_ARCH)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(lambda: moe.init_params(cfg, 0)))
+    pool = tuple(sds((CMDA_SLOTS, depth, rows, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16) for _, depth, rows in cfg.pool_layout(CMDA_WIDTH))
+    assert [p.shape for p in pool] == [(12, 1, 12544, 8, 128), (12, 3, 4096, 8, 128)]
+    return moe, cfg, sds, params, pool
+
+
+def _layer_matrices_in_hbm(text, params):
+    """Top-level fusions outside VMEM whose result is one layer's slice of a stacked projection (``[1, A, D]`` or
+    ``[A, D]``): a weight copied out of the stack instead of read where it lies."""
+    shapes = {tuple(a.shape[1:]) for a in (params["layers"]["wq"], params["layers"]["wo"])}
+    found, inside_fusion = [], False
+    for line in text.splitlines():
+        if line[:1] not in ("", " ", "}"):  # a computation's header: a fused one's slices are its product's operands
+            inside_fusion = line.startswith("%fused")
+        m = re.match(r"\s+(?:ROOT )?%(\S+) = bf16\[([\d,]+)\]\{([^}]*)\} fusion\(", line)
+        if not inside_fusion and m and "S(1)" not in m.group(3):
+            dims = tuple(int(d) for d in m.group(2).split(","))
+            if dims[-2:] in shapes and all(d == 1 for d in dims[:-2]):
+                found.append(f"{m.group(1)} {list(dims)}")
+    return found
+
+
+def test_the_parallel_blocks_step_reads_each_layers_projections_where_they_lie(one_chip, monkeypatch):
+    moe, cfg, sds, params, pool = _cmda_shapes(one_chip, monkeypatch)
+    S = CMDA_SLOTS
+    args = (params, pool, pool, sds((S,), jnp.int32), sds((S,), jnp.int32), sds((S,), jnp.bool_), sds((S,), jnp.int32),
+            sds((S, 2), jnp.uint32), sds((S,), jnp.float32), sds((S,), jnp.int32), sds((), jnp.int32))
+    compiled = moe.slot_step(cfg, S, CMDA_WIDTH, 8).lower(*args).compile()
+    _moe_fits(compiled, pool, 0.3e9)  # 0.19 GB; with the four layers' wq copied out of the stack 0.8
+    assert _layer_matrices_in_hbm(compiled.as_text(), params) == []
+
+
+# a join of Command A+'s widths is a half-minute of the chip's compiler on every core: run them with -m slow
+@pytest.mark.slow
+@pytest.mark.parametrize("L,P,temporaries", [(12544, 0, 3.0e9), (4352, 8192, 2.1e9)], ids=["cold", "warm-behind-the-tier"])
+def test_the_parallel_blocks_join_fits_beside_weights_pool_and_tier(one_chip, monkeypatch, L, P, temporaries):
+    """2.74 GB cold, 1.84 GB warm: beside 10.7 GB of arguments and the tier's 2 GiB, under the chip's 15.75 GB (queries
+    of all 128 heads at once, the shared experts over every token and a row for every routed pair needed 6.4 GB)."""
+    moe, cfg, sds, params, pool = _cmda_shapes(one_chip, monkeypatch)
+    block = sds((cfg.cache_depth, BLOCK, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    prefix = ((block,) * (P // BLOCK),) if P else None
+    args = (params, pool, pool, sds((1,), jnp.int32), sds((1, L), jnp.int32), sds((1,), jnp.int32), prefix, prefix,
+            sds((1, 2), jnp.uint32), sds((1,), jnp.float32))
+    compiled = moe.slot_prefill(cfg, CMDA_SLOTS, CMDA_WIDTH, 1, L, P, block=BLOCK).lower(*args).compile()
+    _moe_fits(compiled, pool, temporaries)
+    assert "splash_mha_fwd" in compiled.as_text() and "%gmm" in compiled.as_text()
