@@ -18,7 +18,11 @@ and values under cache row ``d`` and hands back what to attend.  The full
 forward (no cache), the slot prefill and the slot step differ only in that
 function.  Weights are stacked ``[layers, ...]`` and the stack is a
 ``lax.scan`` over layers inside a ``lax.scan`` over loop steps, so program
-size does not grow with depth.
+size does not grow with depth.  Every product reads its layer's slice of a
+stack where it lies: the query, key and value projections' outputs pass an
+``optimization_barrier`` before they are cut into heads, since a reshape
+that the compiler moves onto a slice stops the slice fusing into its
+product and copies it first.
 
 Precision: bfloat16 weights, bfloat16 matrix-product inputs with float32
 accumulation; the residual stream, norms, rotary angles, softmax and the
@@ -187,9 +191,12 @@ def _block(cfg: LoopedConfig, w, x, q_pos, carry, d, append):
     B, L, _ = x.shape
     H, hd, eps = cfg.num_attention_heads, cfg.head_dim, cfg.rms_norm_eps
     a = _rms(x, w["in_norm"], eps)
-    q = _rope(_mm_t(a, w["wq"]).reshape(B, L, H, hd), q_pos, cfg.rope_theta).astype(cfg.dtype)
-    k = _rope(_mm_t(a, w["wk"]).reshape(B, L, H, hd), q_pos, cfg.rope_theta).astype(cfg.dtype)
-    v = _mm_t(a, w["wv"]).reshape(B, L, H, hd).astype(cfg.dtype)
+    # the barrier keeps the heads' reshape off the weights: moved onto them it copies each layer's wq, wk and wv into
+    # VMEM before the products read the copies
+    q, k, v = (jax.lax.optimization_barrier(_mm_t(a, w[n])).reshape(B, L, H, hd) for n in ("wq", "wk", "wv"))
+    q = _rope(q, q_pos, cfg.rope_theta).astype(cfg.dtype)
+    k = _rope(k, q_pos, cfg.rope_theta).astype(cfg.dtype)
+    v = v.astype(cfg.dtype)
     carry, K, V = append(carry, d, k, v)
     o = _mm(_attend(q, K, V, q_pos).reshape(B, L, H * hd), w["wo"])
     x = x + _rms(o, w["attn_out_norm"], eps)
